@@ -2,9 +2,9 @@
 
     python native/setup.py build_ext --build-lib <dir>
 
-siddhi_tpu.native builds this lazily on first import (cached under
-siddhi_tpu/_native_build/) and falls back to the pure-Python encoder when no
-compiler is available."""
+siddhi_tpu.native builds this on first import (cached under
+siddhi_tpu/_native_build/<hash of native.SOURCES>/, always with --force) and
+falls back to the pure-Python encoder, with a WARNING, when the build fails."""
 
 from setuptools import Extension, setup
 
@@ -14,6 +14,7 @@ setup(
         Extension(
             "_siddhi_native",
             sources=["columnar.c"],
+            depends=["colring_core.h"],
             extra_compile_args=["-O3"],
         )
     ],

@@ -522,7 +522,7 @@ class AggregationRuntime(Receiver):
             return
         from jax.sharding import PartitionSpec as P
 
-        from ..parallel.sharded import _SHARD_KW, shard_map
+        from jax import shard_map
 
         mesh = self.mesh
         axis = mesh.axis_names[0]
@@ -542,7 +542,7 @@ class AggregationRuntime(Receiver):
         self._ingest = jax.jit(
             shard_map(shard_ingest, mesh=mesh,
                       in_specs=(P(axis), P(), P()), out_specs=P(axis),
-                      **_SHARD_KW),
+                      check_vma=False),
             donate_argnums=(0,))
 
         def shard_ingest_lanes(state, batch: EventBatch, now):
@@ -561,7 +561,7 @@ class AggregationRuntime(Receiver):
         self._ingest_lanes = jax.jit(
             shard_map(shard_ingest_lanes, mesh=mesh,
                       in_specs=(P(axis), P(axis), P()), out_specs=P(axis),
-                      **_SHARD_KW),
+                      check_vma=False),
             donate_argnums=(0,))
         self._evict = jax.jit(jax.vmap(self._make_evict(), in_axes=(0, 0)))
 

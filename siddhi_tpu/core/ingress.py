@@ -54,6 +54,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from ..errors import SiddhiAppRuntimeError
 from ..util.locks import named_condition, named_lock, note_blocking
 
 _log = logging.getLogger("siddhi_tpu")
@@ -273,9 +274,10 @@ class IngressPipeline:
                                  name=f"siddhi-ingress-{sid}-w{i}")
             t.start()
             self._threads.append(t)
-        self._feeder = threading.Thread(target=self._feed_loop, daemon=True,
-                                        name=f"siddhi-ingress-{sid}-feed")
-        self._feeder.start()
+        feeder = threading.Thread(target=self._feed_loop, daemon=True,
+                                  name=f"siddhi-ingress-{sid}-feed")
+        feeder.start()
+        self._feeder = feeder  # published started: liveness checks read it
 
     def stop(self) -> None:
         """Orderly shutdown: no new submits, queued runs finish (every
@@ -311,6 +313,13 @@ class IngressPipeline:
                 return s
             if deadline is not None and time.monotonic() >= deadline:
                 return -1
+            if self._feeder is not None and not self._feeder.is_alive():
+                # nothing will ever free a slot: fail the producer instead
+                # of spinning (a step that raised with no @OnError handler
+                # takes the feeder thread down with it)
+                raise SiddhiAppRuntimeError(
+                    f"ingress feeder for {self.j.definition.id!r} died; "
+                    "the ring is full and will not drain")
             self._flush_req.set()
             note_blocking("ring.claim_wait", allow=("ingress.submit",))
             time.sleep(0.0002)  # noqa: SL404 — blocking claim IS the backpressure
@@ -731,18 +740,41 @@ class IngressPipeline:
     def drain(self, timeout: float = 120.0) -> None:
         """Barrier: every row submitted before this call is delivered when
         it returns. Callers must NOT hold the controller lock (the feeder
-        needs it to deliver); junction.flush() guards on _lock_owned."""
-        self._q.join()  # all claimed runs are encoded + published
+        needs it to deliver); junction.flush() guards on _lock_owned.
+        Raises SiddhiAppRuntimeError when the workers or the feeder have
+        died or `timeout` seconds pass first — returning as if drained
+        would let the caller read results that are not there yet."""
         deadline = time.monotonic() + timeout
-        while time.monotonic() < deadline:
+
+        def check(stage: str) -> None:
+            dead = [t.name for t in (*self._threads, self._feeder)
+                    if t is not None and not t.is_alive()]
+            if dead and not self._stopping:
+                why = f"thread(s) {dead} died"
+            elif time.monotonic() >= deadline:
+                why = f"not {stage} within {timeout:.0f}s"
+            else:
+                return
+            raise SiddhiAppRuntimeError(
+                f"ingress drain on {self.j.definition.id!r}: {why} "
+                f"(ring={self.ring.size()}, "
+                f"queued={self._q.unfinished_tasks})")
+
+        # every claimed run is encoded + published BEFORE the barrier goes
+        # up: a flush request that meets a half-written run would deliver
+        # its published prefix as a partial chunk (same rows, but a chunk
+        # boundary — and a lane bucket — the synchronous path never makes)
+        while self._q.unfinished_tasks:
+            check("published")
+            time.sleep(0.0005)
+        while True:
             self._barrier_req.set()
             self._flush_req.set()
             if self._feeder_idle.is_set() and self.ring.size() == 0 \
                     and self._q.unfinished_tasks == 0:
                 return
+            check("delivered")
             time.sleep(0.0005)
-        _log.warning("ingress drain timed out on %r (ring=%d)",  # pragma: no cover
-                     self.j.definition.id, self.ring.size())
 
     def size(self) -> int:
         return self.ring.size() + self._q.unfinished_tasks

@@ -70,7 +70,7 @@ from .event import EventBatch, EventType, StreamCodec
 from .query_runtime import QueryCallback
 from .stream import Receiver, StreamJunction
 
-BIGSEQ = 2**62  # Python int literal — see ops/windows.py BIG note (tunnel cost)
+BIGSEQ = 2**62  # Python int literal — see ops/windows.py BIG note
 
 #: junction key for the merged multi-stream sequence step
 MERGED_SID = "#merged"

@@ -80,11 +80,11 @@ class QueryPlanInputs:
 
 def aot_warm(jit_fn, *args) -> None:
     """Populate `jit_fn`'s dispatch cache for `args`' shape signature
-    WITHOUT executing it — jax (>= 0.4.31) shares `lower().compile()`
-    executables with the normal call path, so the next real call is a pure
-    cache hit. Warmup therefore has no step side effects, cannot touch live
-    state, and never runs host callbacks (executing a step during warmup
-    can deadlock jax's CPU pure_callback path on small hosts)."""
+    WITHOUT executing it — jax shares `lower().compile()` executables
+    with the normal call path, so the next real call is a pure cache hit.
+    Warmup therefore has no step side effects, cannot touch live state,
+    and never runs host callbacks (executing a step during warmup can
+    deadlock jax's CPU pure_callback path on small hosts)."""
     abstract = jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(jnp.shape(x), jnp.result_type(x)),
         args)

@@ -377,13 +377,16 @@ class SharedStepGroup(Receiver):
         bucket-eligible — full-capacity traffic never stalls.)
 
         The warm is an actual EXECUTION on an empty batch, not just
-        lower().compile(): on this jax line the AOT executable is not
-        shared with the normal dispatch cache, so a lower-only warm still
-        leaves the first traffic batch paying the backend compile (~100s
-        of ms — the exact cliff the splice exists to avoid). The step is
-        pure and the batch empty, so the run has no observable effect;
-        states are deep-copied first because donate_argnums=(0,) would
-        otherwise invalidate the live member state buffers.
+        lower().compile(): PR 18 measured (XLA:CPU) a lower-only warm
+        leaving the first traffic batch ~100s of ms slow — the cliff the
+        splice exists to avoid. That is first-dispatch cost, not a second
+        backend compile: on jax 0.9.0 a query step called after
+        lower().compile() compiles nothing more, on the CPU and on the TPU
+        v5e alike (PR 21, chip_smoke.py `first_batch_after_warmup`); which
+        warm primitive to keep is ROADMAP D6. The step is pure and the
+        batch empty, so the run has no observable effect; states are
+        deep-copied first because donate_argnums=(0,) would otherwise
+        invalidate the live member state buffers.
 
         A separate method so fault injection (util.faults.inject) can
         fail a splice mid-flight; splice_in/splice_out roll back to the

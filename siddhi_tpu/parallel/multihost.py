@@ -51,12 +51,7 @@ def init_distributed(coordinator: str, num_processes: int, process_id: int,
     """
     import jax
 
-    try:
-        already = jax.distributed.is_initialized()
-    except AttributeError:  # older jax: no public probe
-        from jax._src import distributed as _dist
-        already = getattr(_dist.global_state, "client", None) is not None
-    if already:
+    if jax.distributed.is_initialized():
         return
     jax.distributed.initialize(
         coordinator_address=coordinator,

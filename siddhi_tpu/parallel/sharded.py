@@ -38,12 +38,7 @@ from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-    _SHARD_KW = {"check_vma": False}
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
-    _SHARD_KW = {"check_rep": False}
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.event import EventBatch
@@ -147,7 +142,7 @@ class ShardedQueryStep:
                 shard_step, mesh=mesh,
                 in_specs=(state_spec, repl, repl),
                 out_specs=(state_spec, repl),
-                **_SHARD_KW,
+                check_vma=False,
             ),
             donate_argnums=(0,),
         )
@@ -211,7 +206,7 @@ class PartitionedQueryStep:
             shard_step, mesh=mesh,
             in_specs=(spec, repl, repl, repl),
             out_specs=(spec, spec),
-            **_SHARD_KW,
+            check_vma=False,
         )
 
         def full_step(states, key_table: DenseKeyTable, batch: EventBatch, now):

@@ -636,6 +636,10 @@ def main(argv=None) -> None:
     argv = argv if argv is not None else sys.argv[1:]
     from .telemetry.logs import configure_logging
     configure_logging()  # SIDDHI_LOG_FORMAT=json → structured one-liners
+    from .util.platform import configure_compile_cache
+    # starts the backend: a chip this process cannot have fails here, at
+    # start-up, not at the first deploy; redeploys reuse compiled steps
+    configure_compile_cache()
     allow_scripts = "--allow-scripts" in argv
     argv = [a for a in argv if a != "--allow-scripts"]
     port = int(argv[0]) if argv else 9090
