@@ -1,0 +1,8 @@
+"""Device step: of the traced slice's device idle time (gaps of 0.1 ms and
+more), the share during which the feeder was dispatching under the controller lock.
+Source: `siddhi.feeder.dispatch` events beside the device's, one trace."""
+import spans
+
+
+def read(run: dict):
+    return spans.idle_share_pct(run, "dispatch")

@@ -1064,21 +1064,6 @@ class SiddhiAppRuntime:
         if self.ctx.recorder is not None:
             self.ctx.recorder.close()  # detach the log-tail handler
 
-    def profile(self, n_batches: int = 32):
-        """Arm a one-shot per-query device/host time split over the next
-        `n_batches` query-step invocations (across all queries). Returns the
-        ProfileSession; call .wait() after driving traffic, then .report()
-        for {query: {batches, host_ms, device_wait_ms, device_fraction}}.
-
-        Each profiled step pays a block_until_ready() on its post-step
-        state — the device sync the steady-state pipeline avoids — which is
-        why this is a bounded one-shot, not an always-on metric."""
-        from ..telemetry.profiling import ProfileSession
-        tele = self.ctx.telemetry
-        sess = ProfileSession(tele, n_batches)
-        tele.profile = sess
-        return sess
-
     # ------------------------------------------------------------------- I/O
 
     def get_input_handler(self, stream_id: str) -> InputHandler:

@@ -368,12 +368,18 @@ class Statistics:
             # Populated below from the live pipelines (ring depth HWM,
             # worker utilization, h2d overlap ratio, per-stage wall time).
             "ingress_pipeline": {},
+            # the async read-back (core/stream.py AsyncDecoder): batches
+            # submitted and delivered, and per batch the wait for a fetch
+            # worker, the fetch and the reorder wait + callback
+            "readback": {},
         }
         if runtime is not None:
             for sid, j in runtime.junctions.items():
                 p = getattr(j, "_pipeline", None)
                 if p is not None:
                     out["ingress_pipeline"][sid] = p.stats_snapshot()
+            if runtime.ctx.decoder is not None:
+                out["readback"] = runtime.ctx.decoder.stats_snapshot()
         if runtime is not None:
             wal = getattr(runtime, "wal", None)
             if wal is not None:

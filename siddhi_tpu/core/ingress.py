@@ -50,11 +50,13 @@ import os
 import queue
 import threading
 import time
+from contextlib import contextmanager
 from typing import Optional, Sequence
 
 import numpy as np
 
 from ..errors import SiddhiAppRuntimeError
+from ..telemetry.tracing import Span, StageCells
 from ..util.locks import named_condition, named_lock, note_blocking
 
 _log = logging.getLogger("siddhi_tpu")
@@ -252,17 +254,23 @@ class IngressPipeline:
         # --- statistics (each slot has a single writer thread) ---
         self._t0 = time.monotonic()
         self._worker_busy_ns = [0] * self.workers
-        self._worker_decode_ns = [0] * self.workers
-        self._worker_intern_ns = [0] * self.workers
         self._worker_runs = [0] * self.workers
-        self._h2d_ns = 0        # feeder only
-        self._h2d_count = 0     # feeder only
-        self._device_ns = 0     # feeder only
         self._batches = 0       # feeder only
         self._overlapped = 0    # feeder only
         self._rows_in = 0       # under submit lock
         self._runs_in = 0       # under submit lock
         self._frames_in = 0     # wire path, under submit lock
+        # stage_ms: where each thread's time goes, wait told from work.
+        # Units: wire per frame; claim_wait, decode, intern, ticket_wait and
+        # intern_lock_wait per worker run; the rest per delivered batch.
+        # wire and claim_wait come from HTTP handler threads (one per
+        # connection) and are booked under the submit lock; every other
+        # stage by the one long-lived thread that runs it.
+        self.cells = StageCells(
+            ("wire", "claim_wait", "decode", "ticket_wait",
+             "intern_lock_wait", "intern", "fill", "h2d", "hold",
+             "lock_wait", "dispatch", "device"),
+            cpu=("wire", "intern", "h2d", "dispatch"))
 
     # ------------------------------------------------------------- lifecycle
 
@@ -324,6 +332,23 @@ class IngressPipeline:
             note_blocking("ring.claim_wait", allow=("ingress.submit",))
             time.sleep(0.0002)  # noqa: SL404 — blocking claim IS the backpressure
 
+    @contextmanager
+    def _claimed(self, n: int, deadline: Optional[float], frame=None):
+        """The submit lock with n slots claimed under it (see
+        _claim_blocking for the value). `claim_wait` is the producer's wait
+        for both: another producer holds the lock, or the ring is full.
+        `frame` is the wire decode's span of the frame this run opens."""
+        wait = Span("siddhi.ingress.claim_wait").begin()
+        with self._submit_lock:
+            try:
+                s = self._claim_blocking(n, deadline)
+            finally:
+                wait.end()
+                self.cells.book_shared("claim_wait", wait.wall_ns)
+            if frame is not None:
+                self.cells.book_shared("wire", frame.wall_ns, frame.cpu_ns)
+            yield s
+
     def _deadline(self) -> Optional[float]:
         bt = self.j.block_timeout_s
         return None if bt is None else time.monotonic() + bt
@@ -344,8 +369,7 @@ class IngressPipeline:
         deadline = self._deadline()
         while i < n:
             m = min(bs, n - i)
-            with self._submit_lock:
-                s = self._claim_blocking(m, deadline)
+            with self._claimed(m, deadline) as s:
                 if s == -2:
                     return i  # claimed prefix is in flight; caller owns rest
                 if s == -1:
@@ -360,12 +384,13 @@ class IngressPipeline:
         return n
 
     def submit_columns(self, ts_arr: np.ndarray, columns: dict,
-                       n: int, frame: bool = False) -> int:
+                       n: int, frame: Optional[Span] = None) -> int:
         """Producer-thread entry for the columnar/wire path. `columns` maps
         attr -> numpy array (numeric, pre-encoded int codes, or str/None
         objects) or, for wire frames, attr -> ('dict', strings, idx) where
-        idx is int32 with -1 = null — the zero-copy dictionary form.
-        Returns rows consumed; see submit_rows."""
+        idx is int32 with -1 = null — the zero-copy dictionary form; `frame`
+        is then the span of the frame's decode (io/wire.py), booked here as
+        `wire`. Returns rows consumed; see submit_rows."""
         if self._stopping or self.j._redirect is not None:
             return 0
         specs = []
@@ -400,8 +425,7 @@ class IngressPipeline:
                     run.append(("dict", a, b[i:i + m]))
                 else:
                     run.append((kind, a[i:i + m], None))
-            with self._submit_lock:
-                s = self._claim_blocking(m, deadline)
+            with self._claimed(m, deadline, frame if i == 0 else None) as s:
                 if s == -2:
                     return i
                 if s == -1:
@@ -410,7 +434,7 @@ class IngressPipeline:
                     return n
                 self._rows_in += m
                 self._runs_in += 1
-                if frame:
+                if frame is not None:
                     self._frames_in += 1
                 self._q.put(  # noqa: SL404 — unbounded queue, never blocks
                     ("cols", s, m, ts_arr[i:i + m], run))
@@ -420,7 +444,8 @@ class IngressPipeline:
     # --------------------------------------------------------------- workers
 
     def _take_ticket(self, start: int) -> None:
-        with self._ticket_cv:
+        with self.cells.span("ticket_wait", "siddhi.ingress.ticket_wait"), \
+                self._ticket_cv:
             while self._next_ticket != start:
                 self._ticket_cv.wait(timeout=0.05)
 
@@ -429,22 +454,44 @@ class IngressPipeline:
             self._next_ticket = start + n
             self._ticket_cv.notify_all()
 
+    @contextmanager
+    def _controller_locked(self, stage: str, label: str):
+        """The controller lock, the wait for it booked to `stage`."""
+        lock = self.ctx.controller_lock
+        with self.cells.span(stage, label):
+            lock.acquire()
+        try:
+            yield
+        finally:
+            lock.release()
+
     def _worker_loop(self, wid: int) -> None:
         codec = self.j.codec
         dtypes_list = self.np_dtypes
         attrs = self.attrs
         string_attrs = self._string_attrs
         ordered = self._ordered
-        clock = self.ctx.controller_lock
+        cells = self.cells
+
+        def interning():
+            # `intern` is booked once a run, below: a run may intern for
+            # several columns
+            return Span("siddhi.ingress.intern", cpu=True)
+
+        def intern_locked():
+            return self._controller_locked(
+                "intern_lock_wait", "siddhi.ingress.intern_lock_wait")
+
         while True:
             item = self._q.get()
             if item is None:
                 self._q.task_done()
                 return
+            run = Span("siddhi.ingress.worker_run", start=item[1]).begin()
             t0 = time.perf_counter_ns()
             try:
                 kind, start, m, ts, payload = item
-                intern_ns = 0
+                intern_ns = intern_cpu = 0
                 if kind == "rows":
                     if ordered:
                         # rows_to_columns interns inline (native
@@ -453,14 +500,13 @@ class IngressPipeline:
                         # StringTable is also mutated by synchronous paths
                         # that hold it
                         self._take_ticket(start)
-                        ti = time.perf_counter_ns()
                         try:
-                            with clock:
+                            with interning() as span, intern_locked():
                                 cols_d = codec.rows_to_columns(payload,
                                                                n_pad=m)
                         finally:
                             self._release_ticket(start, m)
-                        intern_ns = time.perf_counter_ns() - ti
+                        intern_ns, intern_cpu = span.wall_ns, span.cpu_ns
                         cols = tuple(cols_d[a] for a in attrs)
                     else:
                         cols_d = codec.rows_to_columns(payload, n_pad=m)
@@ -478,30 +524,33 @@ class IngressPipeline:
                                 if not took and ordered:
                                     self._take_ticket(start)
                                     took = True
-                                ti = time.perf_counter_ns()
                                 tbl = codec.string_tables[name]
-                                with clock:
+                                with interning() as span, intern_locked():
                                     codes = tbl.encode_array(a)
-                                intern_ns += time.perf_counter_ns() - ti
+                                intern_ns += span.wall_ns
+                                intern_cpu += span.cpu_ns
                                 out.append(np.ascontiguousarray(
                                     codes, dtype=dt))
                             else:  # "dict": intern DISTINCT values, take
                                 if not took and ordered:
                                     self._take_ticket(start)
                                     took = True
-                                ti = time.perf_counter_ns()
                                 tbl = codec.string_tables[name]
-                                with clock:
-                                    codes = tbl.encode_array(
-                                        np.asarray(a, dtype=object))
-                                # idx -1 = null -> code 0 via a shifted LUT
-                                lut = np.empty(len(codes) + 1,
-                                               dtype=np.int32)
-                                lut[0] = 0
-                                lut[1:] = codes
-                                out.append(np.ascontiguousarray(
-                                    lut[b.astype(np.int64) + 1], dtype=dt))
-                                intern_ns += time.perf_counter_ns() - ti
+                                with interning() as span:
+                                    with intern_locked():
+                                        codes = tbl.encode_array(
+                                            np.asarray(a, dtype=object))
+                                    # idx -1 = null -> code 0 via a shifted
+                                    # LUT
+                                    lut = np.empty(len(codes) + 1,
+                                                   dtype=np.int32)
+                                    lut[0] = 0
+                                    lut[1:] = codes
+                                    out.append(np.ascontiguousarray(
+                                        lut[b.astype(np.int64) + 1],
+                                        dtype=dt))
+                                intern_ns += span.wall_ns
+                                intern_cpu += span.cpu_ns
                     finally:
                         if ordered:
                             if not took:
@@ -510,10 +559,10 @@ class IngressPipeline:
                     cols = tuple(out)
                     ts_buf = np.ascontiguousarray(ts, dtype=np.int64)
                 self.ring.write(start, m, ts_buf, cols)
-                self._worker_intern_ns[wid] += intern_ns
                 spent = time.perf_counter_ns() - t0
+                cells.book("intern", intern_ns, intern_cpu)
+                cells.book("decode", spent - intern_ns)
                 self._worker_busy_ns[wid] += spent
-                self._worker_decode_ns[wid] += spent - intern_ns
                 self._worker_runs[wid] += 1
                 self._feeder_idle.clear()
             except Exception:  # pragma: no cover — logged, slot published 0s
@@ -527,18 +576,34 @@ class IngressPipeline:
                 except Exception:
                     pass
             finally:
+                run.end()
                 self._q.task_done()
 
     # ---------------------------------------------------------------- feeder
 
-    def _deliver_locked(self, batch, m: int) -> None:
+    def _upload(self, ts, cols: dict, n: int):
+        """Start a chunk's host->device transfer; returns (batch, h2d ns)."""
+        from .event import EventBatch
+        with self.cells.span("h2d", "siddhi.feeder.h2d") as up:
+            batch = EventBatch.from_numpy(ts, cols, n)
+        return batch, up.wall_ns
+
+    def _deliver_locked(self, batch, m: int, held_since: int = 0) -> None:
+        """Deliver one batch under the controller lock. `device` is the
+        whole of it, `lock_wait` + `dispatch`; a batch the double buffer
+        held since `held_since` books that residence as `hold`."""
         j = self.j
+        cells = self.cells
         t0 = time.perf_counter_ns()
-        with self.ctx.controller_lock:
+        if held_since:
+            cells.book("hold", t0 - held_since)
+        with self._controller_locked("lock_wait", "siddhi.feeder.lock_wait"), \
+                cells.span("dispatch", "siddhi.feeder.dispatch",
+                           chunk=self._batches):
             if j._staged_rows or j._tap_queue:
                 j.flush()  # staged (sync-path) rows first: arrival order
             j._deliver(batch, self.ctx.timestamp_generator.current_time())
-        self._device_ns += time.perf_counter_ns() - t0
+        cells.book("device", time.perf_counter_ns() - t0)
         self._batches += 1
 
     def _superstep_dispatch(self, sstack: list) -> bool:
@@ -586,16 +651,10 @@ class IngressPipeline:
     def _deliver_chunk(self, ts_buf, col_bufs, fill_t0: int) -> None:  # noqa: SL402 — feeder-thread only (called from _feed_loop / superstep fallback)
         """K=1 delivery of one staged full chunk (the superstep fallback
         path — identical to the inline full-chunk branch of _feed_loop)."""
-        from .event import EventBatch
         tele = getattr(self.ctx, "telemetry", None)
         tracing = tele is not None and tele.on
         bs = self.j.batch_size
-        t0 = time.perf_counter_ns()
-        batch = EventBatch.from_numpy(
-            ts_buf, dict(zip(self.attrs, col_bufs)), bs)
-        h2d = time.perf_counter_ns() - t0
-        self._h2d_ns += h2d
-        self._h2d_count += 1
+        batch, h2d = self._upload(ts_buf, dict(zip(self.attrs, col_bufs)), bs)
         if tracing:
             trace = tele.mint(self.j.definition.id, bs, t0=fill_t0)
             trace.h2d_ns = h2d
@@ -604,7 +663,6 @@ class IngressPipeline:
         self._deliver_locked(batch, bs)
 
     def _feed_loop(self) -> None:
-        from .event import EventBatch
         j = self.j
         bs = j.batch_size
         ring = self.ring
@@ -616,10 +674,19 @@ class IngressPipeline:
         superstep = self._ss_k > 1
         sstack: list = []  # staged full chunks awaiting one K-batch dispatch
         pending = None  # the double buffer: built + transferring, undelivered
+        pending_t0 = 0  # when it went in: `hold` is its residence there
         fill = 0
         fill_t0 = 0  # when the first row popped into the (empty) chunk
         ts_buf = np.zeros(bs, dtype=np.int64)
         col_bufs = [np.zeros(bs, dtype=dt) for dt in self.np_dtypes]
+
+        def starved():
+            # the feeder waiting for the workers to publish a batch's rows:
+            # open from the end of one chunk's handling to the next full
+            # chunk (or flush), and not while there is nothing to wait for
+            return self.cells.span("fill", "siddhi.feeder.fill").begin()
+
+        wait = starved()
         while True:
             got = ring.pop(bs - fill, ts_buf[fill:],
                            tuple(c[fill:] for c in col_bufs))
@@ -628,6 +695,7 @@ class IngressPipeline:
                     fill_t0 = time.perf_counter_ns()
                 fill += got
             if fill == bs:
+                wait.end()
                 if superstep:
                     # stage the host chunk; at K staged chunks the whole
                     # stack rides one device dispatch. The staging itself
@@ -644,15 +712,12 @@ class IngressPipeline:
                         sstack = []
                         if self._ss_decline is not None:
                             superstep = False
+                    wait = starved()
                     continue
                 # full chunk: start its H2D NOW (from_numpy = device_put),
                 # then deliver the PREVIOUS chunk while this transfer runs
-                t0 = time.perf_counter_ns()
-                batch = EventBatch.from_numpy(
-                    ts_buf, dict(zip(attrs, col_bufs)), bs)
-                h2d = time.perf_counter_ns() - t0
-                self._h2d_ns += h2d
-                self._h2d_count += 1
+                batch, h2d = self._upload(ts_buf, dict(zip(attrs, col_bufs)),
+                                          bs)
                 if tracing:
                     trace = tele.mint(sid, bs, t0=fill_t0)
                     trace.h2d_ns = h2d
@@ -663,11 +728,13 @@ class IngressPipeline:
                 fill = 0
                 if self._double_buffer:
                     if pending is not None:
-                        self._deliver_locked(pending, bs)
+                        self._deliver_locked(pending, bs, pending_t0)
                         self._overlapped += 1
                     pending = batch
+                    pending_t0 = time.perf_counter_ns()
                 else:
                     self._deliver_locked(batch, bs)
+                wait = starved()
                 continue
             if got:
                 continue  # partially filled; keep popping while data flows
@@ -683,6 +750,9 @@ class IngressPipeline:
                 # drain()/stop() barrier flushes a staged stack.
                 flushing = False
             if flushing and (fill or pending is not None or sstack):
+                # a partial chunk is a batch too; a flush of held batches
+                # alone ends no batch's wait for rows
+                wait.end(units=1 if fill else 0)
                 if sstack:
                     # partial superstep at a flush barrier: the staged
                     # chunks deliver per-batch (same step math, same state
@@ -691,7 +761,7 @@ class IngressPipeline:
                         self._deliver_chunk(c_ts, c_cols, c_t0)
                     sstack = []
                 if pending is not None:
-                    self._deliver_locked(pending, bs)
+                    self._deliver_locked(pending, bs, pending_t0)
                     pending = None
                 if fill:
                     m = fill
@@ -704,11 +774,7 @@ class IngressPipeline:
                         pad = np.zeros(pcap, dtype=src.dtype)
                         pad[:m] = src[:m]
                         cols_c[name] = pad
-                    t0 = time.perf_counter_ns()
-                    batch = EventBatch.from_numpy(ts_c, cols_c, m)
-                    h2d = time.perf_counter_ns() - t0
-                    self._h2d_ns += h2d
-                    self._h2d_count += 1
+                    batch, h2d = self._upload(ts_c, cols_c, m)
                     if tracing:
                         trace = tele.mint(sid, m, t0=fill_t0)
                         trace.h2d_ns = h2d
@@ -719,15 +785,18 @@ class IngressPipeline:
                     col_bufs = [np.zeros(bs, dtype=dt)
                                 for dt in self.np_dtypes]
                     self._deliver_locked(batch, m)
+                wait = starved()
                 continue
             if fill == 0 and pending is None and not sstack \
                     and ring.size() == 0 and self._q.unfinished_tasks == 0:
+                wait.drop()  # idle: nothing was sent, so not starved
                 self._feeder_idle.set()
                 if self._feeder_stop.is_set():
                     return
                 self._flush_req.clear()
                 self._barrier_req.clear()
                 self._flush_req.wait(timeout=0.001)
+                wait = starved()
             elif self._feeder_stop.is_set() and ring.size() == 0 \
                     and self._q.unfinished_tasks == 0:
                 # stopping with a partial chunk: force the final flush
@@ -805,21 +874,8 @@ class IngressPipeline:
             # per-stage: cumulative wall, how many units it covers, and the
             # per-unit mean — total alone made per-batch math impossible
             # (decode/intern are per worker RUN; h2d/device are per BATCH)
-            "stage_ms": {
-                "decode": _stage_cell(sum(self._worker_decode_ns),
-                                      sum(self._worker_runs)),
-                "intern": _stage_cell(sum(self._worker_intern_ns),
-                                      sum(self._worker_runs)),
-                "h2d": _stage_cell(self._h2d_ns, self._h2d_count),
-                "device": _stage_cell(self._device_ns, self._batches),
-            },
+            "stage_ms": self.cells.snapshot(),
         }
-
-
-def _stage_cell(total_ns: int, count: int) -> dict:
-    total_ms = total_ns / 1e6
-    return {"total_ms": total_ms, "batches": count,
-            "mean_ms": total_ms / count if count else 0.0}
 
 
 # ==========================================================================

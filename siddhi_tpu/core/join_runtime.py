@@ -800,7 +800,11 @@ class _JoinSideReceiver(Receiver):
 
     def on_batch(self, batch: EventBatch, now: int) -> None:
         t0 = time.perf_counter_ns()
+        compiles = self.runtime.ctx.statistics.compiles
+        traced = compiles.get(self.runtime.name, 0)
         self.runtime.on_side_batch(self.from_left, batch, now)
         tele = getattr(self.runtime.ctx, "telemetry", None)
         if tele is not None and tele.on:
-            tele.record_query(self.runtime.name, time.perf_counter_ns() - t0)
+            tele.record_query(
+                self.runtime.name, time.perf_counter_ns() - t0,
+                compiled=compiles.get(self.runtime.name, 0) != traced)

@@ -611,8 +611,10 @@ class QueryRuntime(Receiver):
                     batch.to_host_events(self.codec))
         if self._in_fallbacks:
             self._maybe_in_fallback(batch, now)
+        traced = self.ctx.statistics.compiles.get(self.name, 0)
         self.state, out = self._step(self.state, batch, jnp.int64(now),
                                      self._table_states())
+        traced = self.ctx.statistics.compiles.get(self.name, 0) != traced
         self._distribute(out, now)
         elapsed = time.perf_counter_ns() - t0
         self.ctx.statistics.track_latency(self.name, elapsed)
@@ -620,18 +622,8 @@ class QueryRuntime(Receiver):
         if meter is not None:
             meter.record(self.name, elapsed)
         tele = getattr(self.ctx, "telemetry", None)
-        if tele is not None:
-            if tele.on:
-                tele.record_query(self.name, elapsed)
-            sess = tele.profile
-            if sess is not None and sess.active:
-                # one-shot profile(): block on the post-step state to split
-                # host wall time from device execution still in flight
-                import jax
-                w0 = time.perf_counter_ns()
-                jax.block_until_ready(self.state)
-                wait = time.perf_counter_ns() - w0
-                sess.record(self.name, elapsed + wait, wait)
+        if tele is not None and tele.on:
+            tele.record_query(self.name, elapsed, compiled=traced)
         self._post_step_maintenance()
 
     def _post_step_maintenance(self) -> None:

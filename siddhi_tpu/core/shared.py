@@ -211,7 +211,9 @@ class SharedStepGroup(Receiver):
             self._emit_flags = flags
             self._step = self._make_jit(flags)
         states = tuple(m.state for m in self.members)
+        traced = self.ctx.statistics.compiles.get(self.name, 0)
         new_states, outs = self._step(states, batch, jnp.int64(now))
+        traced = self.ctx.statistics.compiles.get(self.name, 0) != traced
         # write ALL states back before any distribution: a member's output
         # cascade can re-enter this junction (and this group) synchronously
         for m, s in zip(self.members, new_states):
@@ -239,15 +241,9 @@ class SharedStepGroup(Receiver):
             if cells is None:
                 cells = self._tele_cells = [
                     tele.query_cell(n) for n in self._member_names]
-            tele.record_query_block(cells, self._member_names, share)
+            tele.record_query_block(cells, self._member_names, share,
+                                    compiled=traced)
         stats.track_latency(self.name, elapsed)
-        if tele is not None:
-            sess = tele.profile
-            if sess is not None and sess.active:
-                w0 = time.perf_counter_ns()
-                jax.block_until_ready([m.state for m in self.members])
-                wait = time.perf_counter_ns() - w0
-                sess.record(self.name, elapsed + wait, wait)
         self._batches_seen += 1
 
     # -------------------------------------------------------------- warmup

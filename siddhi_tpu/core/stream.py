@@ -24,6 +24,7 @@ from typing import Callable, Optional, Sequence
 import numpy as np
 
 from ..errors import SiddhiAppCreationError, SiddhiAppRuntimeError
+from ..telemetry.tracing import Span, StageCells
 from ..util.locks import named_condition, named_lock, note_blocking
 from ..query_api.definition import AttributeType, StreamDefinition
 from . import dtypes
@@ -247,6 +248,10 @@ class AsyncDecoder:
         self._buffer: dict = {}
         self._cv = named_condition("stream.decoder")
         self._stopping = False
+        # statistics_report()["readback"]: per batch, the wait for a fetch
+        # worker, the fetch (the wait for the step to end + D2H + unpack)
+        # and the reorder wait + callback
+        self.cells = StageCells(("queue", "fetch", "deliver"))
         self._deliverer = threading.Thread(
             target=self._deliver_loop, daemon=True, name="siddhi-decoder")
         self._threads = [
@@ -261,23 +266,26 @@ class AsyncDecoder:
                junction: "StreamJunction" = None) -> None:
         import jax
         global _wire_pack_jit
-        payload = batch
-        if self._pack:
-            if _wire_pack_jit is None:
-                _wire_pack_jit = jax.jit(_wire_pack)
-            payload = (_wire_pack_jit(batch), batch)
-        for leaf in jax.tree_util.tree_leaves(
-                payload[0] if isinstance(payload, tuple) else payload):
-            start = getattr(leaf, "copy_to_host_async", None)
-            if start is not None:
-                start()
-        # the bounded put may block under the controller lock; safe
-        # because decoder threads never block unboundedly on that lock
-        # (the @OnError path acquires it with a timeout) so the queue
-        # always drains — see docs/CONCURRENCY.md
-        note_blocking("queue.put", allow=("app.controller",))
-        self._q.put((self._seq, receiver, payload, now, junction))
-        self._seq += 1
+        # nests in the span of the dispatch that caused it (the feeder's)
+        with Span("siddhi.readback.submit", seq=self._seq):
+            payload = batch
+            if self._pack:
+                if _wire_pack_jit is None:
+                    _wire_pack_jit = jax.jit(_wire_pack)
+                payload = (_wire_pack_jit(batch), batch)
+            for leaf in jax.tree_util.tree_leaves(
+                    payload[0] if isinstance(payload, tuple) else payload):
+                start = getattr(leaf, "copy_to_host_async", None)
+                if start is not None:
+                    start()
+            # the bounded put may block under the controller lock; safe
+            # because decoder threads never block unboundedly on that lock
+            # (the @OnError path acquires it with a timeout) so the queue
+            # always drains — see docs/CONCURRENCY.md
+            note_blocking("queue.put", allow=("app.controller",))
+            self._q.put((self._seq, receiver, payload, now, junction,
+                         time.perf_counter_ns()))
+            self._seq += 1
 
     @staticmethod
     def _fetch(payload):
@@ -295,10 +303,13 @@ class AsyncDecoder:
             item = self._q.get()
             if item is None:
                 return
-            seq, receiver, payload, now, junction = item
+            seq, receiver, payload, now, junction, queued_ns = item
+            self.cells.book("queue", time.perf_counter_ns() - queued_ns)
             err = None
             try:
-                host = self._fetch(payload)
+                with self.cells.span("fetch", "siddhi.readback.fetch",
+                                     seq=seq):
+                    host = self._fetch(payload)
             except Exception as e:  # noqa: BLE001 — routed by the deliverer
                 # a failed readback takes the failed-callback route, in
                 # submit order; the sequence number must still publish or
@@ -319,7 +330,8 @@ class AsyncDecoder:
                     if not self._deliverer.is_alive():
                         return
                     self._cv.wait(timeout=0.2)
-                self._buffer[seq] = (receiver, host, now, junction, err)
+                self._buffer[seq] = (receiver, host, now, junction, err,
+                                     time.perf_counter_ns())
                 self._cv.notify_all()
 
     def _deliver_loop(self) -> None:
@@ -330,13 +342,17 @@ class AsyncDecoder:
                     self._cv.wait(timeout=0.2)
                 if self._stopping and self._deliver_next not in self._buffer:
                     return
-                receiver, host, now, junction, err = self._buffer.pop(
-                    self._deliver_next)
+                receiver, host, now, junction, err, fetched_ns = \
+                    self._buffer.pop(self._deliver_next)
+                seq = self._deliver_next
                 self._deliver_next += 1
             try:
                 if err is not None:
                     raise err
-                receiver.on_batch(host, now)
+                with Span("siddhi.readback.callback", seq=seq):
+                    receiver.on_batch(host, now)
+                self.cells.book("deliver",
+                                time.perf_counter_ns() - fetched_ns)
             except Exception as e:  # noqa: BLE001 — async path must not die
                 what = ("async readback failed" if err is not None
                         else "async stream callback failed")
@@ -394,6 +410,10 @@ class AsyncDecoder:
                         f"({len(self._buffer)} fetched out of order, "
                         f"{self._q.qsize()} queued)")
                 self._cv.wait(timeout=0.2)
+
+    def stats_snapshot(self) -> dict:
+        return {"stage_ms": self.cells.snapshot(), "submitted": self._seq,
+                "delivered": self._deliver_next}
 
     def stop(self) -> None:
         """Drain, then stop the threads. A drain that raises still tears

@@ -493,8 +493,7 @@ class SuperstepRunner:
         cols_k = {a: jnp.asarray(np.stack([s[1][ai] for s in slots]))
                   for ai, a in enumerate(pipe.attrs)}
         h2d = time.perf_counter_ns() - t0
-        pipe._h2d_ns += h2d
-        pipe._h2d_count += K
+        pipe.cells.book("h2d", h2d, units=K)
         traces = None
         if tracing:
             traces = []
@@ -542,7 +541,7 @@ class SuperstepRunner:
                 raise
             dev = time.perf_counter_ns() - d0
             pipe._ss_replay_ns += dev - scan_ns
-            pipe._device_ns += dev
+            pipe.cells.book("device", dev, units=K)
             pipe._batches += K
         return True
 

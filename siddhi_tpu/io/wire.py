@@ -42,6 +42,8 @@ from typing import Iterator, Optional, Sequence
 
 import numpy as np
 
+from ..telemetry.tracing import Span
+
 MAGIC = b"SXF1"
 FLAG_HAS_TS = 0x01
 
@@ -277,7 +279,10 @@ def deliver_frames(handler, body) -> int:
     plan = schema_plan(j.definition)
     total = 0
     for payload in iter_frames(body):
-        ts, cols, n = decode_frame(payload, plan)
+        # the decode runs in the HTTP handler's thread, a Python `str` per
+        # dictionary entry: the pipeline books it as its `wire` stage
+        with Span("siddhi.front.wire", cpu=True) as decode:
+            ts, cols, n = decode_frame(payload, plan)
         if n == 0:
             continue
         if ts is None:
@@ -287,7 +292,7 @@ def deliver_frames(handler, body) -> int:
         if p is not None and j.wal is None and not j.taps \
                 and not j._lock_owned():
             j.ctx.timestamp_generator.observe_event_time(int(ts[:n].max()))
-            done = p.submit_columns(ts, cols, n, frame=True)
+            done = p.submit_columns(ts, cols, n, frame=decode)
             if done >= n:
                 total += n
                 continue

@@ -11,12 +11,15 @@ The package threads one measurement substrate through the whole pipeline:
                  at ingress and carried through delivery, per-stage span
                  timings (accept→stage→H2D→device→sink) into per-stage
                  histograms, plus a bounded worst-N slow-batch exemplar ring
-                 surfaced in statistics_report()["slow_batches"].
+                 surfaced in statistics_report()["slow_batches"]; and the
+                 stage spans (`Span`, `StageCells`) that tell wait from work
+                 per thread of the served path: cumulative `stage_ms` cells
+                 and `siddhi.*` events on the profiler's clock.
   prometheus.py  text-exposition rendering for GET /metrics (hand-rolled —
                  no prometheus_client dependency) + a conformance validator
                  used by tests and the CI smoke.
-  profiling.py   SIDDHI_PROFILE=<dir> jax.profiler trace capture and the
-                 SiddhiAppRuntime.profile(n_batches) host/device time split.
+  profiling.py   SIDDHI_PROFILE=<dir> jax.profiler trace capture; the
+                 `siddhi.*` stage spans of tracing.py show inside it.
   logs.py        SIDDHI_LOG_FORMAT=json one-line structured log records.
   slo.py         declarative objectives (@app:slo / @slo) evaluated with
                  multi-window burn rates on a virtual-clock-testable engine

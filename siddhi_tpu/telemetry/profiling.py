@@ -1,28 +1,17 @@
-"""Profiling hooks: SIDDHI_PROFILE trace capture + per-query time splits.
+"""SIDDHI_PROFILE=<dir>: the operator's way to open a profiler session.
 
-Two independent mechanisms:
-
-SIDDHI_PROFILE=<dir>
-    When set, the first SiddhiAppRuntime.start() in the process opens a
-    jax.profiler trace into <dir> (viewable in TensorBoard / Perfetto) and
-    the runtime that opened it closes it on shutdown. One trace per
-    process — concurrent apps share the capture.
-
-SiddhiAppRuntime.profile(n_batches)
-    One-shot, per-app: arms a ProfileSession on ctx.telemetry.profile.
-    For the next `n_batches` query-step invocations each query runtime
-    records (host wall, device wait) where device wait is measured by a
-    block_until_ready() on the post-step state — the synchronization the
-    steady-state pipeline deliberately avoids, which is exactly why this is
-    a bounded one-shot and not an always-on metric. report() returns the
-    host/device split per query.
+When set, the first SiddhiAppRuntime.start() in the process opens a
+jax.profiler trace into <dir> (viewable in TensorBoard / Perfetto) and the
+runtime that opened it closes it on shutdown. One trace per process —
+concurrent apps share the capture. Inside it the served path's `siddhi.*`
+stage spans (telemetry/tracing.py `Span`) lie in `/host:CPU` beside the
+device's own planes, on one clock, with no device sync added.
 """
 
 from __future__ import annotations
 
 import logging
 import os
-import threading
 from typing import Optional
 
 from ..util.locks import named_lock
@@ -67,62 +56,3 @@ def stop_jax_profiler() -> None:
         except Exception as e:  # pragma: no cover
             log.warning("jax.profiler stop_trace failed: %s", e)
         _jax_trace_dir = None
-
-
-class ProfileSession:
-    """Bounded per-query host/device split, armed on telemetry.profile."""
-
-    def __init__(self, telemetry, n_batches: int = 32) -> None:
-        self._telemetry = telemetry
-        self.n_batches = int(n_batches)
-        self._remaining = self.n_batches
-        self._lock = named_lock("telemetry.profile.session")
-        self._done = threading.Event()
-        self._per_query: dict[str, list] = {}  # [batches, host_ns, wait_ns]
-        if self._remaining <= 0:
-            self._done.set()
-
-    @property
-    def active(self) -> bool:
-        return not self._done.is_set()
-
-    def record(self, query: str, host_ns: int, device_wait_ns: int) -> None:
-        with self._lock:
-            if self._done.is_set():
-                return
-            cell = self._per_query.get(query)
-            if cell is None:
-                cell = self._per_query[query] = [0, 0, 0]
-            cell[0] += 1
-            cell[1] += host_ns
-            cell[2] += device_wait_ns
-            self._remaining -= 1
-            if self._remaining <= 0:
-                self._disarm()
-
-    def _disarm(self) -> None:
-        if self._telemetry is not None and self._telemetry.profile is self:
-            self._telemetry.profile = None
-        self._done.set()
-
-    def stop(self) -> None:
-        with self._lock:
-            self._disarm()
-
-    def wait(self, timeout: Optional[float] = None) -> bool:
-        return self._done.wait(timeout)
-
-    def report(self) -> dict:
-        """{query: {batches, host_ms, device_wait_ms, device_fraction}} —
-        host_ms includes the device wait (it is wall time on the controller
-        thread); device_fraction = wait / host."""
-        with self._lock:
-            out = {}
-            for q, (n, host, wait) in sorted(self._per_query.items()):
-                out[q] = {
-                    "batches": n,
-                    "host_ms": host / 1e6,
-                    "device_wait_ms": wait / 1e6,
-                    "device_fraction": (wait / host) if host else 0.0,
-                }
-            return out

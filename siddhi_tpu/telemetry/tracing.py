@@ -22,6 +22,10 @@ Stage model (all spans in ns, recorded into per-stage histograms):
            query's own distribution, so the raw query span contains the
            sink span; subtracting it keeps device + sink additive and lets
            the doctor attribute a slow consumer to `sink`, not `device`
+  compile  the wall of step calls that traced and compiled (a shape's first
+           batch). Host work, and not a `device` span: one compile in a
+           short history would otherwise be that stage's p99 and outweigh
+           the stage that really breaches an objective
   sink     sum of Sink.publish_rows wall time inside the fan-out, credited
            to EVERY trace on the active stack (the derived output stream's
            trace and the ingress trace it is nested under)
@@ -33,6 +37,17 @@ breakdown, query names, and batch size — statistics_report()
 (`recent_summaries()`) exists for tests asserting ID propagation; both
 are O(1) per batch (summary dicts are built on read, not on the hot
 path).
+
+Stage spans (`Span`, `StageCells`): the served path's threads tell wait
+from work with one primitive. A span adds its wall ns (and, where asked,
+its thread's CPU ns: wall - CPU is time the thread did not run, waiting for
+the interpreter lock, a lock or I/O) and one unit to a cumulative cell, and
+opens a `jax.profiler.TraceAnnotation` over the same interval, so inside
+any profiler session (SIDDHI_PROFILE, a benchmark's slice) it lies in
+`/host:CPU` of the same xplane as the device ops, on one clock. Always on;
+entered per frame, per worker run or per batch, never per row. The cells
+are what `statistics_report()` shows as `ingress_pipeline.<stream>.stage_ms`
+and `readback.stage_ms`.
 """
 
 from __future__ import annotations
@@ -44,6 +59,8 @@ import time
 from collections import deque
 from typing import Optional
 
+from jax.profiler import TraceAnnotation
+
 from ..util.locks import named_lock
 from .metrics import Histogram, MetricsRegistry, bucket_index
 
@@ -53,9 +70,114 @@ SLOW_RING = 8
 RECENT_RING = 64
 
 
+class Span:
+    """One timed interval: `with span:` or, for an interval shaped by a
+    loop, `begin()` ... `end()`. After its end `wall_ns` (and `cpu_ns` with
+    `cpu=True`) say how long it was; a span made by `StageCells.span` also
+    books them to its cell. `label` and `ids` name the profiler event and
+    its stats."""
+
+    __slots__ = ("wall_ns", "cpu_ns", "_cell", "_cpu", "_ann", "_t0", "_c0")
+
+    def __init__(self, label: str, cpu: bool = False, cell=None,
+                 **ids) -> None:
+        self._ann = TraceAnnotation(label, **ids)
+        self._cpu = cpu
+        self._cell = cell
+        self.wall_ns = self.cpu_ns = 0
+
+    def begin(self) -> "Span":
+        self._ann.__enter__()
+        self._t0 = time.perf_counter_ns()
+        if self._cpu:  # read inside the wall's interval: CPU <= wall
+            self._c0 = time.thread_time_ns()
+        return self
+
+    def end(self, units: int = 1) -> None:
+        if self._cpu:
+            self.cpu_ns = time.thread_time_ns() - self._c0
+        self.wall_ns = time.perf_counter_ns() - self._t0
+        self._ann.__exit__(None, None, None)
+        if self._cell is not None:
+            _add(self._cell, self.wall_ns, units, self.cpu_ns)
+
+    def drop(self) -> None:
+        """Close the span and book nothing (the interval was not what the
+        cell counts: the feeder idle, not starved)."""
+        self._ann.__exit__(None, None, None)
+
+    __enter__ = begin
+
+    def __exit__(self, *exc) -> None:
+        self.end()
+
+
+def _add(cell: list, wall_ns: int, units: int, cpu_ns: int) -> None:
+    cell[0] += wall_ns
+    cell[1] += units
+    cell[2] += cpu_ns
+
+
+class StageCells:
+    """Cumulative wall ns, units and CPU ns per named stage. Every writer
+    thread owns a slot (single writer, no lock on the hot path) and
+    `snapshot()` sums the slots. Threads that do not live long (one HTTP
+    handler per connection) must not grow a slot each: they book through
+    `book_shared`, under a lock of the caller's."""
+
+    def __init__(self, stages, cpu=()) -> None:
+        self._stages = tuple(stages)
+        self._cpu = frozenset(cpu)
+        self._shared = self._new_slot()
+        self._slots = {None: self._shared}
+
+    def _new_slot(self) -> dict:
+        return {s: [0, 0, 0] for s in self._stages}
+
+    def _slot(self) -> dict:
+        me = threading.get_ident()
+        slot = self._slots.get(me)
+        if slot is None:
+            slot = self._slots[me] = self._new_slot()
+        return slot
+
+    def span(self, stage: str, label: str, **ids) -> Span:
+        """A span of the calling thread that books to `stage` at its end."""
+        return Span(label, stage in self._cpu, self._slot()[stage], **ids)
+
+    def book(self, stage: str, wall_ns: int, cpu_ns: int = 0,
+             units: int = 1) -> None:
+        """`units` of `stage`, timed by the calling thread itself."""
+        _add(self._slot()[stage], wall_ns, units, cpu_ns)
+
+    def book_shared(self, stage: str, wall_ns: int, cpu_ns: int = 0) -> None:
+        """`book` for short-lived threads; the caller holds the one lock
+        under which this stage is booked."""
+        _add(self._shared[stage], wall_ns, 1, cpu_ns)
+
+    def snapshot(self) -> dict:
+        """stage -> {total_ms, batches (units), mean_ms[, cpu_ms]}."""
+        out = {}
+        slots = list(self._slots.values())
+        for stage in self._stages:
+            wall = units = cpu = 0
+            for slot in slots:
+                w, n, c = slot[stage]
+                wall += w
+                units += n
+                cpu += c
+            total_ms = wall / 1e6
+            out[stage] = {"total_ms": total_ms, "batches": units,
+                          "mean_ms": total_ms / units if units else 0.0}
+            if stage in self._cpu:
+                out[stage]["cpu_ms"] = cpu / 1e6
+        return out
+
+
 class BatchTrace:
     __slots__ = ("batch_id", "stream", "size", "t0", "h2d_ns", "device_ns",
-                 "sink_ns", "deliver_t0", "queries", "superstep")
+                 "compile_ns", "sink_ns", "deliver_t0", "queries",
+                 "superstep")
 
     def __init__(self, batch_id: int, stream: str, size: Optional[int],
                  t0: int) -> None:
@@ -65,6 +187,7 @@ class BatchTrace:
         self.t0 = t0
         self.h2d_ns = 0
         self.device_ns = 0
+        self.compile_ns = 0
         self.sink_ns = 0
         self.deliver_t0 = 0
         self.queries: list[str] = []
@@ -72,12 +195,20 @@ class BatchTrace:
         #: per-batch dispatch — the trace stays per INNER batch either way
         self.superstep = 0
 
+    def exclusive(self) -> tuple:
+        """(device ns, compile ns) exclusive of sink: sink publishes run
+        nested inside query spans, so taking them out keeps the stage
+        shares additive. Out of `device` first; a batch whose only step
+        call compiled holds its sink span inside `compile`."""
+        device = self.device_ns - self.sink_ns
+        if device >= 0:
+            return device, self.compile_ns
+        return 0, max(self.compile_ns + device, 0)
+
     def summary(self, t_end: int) -> dict:
         e2e = t_end - self.t0
         stage = max(self.deliver_t0 - self.t0 - self.h2d_ns, 0)
-        # sink publishes run nested inside query spans: report device
-        # exclusive of sink so the stage shares stay additive
-        device = max(self.device_ns - self.sink_ns, 0)
+        device, compile_ns = self.exclusive()
         out = {
             "batch_id": self.batch_id,
             "stream": self.stream,
@@ -88,6 +219,7 @@ class BatchTrace:
                 "stage": stage / 1e6,
                 "h2d": self.h2d_ns / 1e6,
                 "device": device / 1e6,
+                "compile": compile_ns / 1e6,
                 "sink": self.sink_ns / 1e6,
             },
         }
@@ -97,9 +229,8 @@ class BatchTrace:
 
 
 class AppTelemetry:
-    """Per-app telemetry façade: the metrics registry, the batch tracer
-    state, and the (usually-None) profiling session. Attached to
-    SiddhiAppContext.telemetry by the app runtime."""
+    """Per-app telemetry façade: the metrics registry and the batch tracer
+    state. Attached to SiddhiAppContext.telemetry by the app runtime."""
 
     def __init__(self, app_name: str, enabled: Optional[bool] = None) -> None:
         from . import telemetry_enabled
@@ -118,7 +249,7 @@ class AppTelemetry:
             ("stream",))
         self.stage_hist = r.histogram(
             "siddhi_stage_latency_seconds",
-            "Per-stage batch latency (stage|h2d|device|sink|e2e)",
+            "Per-stage batch latency (stage|h2d|device|compile|sink|e2e)",
             ("stream", "stage"))
         self.query_hist = r.histogram(
             "siddhi_query_latency_seconds",
@@ -172,8 +303,6 @@ class AppTelemetry:
         self._slow_floor = 0.0  # cheapest e2e_ms in a full ring (fast reject)
         self._slow_lock = named_lock("telemetry.trace.slow")
         self.recent: deque = deque(maxlen=RECENT_RING)  # (trace, t_end_ns)
-        #: armed by SiddhiAppRuntime.profile(); checked by query runtimes
-        self.profile = None
         # per-series child caches: Family.labels() is a guarded dict walk,
         # and pop_active touches seven series per delivery — resolving them
         # once per stream keeps the always-on path in single-dict-get
@@ -223,9 +352,11 @@ class AppTelemetry:
         stage_c.observe_ns(stage_ns if stage_ns > 0 else 0)
         if trace.h2d_ns:
             h2d_c.observe_ns(trace.h2d_ns)
-        device_ns = trace.device_ns - trace.sink_ns  # sink nests in query spans
+        device_ns, compile_ns = trace.exclusive()
         if device_ns > 0:
             device_c.observe_ns(device_ns)
+        if compile_ns:  # rare: its cell is resolved when it happens
+            self.stage_hist.labels(stream, "compile").observe_ns(compile_ns)
         if trace.sink_ns:
             sink_c.observe_ns(trace.sink_ns)
         e2e_ns = t_end - trace.t0
@@ -252,14 +383,20 @@ class AppTelemetry:
 
     # ------------------------------------------------------------ span hooks
 
-    def record_query(self, query: str, ns: int) -> None:
+    def record_query(self, query: str, ns: int,
+                     compiled: bool = False) -> None:
+        """One step call's wall; `compiled` when the call traced and
+        compiled, which books it as the batch's `compile`, not `device`."""
         h = self._query_cells.get(query)
         if h is None:
             h = self._query_cells[query] = self.query_hist.labels(query)
         h.observe_ns(ns)
         tr = self.active()
         if tr is not None:
-            tr.device_ns += ns
+            if compiled:
+                tr.compile_ns += ns
+            else:
+                tr.device_ns += ns
             tr.queries.append(query)
 
     def query_cell(self, query: str):
@@ -270,18 +407,22 @@ class AppTelemetry:
             h = self._query_cells[query] = self.query_hist.labels(query)
         return h
 
-    def record_query_block(self, cells, names, ns: int) -> None:
+    def record_query_block(self, cells, names, ns: int,
+                           compiled: bool = False) -> None:
         """Bulk `record_query` for one fused group: every member reports
         the same share `ns` of the group's measured span, so the bucket
         index is computed once and the cells (from `query_cell`) are
         observed directly. Series produced are identical to calling
-        `record_query(name, ns)` per member."""
+        `record_query(name, ns, compiled)` per member."""
         bi = bucket_index(ns)
         for h in cells:
             h.observe_ns_at(bi, ns)
         tr = self.active()
         if tr is not None:
-            tr.device_ns += ns * len(names)
+            if compiled:
+                tr.compile_ns += ns * len(names)
+            else:
+                tr.device_ns += ns * len(names)
             tr.queries.extend(names)
 
     def record_splice(self, kind: str, ms=None) -> None:

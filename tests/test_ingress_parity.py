@@ -168,7 +168,10 @@ class TestBitParity:
         assert seen["rows_in"] == 200
         assert seen["batches_delivered"] >= 1
         assert seen["ring_depth_hwm"] >= 1
-        assert set(seen["stage_ms"]) == {"decode", "intern", "h2d", "device"}
+        assert set(seen["stage_ms"]) == {
+            "wire", "claim_wait", "decode", "ticket_wait",
+            "intern_lock_wait", "intern", "fill", "h2d", "hold",
+            "lock_wait", "dispatch", "device"}
 
     def test_fallback_ring_selected_without_native(self):
         """With SIDDHI_NATIVE=0 (or the C module missing) the pipeline must

@@ -206,7 +206,9 @@ class TestBatchTracing:
         e2es = [b["e2e_ms"] for b in slow]
         assert e2es == sorted(e2es, reverse=True)
         assert set(slow[0]["stages_ms"]) == {"stage", "h2d", "device",
-                                             "sink"}
+                                             "compile", "sink"}
+        # a shape's first batch compiles: host work, not a `device` span
+        assert slow[0]["stages_ms"]["compile"] > slow[0]["stages_ms"]["device"]
         assert json.dumps(rep)  # report stays JSON-serializable
         rt.shutdown()
 
@@ -263,9 +265,10 @@ class TestPipelineTracing:
             p = rt.junctions["TradeStream"]._pipeline
             assert p is not None
             stage = p.stats_snapshot()["stage_ms"]
-            assert set(stage) == {"decode", "intern", "h2d", "device"}
+            assert {"decode", "intern", "h2d", "device"} <= set(stage)
             for name, cell in stage.items():
-                assert set(cell) == {"total_ms", "batches", "mean_ms"}, name
+                assert set(cell) - {"cpu_ms"} == {"total_ms", "batches",
+                                                  "mean_ms"}, name
                 assert cell["total_ms"] >= 0
                 if cell["batches"]:
                     assert cell["mean_ms"] == pytest.approx(
@@ -417,34 +420,6 @@ class TestOverheadGuard:
 # ------------------------------------------------------------------ profiling
 
 class TestProfiling:
-    def test_profile_reports_host_device_split(self):
-        rt = build("@app:name('pf')\n" + S
-                   + "@info(name='q') from S select symbol insert into Out;",
-                   batch_size=8)
-        sess = rt.profile(n_batches=3)
-        assert sess.active
-        h = rt.get_input_handler("S")
-        for i in range(32):
-            h.send(("A", float(i)))
-        rt.flush()
-        assert sess.wait(5.0)            # auto-disarmed after 3 batches
-        assert rt.ctx.telemetry.profile is None
-        rep = sess.report()
-        assert rep["q"]["batches"] == 3
-        assert rep["q"]["host_ms"] > 0
-        assert 0.0 <= rep["q"]["device_fraction"] <= 1.0
-        rt.shutdown()
-
-    def test_profile_stop_is_idempotent(self):
-        rt = build(S + "from S select symbol insert into Out;")
-        sess = rt.profile(n_batches=100)
-        sess.stop()
-        sess.stop()
-        assert not sess.active
-        assert rt.ctx.telemetry.profile is None
-        assert sess.report() == {}
-        rt.shutdown()
-
     def test_maybe_start_without_env_is_noop(self, monkeypatch):
         from siddhi_tpu.telemetry.profiling import maybe_start_jax_profiler
         monkeypatch.delenv("SIDDHI_PROFILE", raising=False)
