@@ -485,6 +485,67 @@ map_codes(PyObject *self, PyObject *args)
     return result;
 }
 
+/* decode_dict(buf: bytes-like, offset, dict_n) -> (values: list[str], end)
+ *
+ * One SXF1 string column's dictionary block (io/wire.py): dict_n entries,
+ * each a little-endian u16 byte length and that many UTF-8 bytes, from
+ * `offset` in `buf`; `end` is the offset after the last entry. The wire
+ * decode runs in the HTTP handler's thread and holds the interpreter for as
+ * long as it lasts: 123k entries cost the Python loop ~60 ms of it, this
+ * one a few. Every header and body is checked against the buffer, and
+ * dict_n against what the buffer could hold before the list is made;
+ * ValueError (UnicodeDecodeError for bytes that are not UTF-8) otherwise. */
+static PyObject *
+decode_dict(PyObject *self, PyObject *args)
+{
+    Py_buffer buf;
+    Py_ssize_t off, dict_n;
+    if (!PyArg_ParseTuple(args, "y*nn", &buf, &off, &dict_n))
+        return NULL;
+    const unsigned char *data = (const unsigned char *)buf.buf;
+    Py_ssize_t total = buf.len;
+    PyObject *values = NULL;
+    if (off < 0 || off > total || dict_n < 0 || dict_n > (total - off) / 2) {
+        /* an entry is at least its two-byte header */
+        PyErr_Format(PyExc_ValueError,
+                     "dictionary of %zd entries cannot fit in the %zd bytes "
+                     "after offset %zd", dict_n, total - off, off);
+        goto fail;
+    }
+    values = PyList_New(dict_n);
+    if (values == NULL)
+        goto fail;
+    for (Py_ssize_t i = 0; i < dict_n; i++) {
+        if (total - off < 2) {
+            PyErr_Format(PyExc_ValueError,
+                         "dictionary entry %zd of %zd: header runs past the "
+                         "end of the payload", i, dict_n);
+            goto fail;
+        }
+        Py_ssize_t blen = (Py_ssize_t)data[off]
+                          | ((Py_ssize_t)data[off + 1] << 8);
+        off += 2;
+        if (total - off < blen) {
+            PyErr_Format(PyExc_ValueError,
+                         "dictionary entry %zd of %zd: %zd bytes run past "
+                         "the end of the payload", i, dict_n, blen);
+            goto fail;
+        }
+        PyObject *s = PyUnicode_DecodeUTF8((const char *)data + off, blen,
+                                           "strict");
+        if (s == NULL)
+            goto fail;
+        PyList_SET_ITEM(values, i, s);
+        off += blen;
+    }
+    PyBuffer_Release(&buf);
+    return Py_BuildValue("(Nn)", values, off);
+fail:
+    Py_XDECREF(values);
+    PyBuffer_Release(&buf);
+    return NULL;
+}
+
 /* build_events(event_cls, ts: int64 buffer, expired: uint8 buffer,
  *              cols: tuple[list]) -> list[Event]
  *
@@ -1104,6 +1165,8 @@ static PyMethodDef methods[] = {
      "radix_argsort(keys_i32, out_i32): stable LSD radix argsort"},
     {"map_codes", map_codes, METH_VARARGS,
      "Decode an int32 code buffer through a string table list."},
+    {"decode_dict", decode_dict, METH_VARARGS,
+     "Decode an SXF1 dictionary block into (list[str], end offset)."},
     {"build_events", build_events, METH_VARARGS,
      "Construct a list of Event objects from decoded columns."},
     {"ring_new", ring_new, METH_VARARGS,

@@ -260,6 +260,7 @@ class IngressPipeline:
         self._rows_in = 0       # under submit lock
         self._runs_in = 0       # under submit lock
         self._frames_in = 0     # wire path, under submit lock
+        self._wire_native_frames = 0  # of them, decoded by the extension
         # stage_ms: where each thread's time goes, wait told from work.
         # Units: wire per frame; claim_wait, decode, intern, ticket_wait and
         # intern_lock_wait per worker run; the rest per delivered batch.
@@ -384,13 +385,16 @@ class IngressPipeline:
         return n
 
     def submit_columns(self, ts_arr: np.ndarray, columns: dict,
-                       n: int, frame: Optional[Span] = None) -> int:
+                       n: int, frame: Optional[Span] = None,
+                       frame_native: bool = False) -> int:
         """Producer-thread entry for the columnar/wire path. `columns` maps
         attr -> numpy array (numeric, pre-encoded int codes, or str/None
         objects) or, for wire frames, attr -> ('dict', strings, idx) where
         idx is int32 with -1 = null — the zero-copy dictionary form; `frame`
         is then the span of the frame's decode (io/wire.py), booked here as
-        `wire`. Returns rows consumed; see submit_rows."""
+        `wire`, and `frame_native` says the extension decoded its
+        dictionaries (`wire_native_frames`). Returns rows consumed; see
+        submit_rows."""
         if self._stopping or self.j._redirect is not None:
             return 0
         specs = []
@@ -436,6 +440,7 @@ class IngressPipeline:
                 self._runs_in += 1
                 if frame is not None:
                     self._frames_in += 1
+                    self._wire_native_frames += frame_native
                 self._q.put(  # noqa: SL404 — unbounded queue, never blocks
                     ("cols", s, m, ts_arr[i:i + m], run))
             i += m
@@ -866,6 +871,7 @@ class IngressPipeline:
             "rows_in": self._rows_in,
             "runs_in": self._runs_in,
             "frames_in": self._frames_in,
+            "wire_native_frames": self._wire_native_frames,
             "batches_delivered": delivered,
             "batches_overlapped": self._overlapped,
             "h2d_overlap_ratio": (self._overlapped / delivered

@@ -42,6 +42,7 @@ from typing import Iterator, Optional, Sequence
 
 import numpy as np
 
+from .. import native as native_mod
 from ..telemetry.tracing import Span
 
 MAGIC = b"SXF1"
@@ -178,11 +179,44 @@ def iter_frames(body) -> Iterator[memoryview]:
         off += plen
 
 
+def _decode_dict_py(mv: memoryview, off: int,
+                    dict_n: int) -> tuple[list, int]:
+    """One string column's dictionary block without the extension:
+    `dict_n` entries of (u16 byte_len | utf8 bytes) from `off` in `mv` ->
+    (values: list[str], offset after the last entry). Same bounds, same
+    refusals (ValueError) as native/columnar.c decode_dict."""
+    total = len(mv)
+    if off < 0 or not 0 <= dict_n <= (total - off) // 2:
+        # an entry is at least its two-byte header
+        raise ValueError(
+            f"dictionary of {dict_n} entries cannot fit in the "
+            f"{total - off} bytes after offset {off}")
+    values: list = []
+    for i in range(dict_n):
+        if total - off < 2:
+            raise ValueError(f"dictionary entry {i} of {dict_n}: header "
+                             "runs past the end of the payload")
+        (blen,) = struct.unpack_from("<H", mv, off)
+        off += 2
+        if total - off < blen:  # a memoryview slice would come back short
+            raise ValueError(f"dictionary entry {i} of {dict_n}: {blen} "
+                             "bytes run past the end of the payload")
+        values.append(str(mv[off:off + blen], "utf-8"))
+        off += blen
+    return values, off
+
+
+#: a dictionary block's decoder: the extension's loop when it is loaded
+#: (decided at import, like the ring and intern_column), else the one above
+_decode_dict = (native_mod.native.decode_dict
+                if native_mod.native is not None else _decode_dict_py)
+
+
 def decode_frame(payload: memoryview, plan) -> tuple[
         Optional[np.ndarray], dict, int]:
     """Decode one payload against `plan`. Returns (ts or None, columns, n)
     where numeric columns are zero-copy views over the payload and string
-    columns are ('dict', values: list[str|None], idx: int32 view) triples —
+    columns are ('dict', values: list[str], idx: int32 view) triples —
     exactly what IngressPipeline.submit_columns takes."""
     mv = memoryview(payload)
     if len(mv) < 11 or bytes(mv[:4]) != MAGIC:
@@ -209,14 +243,14 @@ def decode_frame(payload: memoryview, plan) -> tuple[
             raise WireFormatError(
                 f"column {name!r}: frame typecode {got!r} != schema {code!r}")
         if code == "s":
+            if len(mv) < off + 4:
+                raise WireFormatError(
+                    f"truncated dictionary header for {name!r}")
             (dict_n,) = struct.unpack_from("<I", mv, off)
-            off += 4
-            values: list = []
-            for _ in range(dict_n):
-                (blen,) = struct.unpack_from("<H", mv, off)
-                off += 2
-                values.append(str(mv[off:off + blen], "utf-8"))
-                off += blen
+            try:
+                values, off = _decode_dict(mv, off + 4, dict_n)
+            except ValueError as e:  # a bound, or bytes that are not UTF-8
+                raise WireFormatError(f"column {name!r}: {e}") from e
             end = off + 4 * n
             if len(mv) < end:
                 raise WireFormatError(f"truncated index block for {name!r}")
@@ -278,9 +312,12 @@ def deliver_frames(handler, body) -> int:
     j = handler.junction
     plan = schema_plan(j.definition)
     total = 0
+    by_extension = _decode_dict is not _decode_dict_py
     for payload in iter_frames(body):
-        # the decode runs in the HTTP handler's thread, a Python `str` per
-        # dictionary entry: the pipeline books it as its `wire` stage
+        # the decode runs in the HTTP handler's thread and holds the
+        # interpreter while it makes a `str` per dictionary entry (one call
+        # into the extension, or the Python loop without it): the pipeline
+        # books it as its `wire` stage and counts which of the two it was
         with Span("siddhi.front.wire", cpu=True) as decode:
             ts, cols, n = decode_frame(payload, plan)
         if n == 0:
@@ -292,7 +329,8 @@ def deliver_frames(handler, body) -> int:
         if p is not None and j.wal is None and not j.taps \
                 and not j._lock_owned():
             j.ctx.timestamp_generator.observe_event_time(int(ts[:n].max()))
-            done = p.submit_columns(ts, cols, n, frame=decode)
+            done = p.submit_columns(ts, cols, n, frame=decode,
+                                    frame_native=by_extension)
             if done >= n:
                 total += n
                 continue
