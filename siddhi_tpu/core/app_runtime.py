@@ -1480,26 +1480,40 @@ class SiddhiAppRuntime:
                 for f in _dc.fields(obj):  # e.g. SelectorState
                     scan(label, getattr(obj, f.name), acc)
 
-        sources: list[tuple[str, object]] = []
-        sources += [(f"query:{n}", qr.state)
-                    for n, qr in self.query_runtimes.items()
-                    if hasattr(qr, "state")]
-        sources += [(f"window:{n}", w.state) for n, w in self.windows.items()]
-        sources += [(f"aggregation:{n}", a.state)
-                    for n, a in self.aggregations.items()]
-        pending: dict[str, list] = {}
-        for label, obj in sources:
-            acc: dict = {}
-            scan(label, obj, acc)
-            for k, arrs in acc.items():
-                pending[f"{label}.{k}"] = arrs
-        for n, qr in self.query_runtimes.items():
-            if isinstance(qr, JoinQueryRuntime) and qr._dropped_dev is not None:
-                pending[f"query:{n}.join_pairs_dropped"] = [qr._dropped_dev]
         import jax
+        import jax.numpy as jnp
+        pending: dict[str, list] = {}
+        # under the controller lock, and copied there: join, pattern and
+        # aggregation steps DONATE their state, so a counter read beside a
+        # running feeder is deleted before the fetch below reaches it
+        # ("Array has been deleted"). The copies are a few scalar ops; the
+        # fetch, which waits for the device, runs outside the lock
+        with self.ctx.controller_lock:
+            sources: list[tuple[str, object]] = []
+            sources += [(f"query:{n}", qr.state)
+                        for n, qr in self.query_runtimes.items()
+                        if hasattr(qr, "state")]
+            sources += [(f"window:{n}", w.state)
+                        for n, w in self.windows.items()]
+            sources += [(f"aggregation:{n}", a.state)
+                        for n, a in self.aggregations.items()]
+            for label, obj in sources:
+                acc: dict = {}
+                scan(label, obj, acc)
+                for k, arrs in acc.items():
+                    pending[f"{label}.{k}"] = arrs
+            joins = {f"query:{n}.join_pairs_dropped": qr
+                     for n, qr in self.query_runtimes.items()
+                     if isinstance(qr, JoinQueryRuntime)
+                     and qr._dropped_dev is not None}
+            for key, qr in joins.items():
+                pending[key] = [qr._dropped_dev]
+            pending = jax.tree_util.tree_map(jnp.copy, pending)
         fetched = jax.device_get(pending)  # ONE device->host round trip
         for name, arrs in fetched.items():
             stats.record_overflow(name, int(sum(np.sum(a) for a in arrs)))
+        for key, qr in joins.items():
+            qr.dropped_synced = int(fetched[key][0])
 
     # ---------------------------------------------------------------- debugger
 

@@ -380,6 +380,14 @@ class Statistics:
                     out["ingress_pipeline"][sid] = p.stats_snapshot()
             if runtime.ctx.decoder is not None:
                 out["readback"] = runtime.ctx.decoder.stats_snapshot()
+            # join queries (core/join_runtime.py): steps per probe
+            # direction, out-block and candidate lanes, the drop counter
+            from .join_runtime import JoinQueryRuntime
+            joins = {name: qr.stats_snapshot()
+                     for name, qr in runtime.query_runtimes.items()
+                     if isinstance(qr, JoinQueryRuntime)}
+            if joins:
+                out["joins"] = joins
         if runtime is not None:
             wal = getattr(runtime, "wal", None)
             if wal is not None:

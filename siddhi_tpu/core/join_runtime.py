@@ -41,6 +41,7 @@ from ..query_api.execution import (
     Query,
     SingleInputStream,
 )
+from ..telemetry.tracing import StageCells
 from . import dtypes
 from .context import SiddhiAppContext
 from .event import EventBatch, EventType, StreamCodec
@@ -177,6 +178,12 @@ class _Side:
         self.handlers = ins.handlers
 
 
+def _named(fn, name: str):
+    """`fn` under the name jax gives its program (`jit_<name>`)."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
 class JoinQueryRuntime:
     def __init__(self, query: Query, ctx: SiddhiAppContext,
                  junctions: dict, tables: dict, registry: Registry,
@@ -192,6 +199,15 @@ class JoinQueryRuntime:
         self._dropped_dev = None
         self._drop_checks = 0
         self._drop_warned = False
+        #: the drop counter as last read from the device (the 64th-batch
+        #: sync below, or statistics_report()'s sweep)
+        self.dropped_synced = 0
+        # statistics_report()["joins"][name]: per probe direction the
+        # dispatch of one step, and the drop counter's device sync; the
+        # lanes are cumulative over steps, read as deltas
+        self.cells = StageCells(("step_left", "step_right", "drop_sync"))
+        self._out_lanes = 0
+        self._candidate_lanes = 0
         self.output_junction = None
         self.table_executor = None
         self.k_max = dtypes.config.join_max_matches
@@ -333,10 +349,14 @@ class JoinQueryRuntime:
             _mm_state(self.right),
             self.selector.init_state(),
         )
-        self._step_left = jax.jit(self._make_step(from_left=True),
-                                  donate_argnums=(0,))
-        self._step_right = jax.jit(self._make_step(from_left=False),
-                                   donate_argnums=(0,))
+        # named, so a profiler's `XLA Modules` line tells the join's
+        # programs from every other query's `jit_step`
+        self._step_left = jax.jit(
+            _named(self._make_step(from_left=True), "join_probe_left"),
+            donate_argnums=(0,))
+        self._step_right = jax.jit(
+            _named(self._make_step(from_left=False), "join_probe_right"),
+            donate_argnums=(0,))
         from ..ops.windows import window_has_time_semantics
         self.has_time_semantics = any(
             s.window is not None and window_has_time_semantics(s.window)
@@ -737,24 +757,51 @@ class JoinQueryRuntime:
             self.state = ((w2, wr, mm2, mmr, sel) if from_left
                           else (wl, w2, mml, mm2, sel))
             return
-        self.state, out, dropped = step(self.state, batch, jnp.int64(now),
-                                        tstate)
+        # nests in the feeder's `siddhi.feeder.dispatch` (or whichever
+        # delivery holds the controller lock)
+        side_name = "left" if from_left else "right"
+        with self.cells.span("step_" + side_name, "siddhi.join.step",
+                             side=side_name):
+            self.state, out, dropped = step(self.state, batch,
+                                            jnp.int64(now), tstate)
+        self._out_lanes += out.capacity
+        self._candidate_lanes += batch.capacity * self.k_max
         # accumulate on device; sync only at checkpoints (an int() every
         # batch would serialize the async dispatch pipeline)
         self._dropped_dev = (dropped if self._dropped_dev is None
                              else self._dropped_dev + dropped)
         self._drop_checks += 1
         if not self._drop_warned and self._drop_checks % 64 == 0:
-            if int(self._dropped_dev) > 0:
+            # a device sync under the controller lock: it waits for every
+            # step dispatched so far
+            with self.cells.span("drop_sync", "siddhi.join.drop_sync"):
+                self.dropped_synced = int(self._dropped_dev)
+            if self.dropped_synced > 0:
                 import warnings
                 warnings.warn(
-                    f"join {self.name!r}: {int(self._dropped_dev)} matched "
+                    f"join {self.name!r}: {self.dropped_synced} matched "
                     "pairs exceeded the per-step pair block or the per-probe "
                     "candidate walk and were dropped — raise "
                     "config.join_pair_cap_factor / config.join_max_matches",
                     stacklevel=2)
                 self._drop_warned = True
         self._distribute(out, now)
+
+    def stats_snapshot(self) -> dict:
+        """statistics_report()["joins"][name]. `steps`, `out_lanes` (the
+        out block's lanes, valid or not: what the read-back fetches) and
+        `candidate_lanes` (probe lanes x k_max, before compaction) are
+        cumulative; `pairs_dropped` is the device counter as last synced."""
+        stage_ms = self.cells.snapshot()
+        return {
+            "steps": {"left": stage_ms["step_left"]["batches"],
+                      "right": stage_ms["step_right"]["batches"]},
+            "k_max": self.k_max,
+            "out_lanes": self._out_lanes,
+            "candidate_lanes": self._candidate_lanes,
+            "pairs_dropped": self.dropped_synced,
+            "stage_ms": stage_ms,
+        }
 
     def _append_only(self, side, wstate, mmstate, batch, now):
         if not hasattr(side, "_append_fn"):
@@ -775,7 +822,7 @@ class JoinQueryRuntime:
                     mm = multimap_append(mm, hashes, live, w.appended)
                 return w2, mm
 
-            side._append_fn = jax.jit(fn)
+            side._append_fn = jax.jit(_named(fn, "join_append"))
         return side._append_fn(wstate, mmstate, batch, jnp.int64(now))
 
     def _selector_state(self):

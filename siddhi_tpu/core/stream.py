@@ -245,6 +245,10 @@ class AsyncDecoder:
         self._max_lag = max(maxsize, self.N_FETCH + 1)
         self._seq = 0
         self._deliver_next = 0
+        #: batches whose callback has returned (or failed and been routed):
+        #: what drain() waits for; `_deliver_next` runs one ahead of it
+        #: while a callback is running
+        self._delivered = 0
         self._buffer: dict = {}
         self._cv = named_condition("stream.decoder")
         self._stopping = False
@@ -389,31 +393,33 @@ class AsyncDecoder:
                 else:
                     logging.getLogger("siddhi_tpu").exception(what)
             with self._cv:
+                self._delivered = seq + 1
                 self._cv.notify_all()
 
     def drain(self, timeout: float = 120.0) -> None:
-        """Block until every submitted batch has been decoded+delivered.
+        """Block until every submitted batch has been decoded+delivered: the
+        last one's callback has returned, not merely begun.
         Raises SiddhiAppRuntimeError when a decoder thread has died or
         `timeout` seconds pass first, naming the sequence number delivery
         is stuck on: a fetch worker lost mid-batch strands its sequence
         number, and waiting for it without a bound never returns."""
         deadline = time.monotonic() + timeout
         with self._cv:
-            while self._deliver_next < self._seq:
+            while self._delivered < self._seq:
                 dead = [t.name for t in self._threads if not t.is_alive()]
                 if dead or time.monotonic() >= deadline:
                     why = (f"decoder thread(s) {dead} died" if dead else
                            f"no progress within {timeout:.0f}s")
                     raise SiddhiAppRuntimeError(
                         f"async decoder drain: {why}; stuck at sequence "
-                        f"{self._deliver_next} of {self._seq} submitted "
+                        f"{self._delivered} of {self._seq} submitted "
                         f"({len(self._buffer)} fetched out of order, "
                         f"{self._q.qsize()} queued)")
                 self._cv.wait(timeout=0.2)
 
     def stats_snapshot(self) -> dict:
         return {"stage_ms": self.cells.snapshot(), "submitted": self._seq,
-                "delivered": self._deliver_next}
+                "delivered": self._delivered}
 
     def stop(self) -> None:
         """Drain, then stop the threads. A drain that raises still tears

@@ -556,7 +556,13 @@ def hash_columns32(cols: list[jax.Array]) -> jax.Array:
     """32-bit column mix for candidate generation (join probes): all math in
     u32 — the 64-bit variant's u64 multiplies are software-emulated on TPU
     and show up at 100k-row build windows. Collisions only cost re-verified
-    candidates, never correctness (callers re-check the exact condition)."""
+    candidates, never correctness (callers re-check the exact condition) —
+    but the join's multimap takes its bucket from the LOW bits, and a walk
+    there is bounded (`join_max_matches` chain entries, matching or not), so
+    a lumpy low half loses matches. The FNV round alone put 7 of the dense
+    ids 0..100k into one of 2^18 buckets (a uniform hash: 5 at most, once in
+    a few tries); murmur3's finalizer after it spreads every input bit over
+    every output bit. Bijective, so equality of hashes is what it was."""
     h = jnp.uint32(0x811C9DC5)
     for c in cols:
         if jnp.issubdtype(c.dtype, jnp.floating):
@@ -570,7 +576,9 @@ def hash_columns32(cols: list[jax.Array]) -> jax.Array:
         for x in words:
             h = (h ^ x.astype(jnp.uint32)) * jnp.uint32(0x01000193)
             h = h ^ (h >> 15)
-    return h
+    h = (h ^ (h >> 16)) * jnp.uint32(0x85EBCA6B)
+    h = (h ^ (h >> 13)) * jnp.uint32(0xC2B2AE35)
+    return h ^ (h >> 16)
 
 
 def hash_columns(cols: list[jax.Array]) -> jax.Array:

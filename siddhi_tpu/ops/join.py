@@ -217,9 +217,16 @@ def multimap_init(ring_capacity: int, n_buckets: int) -> MultimapState:
 
 
 def multimap_buckets(ring_capacity: int) -> int:
-    """Power-of-two bucket count ~2x the ring: short chains, cheap masking."""
+    """Power-of-two bucket count >= 16x the ring: cheap masking, and chains
+    that are the probe's own matches almost alone. A walk examines
+    `join_max_matches` chain entries whether they match or not, so a bucket
+    shared with other keys spends the probe's budget on them: at 2x the ring
+    (the first cut) a `length(100000)` window over 100k uniform keys had a
+    chain of 17 or more in one window in 1,600 even under a uniform hash,
+    and lost pairs in 5 of 17 served runs on the chip (PERF.md, PR 26); at
+    16x it is one window in 670,000. Costs 64 B a ring slot."""
     h = 1
-    while h < 2 * ring_capacity:
+    while h < 16 * ring_capacity:
         h *= 2
     return h
 
