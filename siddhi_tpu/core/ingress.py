@@ -25,7 +25,8 @@ synchronous path runs strictly in sequence:
            transfer for chunk k+1 (EventBatch.from_numpy = device_put)
            BEFORE delivering chunk k under the controller lock, so H2D
            overlaps engine compute (double buffering; SIDDHI_DOUBLE_BUFFER=0
-           disables).
+           disables). Chunk k is held only while the ring has rows for
+           chunk k+1: the moment the feeder would starve, it delivers it.
 
 Determinism/parity: with a single producer the delivered batches are
 bit-identical to the synchronous path — same chunk boundaries (batch_size
@@ -257,6 +258,7 @@ class IngressPipeline:
         self._worker_runs = [0] * self.workers
         self._batches = 0       # feeder only
         self._overlapped = 0    # feeder only
+        self._on_starve = 0     # feeder only
         self._rows_in = 0       # under submit lock
         self._runs_in = 0       # under submit lock
         self._frames_in = 0     # wire path, under submit lock
@@ -720,7 +722,8 @@ class IngressPipeline:
                     wait = starved()
                     continue
                 # full chunk: start its H2D NOW (from_numpy = device_put),
-                # then deliver the PREVIOUS chunk while this transfer runs
+                # then deliver the PREVIOUS chunk, if the ring had this one's
+                # rows before that one went, while this transfer runs
                 batch, h2d = self._upload(ts_buf, dict(zip(attrs, col_bufs)),
                                           bs)
                 if tracing:
@@ -792,7 +795,18 @@ class IngressPipeline:
                     self._deliver_locked(batch, m)
                 wait = starved()
                 continue
-            if fill == 0 and pending is None and not sstack \
+            if pending is not None:
+                # starved with a built batch in hand: no rows are waiting
+                # whose transfer its delivery could overlap, so holding it
+                # buys nothing. Only a full, already-built chunk goes early;
+                # it precedes whatever is in `fill`, so order holds.
+                wait.end(units=0)  # `fill` is the wait, not a dispatch
+                self._deliver_locked(pending, bs, pending_t0)
+                pending = None
+                self._on_starve += 1
+                wait = starved()
+                continue
+            if fill == 0 and not sstack \
                     and ring.size() == 0 and self._q.unfinished_tasks == 0:
                 wait.drop()  # idle: nothing was sent, so not starved
                 self._feeder_idle.set()
@@ -873,7 +887,10 @@ class IngressPipeline:
             "frames_in": self._frames_in,
             "wire_native_frames": self._wire_native_frames,
             "batches_delivered": delivered,
+            # a held batch leaves behind the next one's upload (overlapped),
+            # when the ring runs empty (on starve) or at a flush (the rest)
             "batches_overlapped": self._overlapped,
+            "batches_delivered_on_starve": self._on_starve,
             "h2d_overlap_ratio": (self._overlapped / delivered
                                   if delivered else 0.0),
             "worker_utilization": busy / (elapsed_ns * self.workers),

@@ -131,3 +131,134 @@ class TestAutoFlush:
         assert rt._flusher_thread is not None
         rt.shutdown()
         assert rt._flusher_stop is None
+
+
+class TestStarveDelivery:
+    """The feeder's double buffer (core/ingress.py `_feed_loop`) keeps a
+    built batch only while the ring has rows for the next one: the moment
+    the feeder would starve it delivers the batch it holds. Every wait here
+    has its own deadline; nothing calls `drain()` before the rows are in."""
+
+    BS = 64
+    APP = (f"@Async(buffer.size='{BS}', workers='2')\n"
+           "define stream S (v long);\n"
+           "@info(name='q') from S select v insert into Out;")
+
+    @staticmethod
+    def _until(done, seconds: float) -> bool:
+        deadline = time.monotonic() + seconds
+        while not done():
+            if time.monotonic() >= deadline:
+                return False
+            time.sleep(0.002)
+        return True
+
+    @staticmethod
+    def _counters(rt) -> tuple:
+        sec = rt.statistics_report()["ingress_pipeline"]["S"]
+        return (sec["batches_delivered"], sec["batches_overlapped"],
+                sec["batches_delivered_on_starve"])
+
+    def _chunk(self, k: int) -> list:
+        return [(k * self.BS + i,) for i in range(self.BS)]
+
+    def test_a_lone_full_chunk_is_delivered_without_a_drain(self):
+        rt = build(self.APP)
+        try:
+            assert rt.junctions["S"]._pipeline is not None
+            got = []
+            rt.add_query_callback(
+                "q", lambda ts, i, r: got.extend(e.data[0] for e in i or []))
+            rt.get_input_handler("S").send_batch(self._chunk(0))
+            # the parent held it until the next chunk or a flush: for ever
+            assert self._until(lambda: len(got) == self.BS, 2.0), len(got)
+            assert got == list(range(self.BS))
+            assert self._counters(rt) == (1, 0, 1)
+            # nothing was left behind for the barrier to deliver
+            rt.drain()
+            assert len(got) == self.BS and self._counters(rt) == (1, 0, 1)
+        finally:
+            rt.shutdown()
+
+    def test_chunks_waiting_in_the_ring_still_overlap(self):
+        """The first delivery stands in a callback while four more chunks
+        are published: the feeder finds each one's rows in the ring when it
+        comes back, so it uploads the next before delivering the held one,
+        and only the last, with nothing behind it, goes on starve."""
+        rt = build(self.APP)
+        gate = threading.Event()
+        got = []
+
+        def cb(ts, i, r):
+            got.extend(e.data[0] for e in i or [])
+            assert gate.wait(10.0)
+
+        try:
+            rt.add_query_callback("q", cb)
+            h = rt.get_input_handler("S")
+            n = 5
+            h.send_batch(self._chunk(0))
+            assert self._until(lambda: len(got) == self.BS, 2.0)
+            pipe = rt.junctions["S"]._pipeline
+            for k in range(1, n):
+                h.send_batch(self._chunk(k))
+            assert self._until(
+                lambda: pipe.ring.size() == (n - 1) * self.BS, 5.0)
+            gate.set()
+            assert self._until(lambda: len(got) == n * self.BS, 5.0), len(got)
+            assert got == list(range(n * self.BS))  # arrival order
+            delivered, overlapped, on_starve = self._counters(rt)
+            # no flush delivered anything: the two counters are the whole
+            assert delivered == n == overlapped + on_starve
+            assert overlapped == n - 2 and on_starve == 2
+            # a partial tail is the flush's: the third way out
+            h.send_batch([(n * self.BS,), (n * self.BS + 1,)])
+            rt.drain()
+            assert got[-2:] == [n * self.BS, n * self.BS + 1]
+            delivered, overlapped, on_starve = self._counters(rt)
+            assert delivered - overlapped - on_starve == 1
+            assert (overlapped, on_starve) == (n - 2, 2)
+        finally:
+            gate.set()
+            rt.shutdown()
+
+    def test_double_buffer_off_never_holds_and_never_counts(self, monkeypatch):
+        monkeypatch.setenv("SIDDHI_DOUBLE_BUFFER", "0")
+        rt = build(self.APP)
+        try:
+            got = []
+            rt.add_query_callback(
+                "q", lambda ts, i, r: got.extend(e.data[0] for e in i or []))
+            h = rt.get_input_handler("S")
+            for k in range(3):
+                h.send_batch(self._chunk(k))
+            assert self._until(lambda: len(got) == 3 * self.BS, 2.0)
+            assert self._counters(rt) == (3, 0, 0)
+        finally:
+            rt.shutdown()
+
+    def test_superstep_staging_bypasses_the_double_buffer(self):
+        rt = build("@app:superstep(k='2')\n" + self.APP)
+        try:
+            got = []
+            rt.add_callback("Out", lambda b: got.extend(
+                b.column("v").tolist()), columnar=True)
+            h = rt.get_input_handler("S")
+            h.send_batch(self._chunk(0))
+            # one staged chunk of two: staging holds it, as before
+            time.sleep(0.2)
+            assert got == []
+            h.send_batch(self._chunk(1))
+            assert self._until(lambda: len(got) == 2 * self.BS, 10.0)
+            h.send_batch(self._chunk(2))
+            time.sleep(0.2)
+            assert len(got) == 2 * self.BS
+            rt.drain()
+            assert got == list(range(3 * self.BS))
+            sec = rt.statistics_report()["ingress_pipeline"]["S"]
+            assert sec["superstep_decline"] is None
+            assert sec["supersteps_dispatched"] == 1
+            assert sec["batches_overlapped"] == 0
+            assert sec["batches_delivered_on_starve"] == 0
+        finally:
+            rt.shutdown()

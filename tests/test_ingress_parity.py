@@ -12,6 +12,7 @@ exact conservation: sent == delivered + dropped.
 """
 
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -56,19 +57,21 @@ def _rows(n: int, seed: int = 11):
     return rows
 
 
-def _capture(app: str, feed, *, batch_size=None):
-    """Build, feed via `feed(handler, runtime)`, return the delivered blocks
-    as host tuples (ts, {col: array}, expired) for bit-exact comparison."""
+def _capture(app: str, feed, *, batch_size=None, out="OutStream",
+             streams=("TradeStream",)):
+    """Build, feed via `feed(handler, runtime)` (one handler per name in
+    `streams`), return the blocks delivered to `out` as host tuples (ts,
+    {col: array}, expired) for bit-exact comparison."""
     kw = {"batch_size": batch_size} if batch_size else {}
     rt = SiddhiManager().create_siddhi_app_runtime(app, **kw)
     blocks: list = []
-    rt.add_callback("OutStream", lambda b: blocks.append(
+    rt.add_callback(out, lambda b: blocks.append(
         (b.timestamps.copy(),
          {k: v.copy() for k, v in b.columns.items()},
          b.is_expired.copy())), columnar=True)
     rt.start()
     try:
-        feed(rt.get_input_handler("TradeStream"), rt)
+        feed(*(rt.get_input_handler(s) for s in streams), rt)
         rt.drain()
     finally:
         rt.shutdown()
@@ -190,6 +193,112 @@ class TestBitParity:
             rt.flush()
 
         _capture(APP_PIPE, feed)
+
+
+# ---------------------------------------------------- paced sending
+#
+# Full frames with a pause between them: the feeder meets an empty ring
+# after every upload and delivers the batch it holds there, where it used
+# to keep it until the next frame. Earlier, not different: the batches,
+# their boundaries and their order are the synchronous path's, bit for bit.
+
+PAUSE_S = 0.03
+
+JOIN_STREAMS = ("cseEventStream", "quoteEventStream")
+JOIN_BS, JOIN_WINDOW = 64, 200
+
+
+def _join_app(asynchronous: bool) -> str:
+    """tests/test_join_reference.py's app; without its `@Async` lines the
+    same two streams stage synchronously: the oracle."""
+    from .test_join_reference import APP
+    text = APP.format(batch=JOIN_BS, window=JOIN_WINDOW)
+    if not asynchronous:
+        text = "\n".join(line for line in text.splitlines()
+                         if not line.startswith("@Async"))
+    return text
+
+
+def _single_case():
+    """(pipeline app, serial app, out, streams, frames): five frames of two
+    chunks each into the filter."""
+    frames = []
+    for f in range(5):
+        rows = _rows(2 * BS, seed=30 + f)
+        frames.append((0, {
+            "symbol": np.array([r[0] for r in rows], dtype=object),
+            "price": np.array([r[1] for r in rows]),
+            "volume": np.array([r[2] for r in rows], dtype=np.int64),
+        }, np.arange(f * 1000, f * 1000 + 2 * BS, dtype=np.int64)))
+    return APP_PIPE, APP_SERIAL, "OutStream", ("TradeStream",), BS, frames
+
+
+def _join_case():
+    """Ten one-chunk frames over the two streams of the join, sides in runs
+    and alternating: a frame's pairs depend on every frame before it on the
+    other side, so an order or a boundary that moved shows in the rows."""
+    frames = []
+    for f, side in enumerate((0, 1, 1, 0, 1, 0, 0, 0, 1, 1)):
+        rng = np.random.default_rng([41, f])
+        index = np.arange(f * 1000, f * 1000 + JOIN_BS, dtype=np.int64)
+        frames.append((side, {
+            "symbol": np.array([f"S{k:05d}" for k in rng.integers(
+                0, JOIN_WINDOW, JOIN_BS).tolist()], dtype=object),
+            "price": (rng.integers(1, 4000, JOIN_BS) * 0.25).astype(
+                np.float32),
+            "volume": np.ones(JOIN_BS, np.int64),
+            "timestamp": index}, index))
+    return (_join_app(True), _join_app(False), "joinedStream", JOIN_STREAMS,
+            JOIN_BS, frames)
+
+
+@pytest.mark.parametrize("case", [_single_case, _join_case],
+                         ids=["single_stream", "two_stream_join"])
+def test_paced_frames_match_the_synchronous_path(case):
+    app_pipe, app_serial, out, streams, bs, frames = case()
+    chunks = sum(len(ts) // bs for _, _, ts in frames)
+    seen: dict = {}
+
+    def feed_paced(*args):
+        *handlers, rt = args
+        pipes = [rt.junctions[s]._pipeline for s in streams]
+        assert all(p is not None for p in pipes), "pipeline did not engage"
+        sent = 0
+        for side, cols, ts in frames:
+            handlers[side].send_columns(cols, timestamps=ts)
+            sent += len(ts) // bs
+            # every chunk of the frame is delivered with nothing behind it
+            # and no flush: on the parent the last one stood until the next
+            # frame of its own stream
+            deadline = time.monotonic() + 5.0
+            while sum(p._batches for p in pipes) < sent:
+                assert time.monotonic() < deadline, \
+                    [p.stats_snapshot() for p in pipes]
+                time.sleep(0.001)
+            time.sleep(PAUSE_S)
+        for s, p in zip(streams, pipes):
+            seen[s] = p.stats_snapshot()
+
+    def feed_serial(*args):
+        *handlers, rt = args
+        assert all(rt.junctions[s]._pipeline is None for s in streams)
+        for side, cols, ts in frames:
+            handlers[side].send_columns(cols, timestamps=ts)
+            rt.flush()
+
+    pipe = _capture(app_pipe, feed_paced, out=out, streams=streams,
+                    batch_size=bs)
+    serial = _capture(app_serial, feed_serial, out=out, streams=streams,
+                      batch_size=bs)
+    assert sum(len(b[0]) for b in pipe) > bs  # the case is not vacuous
+    _assert_blocks_identical(pipe, serial)
+    # every frame's last chunk went on starve, none at a flush; a chunk
+    # with the next one's rows already in the ring still overlapped
+    assert sum(s["batches_delivered"] for s in seen.values()) == chunks
+    assert sum(s["batches_delivered_on_starve"]
+               for s in seen.values()) >= len(frames)
+    assert all(s["batches_overlapped"] + s["batches_delivered_on_starve"]
+               == s["batches_delivered"] for s in seen.values())
 
 
 class TestConservation:

@@ -178,7 +178,7 @@ def _alternating(d: Deployment, n_keys: int) -> list:
     order = d.numbers(FRAMES)
     for i, f in enumerate(order):
         d.seeded(i % 2, f, n_keys)
-        d.rt.drain()  # a full chunk is held until the next, or a flush
+        d.rt.drain()  # fixes the order across the two streams' feeders
     return order
 
 

@@ -213,6 +213,49 @@ def test_hold_is_the_double_buffers_residence(monkeypatch, double_buffer):
         assert hold == {"total_ms": 0.0, "batches": 0, "mean_ms": 0.0}
 
 
+def test_fill_ends_before_a_starve_delivery_and_hold_is_short():
+    """Paced frames, one chunk each, into a callback that takes 100 ms:
+    the feeder meets an empty ring after every upload and delivers the
+    batch it holds there. `fill` (the feeder waiting for rows) is closed
+    before that delivery and reopened after it, so it swallows neither the
+    dispatch nor the idle time between frames; `hold` is the few polls from
+    the upload's end to the first empty one, not the interval."""
+    frames, pause, work = 4, 0.15, 0.1
+    rt = SiddhiManager().create_siddhi_app_runtime(APP)
+    rows = [0]
+
+    def slow(block):
+        rows[0] += block.count
+        time.sleep(work)
+
+    rt.add_callback("OutStream", slow, columnar=True)
+    rt.start()
+    try:
+        handler = rt.get_input_handler("TradeStream")
+        for f in range(frames):
+            assert wire.deliver_frames(handler, _body(BS, f)) == BS
+            time.sleep(pause)
+        deadline = time.monotonic() + 5.0
+        while True:
+            sec = rt.statistics_report()["ingress_pipeline"]["TradeStream"]
+            if sec["batches_delivered"] == frames:
+                break
+            assert time.monotonic() < deadline, sec
+            time.sleep(0.005)
+    finally:
+        rt.shutdown()
+    assert rows[0] > 0
+    assert sec["batches_delivered_on_starve"] == frames
+    assert sec["batches_overlapped"] == 0
+    stage = sec["stage_ms"]
+    assert stage["dispatch"]["total_ms"] >= frames * work * 1e3
+    assert stage["fill"]["batches"] == frames  # a unit per chunk, as before
+    assert stage["fill"]["total_ms"] < frames * work * 1e3
+    assert stage["fill"]["total_ms"] < frames * pause * 1e3
+    assert stage["hold"]["batches"] == frames
+    assert stage["hold"]["mean_ms"] < 0.2 * pause * 1e3
+
+
 def test_readback_section_counts_and_times_every_batch():
     stats, _, rows = _served_run()
     back = stats["readback"]
