@@ -382,79 +382,6 @@ intern_column(PyObject *self, PyObject *args)
     return Py_NewRef(Py_None);
 }
 
-/* radix_argsort(keys: int32 C-contiguous buffer, out: int32 buffer)
- *
- * Stable LSD radix argsort over NON-NEGATIVE int32 keys (group slots,
- * emission ranks). XLA CPU lowers a stable argsort to a comparator sort
- * (~260 ns/elem measured at 282k lanes — 74 ms); numpy's "stable" for
- * int32 is mergesort-class (~28 ms). This 11-bit/pass LSD radix runs the
- * same width in ~2-3 ms and is called from inside jitted steps via
- * jax.pure_callback on the CPU backend only (TPU keeps lax.sort). */
-static PyObject *
-radix_argsort(PyObject *self, PyObject *args)
-{
-    PyObject *keys_obj, *out_obj;
-    if (!PyArg_ParseTuple(args, "OO", &keys_obj, &out_obj))
-        return NULL;
-    Py_buffer kb, ob;
-    if (PyObject_GetBuffer(keys_obj, &kb, PyBUF_C_CONTIGUOUS) < 0)
-        return NULL;
-    if (PyObject_GetBuffer(out_obj, &ob,
-                           PyBUF_WRITABLE | PyBUF_C_CONTIGUOUS) < 0) {
-        PyBuffer_Release(&kb);
-        return NULL;
-    }
-    Py_ssize_t n = kb.len / (Py_ssize_t)sizeof(int32_t);
-    if (ob.len < n * (Py_ssize_t)sizeof(int32_t)) {
-        PyErr_SetString(PyExc_ValueError, "radix_argsort: out too small");
-        PyBuffer_Release(&kb); PyBuffer_Release(&ob);
-        return NULL;
-    }
-    const int32_t *keys = (const int32_t *)kb.buf;
-    int32_t *out = (int32_t *)ob.buf;
-    int32_t *tmp = PyMem_Malloc((size_t)n * sizeof(int32_t));
-    if (tmp == NULL && n > 0) {
-        PyBuffer_Release(&kb); PyBuffer_Release(&ob);
-        return PyErr_NoMemory();
-    }
-    uint32_t maxk = 0;
-    for (Py_ssize_t i = 0; i < n; i++) {
-        uint32_t k = (uint32_t)keys[i];
-        if (k > maxk) maxk = k;
-    }
-    for (Py_ssize_t i = 0; i < n; i++)
-        out[i] = (int32_t)i;
-#define RADIX_BITS 11
-#define RADIX_SIZE (1 << RADIX_BITS)
-    static _Thread_local uint32_t hist[RADIX_SIZE];
-    int32_t *src = out, *dst = tmp;
-    for (int shift = 0;
-         shift == 0 || (shift < 32 && (maxk >> shift) != 0);
-         shift += RADIX_BITS) {
-        memset(hist, 0, sizeof(hist));
-        for (Py_ssize_t i = 0; i < n; i++)
-            hist[((uint32_t)keys[i] >> shift) & (RADIX_SIZE - 1)]++;
-        uint32_t sum = 0;
-        for (int b = 0; b < RADIX_SIZE; b++) {
-            uint32_t c = hist[b];
-            hist[b] = sum;
-            sum += c;
-        }
-        for (Py_ssize_t i = 0; i < n; i++) {
-            int32_t idx = src[i];
-            uint32_t b = ((uint32_t)keys[idx] >> shift) & (RADIX_SIZE - 1);
-            dst[hist[b]++] = idx;
-        }
-        int32_t *t = src; src = dst; dst = t;
-    }
-    if (src != out)
-        memcpy(out, src, (size_t)n * sizeof(int32_t));
-    PyMem_Free(tmp);
-    PyBuffer_Release(&kb);
-    PyBuffer_Release(&ob);
-    return Py_NewRef(Py_None);
-}
-
 /* map_codes(codes: int32 buffer, to_str: list) -> list[str|None]
  * — vectorized string-column decode; out-of-range codes map to None (the
  *   caller pre-screens transient codes and takes the Python path). */
@@ -1161,8 +1088,6 @@ static PyMethodDef methods[] = {
      "idmemo_new() -> capsule: pointer-identity intern memo"},
     {"intern_column", intern_column, METH_VARARGS,
      "Intern a string column into an int32 code buffer."},
-    {"radix_argsort", radix_argsort, METH_VARARGS,
-     "radix_argsort(keys_i32, out_i32): stable LSD radix argsort"},
     {"map_codes", map_codes, METH_VARARGS,
      "Decode an int32 code buffer through a string table list."},
     {"decode_dict", decode_dict, METH_VARARGS,

@@ -28,19 +28,15 @@ import jax as _jax
 
 _jax.config.update("jax_enable_x64", True)
 # XLA:CPU's asynchronous dispatch can DEADLOCK nondeterministically when a
-# jitted computation carrying a host callback (ops/search.py
-# stable_argsort_bounded's pure_callback radix sort) runs concurrently with
+# jitted computation carrying a host callback (ops/windows_extra.py
+# CronWindow's pure_callback to the cron clock) runs concurrently with
 # device_get readbacks from other threads (the async stream-callback
 # decoder) — observed as a 0%-CPU wall-clock hang on single-core hosts.
 # Synchronous dispatch costs nothing here: the engine is already
 # one-controller-synchronous per micro-batch, and on CPU "device" compute
 # shares the very cores async dispatch would overlap with. TPU and other
 # backends are unaffected by this CPU-only flag.
-# (SIDDHI_CPU_ASYNC_DISPATCH=1 restores jax's default for experiments.)
-import os as _os
-
-if _os.environ.get("SIDDHI_CPU_ASYNC_DISPATCH", "") != "1":
-    _jax.config.update("jax_cpu_enable_async_dispatch", False)
+_jax.config.update("jax_cpu_enable_async_dispatch", False)
 
 from . import compiler  # noqa: E402
 from . import io  # noqa: E402,F401  (registers source/sink/mapper extensions)

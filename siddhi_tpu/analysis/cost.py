@@ -16,12 +16,9 @@ app, WITHOUT building a runtime or allocating any device state:
   buckets x queries x steps (join directions, pattern per-stream steps +
   heartbeat), respecting SharedStepGroup fusion (analysis/optimizer.py)
   when the multi-query optimizer is enabled.
-- **dispatch class** — whether the per-batch step stays on device
-  (``device``), amortizes its dispatch over a K-batch superstep scan
-  (``superstep``, core/superstep.py: dispatches-per-event divided by K),
-  or takes a host callback hop (``host`` — today only the deprecated
-  ``SIDDHI_RADIX_CALLBACK=1`` escape hatch; the packed-key device sort
-  retired the CPU radix pure_callback, ops/search.py).
+- **dispatch class** — whether the step is dispatched once per batch
+  (``device``) or amortizes its dispatch over a K-batch superstep scan
+  (``superstep``, core/superstep.py: dispatches-per-event divided by K).
 
 Enforcement rides on top: `app_budget` reads ``@app:budget(state=,
 compiles=)`` / ``SIDDHI_STATE_BUDGET`` / ``SIDDHI_COMPILE_BUDGET`` and
@@ -149,7 +146,7 @@ class ElementCost:
     kind: str  # query | join | pattern | window | table | aggregation
     state_bytes: int = 0
     compiles: int = 0
-    dispatch: str = "device"  # device | host
+    dispatch: str = "device"  # device | superstep
     #: byte-exact (closed schema, operator-mirrored) vs degraded estimate
     exact: bool = True
     notes: list = field(default_factory=list)
@@ -240,16 +237,6 @@ def _itemsize(t: AttributeType) -> int:
     import numpy as np
     from ..core import dtypes
     return np.dtype(dtypes.device_dtype(t)).itemsize
-
-
-def _radix_min() -> int:
-    from ..ops.search import _radix_min_lanes
-    return _radix_min_lanes()
-
-
-def _legacy_radix_callback() -> bool:
-    from ..ops.search import _legacy_callback_enabled
-    return _legacy_callback_enabled()
 
 
 def superstep_k(app: Optional[SiddhiApp]) -> int:
@@ -379,16 +366,6 @@ def _single_query_cost(node: QueryNode, plan: PlanGraph, registry,
                         and not selector.extrema_plan)
     ec.compiles = (_ladder_rungs(batch_cap)
                    if ec.bucket_ok and dtypes.config.shape_buckets else 1)
-    grouped_or_custom = bool(selector.group_vars) or any(
-        spec.custom_scan is not None for _, spec, _ in selector.agg_specs)
-    if (selector.has_aggregators and grouped_or_custom
-            and window.chunk_width >= _radix_min()
-            and _legacy_radix_callback()):
-        ec.dispatch = "host"
-        ec.notes.append(
-            f"group-key radix argsort over {window.chunk_width} lanes runs "
-            "as a host callback (SIDDHI_RADIX_CALLBACK=1 legacy escape "
-            "hatch; pjit fastpath veto, ops/search.py)")
     return ec
 
 
@@ -438,11 +415,6 @@ def _join_query_cost(node: QueryNode, plan: PlanGraph, registry,
                                    (rwin, plan_from_left)):
             if isinstance(win, SlidingWindow) and plan_as_build.probe_keys:
                 mm_specs.append((win.C, multimap_buckets(win.C)))
-        probe_keys = bool(plan_from_left.probe_keys
-                          or plan_from_right.probe_keys)
-    else:
-        plan_from_left = plan_from_right = None
-        probe_keys = False
 
     for win in (lwin, rwin):
         if win is not None:
@@ -479,14 +451,6 @@ def _join_query_cost(node: QueryNode, plan: PlanGraph, registry,
         if triggers:
             ec.compiles += 1
 
-    build_caps = [getattr(w, "C", 0) for w in (lwin, rwin) if w is not None]
-    if (probe_keys and build_caps and max(build_caps) >= _radix_min()
-            and _legacy_radix_callback()):
-        ec.dispatch = "host"
-        ec.notes.append(
-            "equi-join build-side indexing radix-sorts "
-            f"{max(build_caps)} ring lanes via a host callback "
-            "(SIDDHI_RADIX_CALLBACK=1 legacy escape hatch)")
     return ec
 
 
@@ -797,9 +761,8 @@ def compute_cost(app_or_plan, *, batch_size: int = 0,
 
     # --- superstep dispatch class: with @app:superstep(k=K>1) the eligible
     # plan runs K batches per device dispatch (one lax.scan, one fetch), so
-    # the per-event dispatch cost divides by K. Host-hop elements keep their
-    # "host" class — a callback makes the plan superstep-ineligible
-    # (core/superstep.py), which SL506 reports. ---
+    # the per-event dispatch cost divides by K. What makes a plan
+    # superstep-ineligible is SL506's to report (analysis/rules.py). ---
     k = superstep_k(app)
     report.superstep_k = k
     if k > 1:

@@ -10,8 +10,8 @@ allocation.
 Hazards:
   SL201  host callbacks (`pure_callback`, `io_callback`, debug prints):
          every step invocation round-trips device→host→device, serializing
-         the dispatch queue (e.g. #window.sort lowers through the bounded
-         radix argsort callback in ops/search.py).
+         the dispatch queue (e.g. #window.cron asks the host's cron clock
+         for its next fire time, ops/windows_extra.py).
   SL202  float64 avals in the step: on TPU f64 is emulated (~10x slower);
          usually a leaked `jax_enable_x64` literal.
   SL203  widening `convert_element_type` ops: silent upcasts that double a
@@ -23,13 +23,23 @@ Hazards:
          verdicts; tools/fastpath_gate.py keeps the in-tree bench apps
          from regressing.
 
-Never raises: a query whose step cannot be traced here is skipped (and the
-skip is logged at debug), because the runtime build path owns those errors.
+Never raises over a query: one whose step cannot be traced here is skipped
+(and the skip is logged at debug), because the runtime build path owns those
+errors. Importing this module does raise on a jax whose jaxpr classes it
+cannot find, since the walk would then see nothing below the top level.
 """
 
 from __future__ import annotations
 
 import logging
+
+# no getattr-with-default here: a walk that cannot recognise a nested jaxpr
+# sees only a step's top-level equations and certifies the rest unseen, so a
+# jax that keeps these classes nowhere we know must stop the import
+try:
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+except ImportError:  # an older jax keeps them in jax.core
+    from jax.core import ClosedJaxpr, Jaxpr
 
 from .diagnostics import Diagnostic, LintReport, Severity
 
@@ -41,12 +51,9 @@ _CALLBACK_PRIMS = ("pure_callback", "io_callback", "callback",
 
 def _sub_jaxprs(value):
     """Yield any jaxprs nested inside an eqn param value."""
-    import jax.core as jcore
-    closed = getattr(jcore, "ClosedJaxpr", None)
-    jaxpr_t = getattr(jcore, "Jaxpr", None)
-    if closed is not None and isinstance(value, closed):
+    if isinstance(value, ClosedJaxpr):
         yield value.jaxpr
-    elif jaxpr_t is not None and isinstance(value, jaxpr_t):
+    elif isinstance(value, Jaxpr):
         yield value
     elif isinstance(value, (list, tuple)):
         for v in value:
@@ -120,8 +127,8 @@ class _Hazards:
             add("SL201", Severity.WARN,
                 "compiled step calls back to the host every batch "
                 f"({', '.join(sorted(self.callbacks))}): device→host→device "
-                "round-trip serializes dispatch (e.g. #window.sort lowers "
-                "through a host radix argsort)")
+                "round-trip serializes dispatch (e.g. #window.cron asks "
+                "the host for its next fire time)")
         if self.f64:
             add("SL202", Severity.WARN,
                 "float64 values flow through the compiled step "

@@ -705,33 +705,6 @@ def compile_ladder_explosion(plan: PlanGraph) -> Iterable:
              "set SIDDHI_SHAPE_BUCKETS=0")
 
 
-@rule("SL504", Severity.WARN,
-      "dispatch-heavy plan: a host callback rides every micro-batch "
-      "(today only the SIDDHI_RADIX_CALLBACK=1 legacy escape hatch)")
-def host_hop_per_batch(plan: PlanGraph) -> Iterable:
-    from .cost import cost_for_plan, superstep_k
-    if (superstep_k(plan.app) > 1
-            and _superstep_ineligibility(plan,
-                                         include_dispatch=False) is None):
-        # the plan rides K-batch supersteps (or, when the hop itself is
-        # the only blocker, SL506 names the callback as the decline
-        # reason): don't double-report the same dispatch
-        return
-    rep = cost_for_plan(plan)
-    for e in rep.elements:
-        if e.dispatch != "host" or e.node_index is None:
-            continue
-        node = _query_by_index(plan, e.node_index)
-        if node is None:
-            continue
-        detail = next((n for n in e.notes if "host" in n), "")
-        yield _q(node,
-                 "this step takes a host-callback hop every micro-batch"
-                 + (f": {detail}" if detail else "")
-                 + " — pjit's C++ fastpath is vetoed for the whole "
-                 "executable (tools/fastpath_gate.py tracks these)")
-
-
 @rule("SL505", Severity.INFO,
       "cost-dominant element: one element holds >50% of the app's "
       "predicted device state")
@@ -761,8 +734,7 @@ def cost_dominant_element(plan: PlanGraph) -> Iterable:
         yield _d(e.element, schema.defn, msg)
 
 
-def _superstep_ineligibility(plan: PlanGraph, *,
-                             include_dispatch: bool = True):
+def _superstep_ineligibility(plan: PlanGraph):
     """First STATIC reason the superstep scan would decline this plan, as
     (reason, anchor-node-or-None) — or None when nothing statically rules
     it out. A lightweight mirror of core/superstep.py's runtime decline
@@ -813,16 +785,6 @@ def _superstep_ineligibility(plan: PlanGraph, *,
                 return ("a pattern/sequence query consumes the @Async "
                         f"stream {sid!r}: NFA steps are not scannable "
                         "receivers", node)
-    if include_dispatch:
-        from .cost import cost_for_plan
-        rep = cost_for_plan(plan)
-        for e in rep.elements:
-            if e.dispatch == "host":
-                node = (None if e.node_index is None
-                        else _query_by_index(plan, e.node_index))
-                return (f"step {e.element!r} takes a host-callback hop "
-                        "(SIDDHI_RADIX_CALLBACK=1 legacy radix sort)",
-                        node)
     return None
 
 

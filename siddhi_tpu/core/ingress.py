@@ -24,9 +24,9 @@ synchronous path runs strictly in sequence:
            assembles batch_size chunks, and starts the host→device
            transfer for chunk k+1 (EventBatch.from_numpy = device_put)
            BEFORE delivering chunk k under the controller lock, so H2D
-           overlaps engine compute (double buffering; SIDDHI_DOUBLE_BUFFER=0
-           disables). Chunk k is held only while the ring has rows for
-           chunk k+1: the moment the feeder would starve, it delivers it.
+           overlaps engine compute (double buffering). Chunk k is held
+           only while the ring has rows for chunk k+1: the moment the
+           feeder would starve, it delivers it.
 
 Determinism/parity: with a single producer the delivered batches are
 bit-identical to the synchronous path — same chunk boundaries (batch_size
@@ -47,7 +47,6 @@ existing MPSC ring or synchronous staging untouched.
 from __future__ import annotations
 
 import logging
-import os
 import queue
 import threading
 import time
@@ -239,8 +238,6 @@ class IngressPipeline:
         self._barrier_req = threading.Event()
         self._feeder_idle = threading.Event()
         self._feeder_idle.set()
-        self._double_buffer = os.environ.get(
-            "SIDDHI_DOUBLE_BUFFER", "1").strip() != "0"
         # device-resident supersteps (@app:superstep(k=) / SIDDHI_SUPERSTEP_K,
         # core/superstep.py): the feeder stages K full chunks and runs the
         # eligible query chain as one lax.scan dispatch. Built lazily at the
@@ -734,14 +731,11 @@ class IngressPipeline:
                 ts_buf = np.zeros(bs, dtype=np.int64)
                 col_bufs = [np.zeros(bs, dtype=dt) for dt in self.np_dtypes]
                 fill = 0
-                if self._double_buffer:
-                    if pending is not None:
-                        self._deliver_locked(pending, bs, pending_t0)
-                        self._overlapped += 1
-                    pending = batch
-                    pending_t0 = time.perf_counter_ns()
-                else:
-                    self._deliver_locked(batch, bs)
+                if pending is not None:
+                    self._deliver_locked(pending, bs, pending_t0)
+                    self._overlapped += 1
+                pending = batch
+                pending_t0 = time.perf_counter_ns()
                 wait = starved()
                 continue
             if got:

@@ -83,8 +83,9 @@ def aot_warm(jit_fn, *args) -> None:
     WITHOUT executing it — jax shares `lower().compile()` executables
     with the normal call path, so the next real call is a pure cache hit.
     Warmup therefore has no step side effects, cannot touch live state,
-    and never runs host callbacks (executing a step during warmup can
-    deadlock jax's CPU pure_callback path on small hosts)."""
+    and never runs a host callback (CronWindow's, ops/windows_extra.py:
+    executing a step during warmup can deadlock jax's CPU pure_callback
+    path on small hosts)."""
     abstract = jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(jnp.shape(x), jnp.result_type(x)),
         args)

@@ -17,7 +17,6 @@ from __future__ import annotations
 
 import dataclasses
 import logging
-import os
 import time
 from typing import Callable, Optional, Sequence
 
@@ -228,7 +227,7 @@ class AsyncDecoder:
     else an ERROR record) and its batch is NOT delivered; drain() raises
     when a decoder thread has died or its deadline passes."""
 
-    N_FETCH = int(os.environ.get("SIDDHI_DECODE_WORKERS", "2"))
+    N_FETCH = 2
 
     def __init__(self, maxsize: int = 32) -> None:
         import queue
@@ -236,10 +235,8 @@ class AsyncDecoder:
 
         import jax
         # on the CPU backend device memory IS host memory: packing would
-        # add a device pass and save no transfer (SIDDHI_WIRE_PACK=0 forces
-        # it off elsewhere)
-        self._pack = (jax.default_backend() not in ("cpu",)
-                      and os.environ.get("SIDDHI_WIRE_PACK", "1") != "0")
+        # add a device pass and save no transfer
+        self._pack = jax.default_backend() != "cpu"
         self._q: "queue.Queue" = queue.Queue(maxsize=maxsize)
         #: max decoded-but-undelivered batches held in the reorder buffer
         self._max_lag = max(maxsize, self.N_FETCH + 1)

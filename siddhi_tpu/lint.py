@@ -68,10 +68,6 @@ def _print_cost(path: str, cost: dict) -> None:
               f"compiles={limit[1] if limit[1] is not None else '-'} "
               f"({budget.get('source')}, mode={budget.get('mode')}) — "
               f"{verdict} budget")
-    for e in cost.get("elements", ()):
-        if e.get("dispatch") == "host":
-            print(f"{path}: cost: element {e['element']!r} takes a "
-                  "host-callback hop every batch (SL504)")
 
 
 def _collect(paths: list[str], scan: bool) -> list[Path]:

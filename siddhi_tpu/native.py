@@ -4,9 +4,8 @@ Builds native/columnar.c on first import (cc via setuptools), caches the
 shared object under siddhi_tpu/_native_build/<tag>/, and degrades to the
 pure-Python encoder when the build fails — with a WARNING that carries the
 compiler's stderr, so a host path that quietly turned into Python is visible
-in the log. Set SIDDHI_TPU_NO_NATIVE=1 (or the shorter SIDDHI_NATIVE=0) to
-force the Python path on purpose (A/B of the marshalling hot loop,
-fallback-parity CI runs).
+in the log. Set SIDDHI_NATIVE=0 to force the Python path on purpose (A/B of
+the marshalling hot loop, fallback-parity CI runs).
 
 The cache tag hashes EVERY file the extension is built from (SOURCES), and
 the build recompiles from scratch into a tag-private temp directory, so a
@@ -97,8 +96,7 @@ def _build() -> bool:
     return True
 
 
-_DISABLED = bool(os.environ.get("SIDDHI_TPU_NO_NATIVE")) or \
-    os.environ.get("SIDDHI_NATIVE", "").strip() == "0"
+_DISABLED = os.environ.get("SIDDHI_NATIVE", "").strip() == "0"
 
 if not _DISABLED:
     try:

@@ -117,7 +117,7 @@ def _segment_plan(slots, valid, resets, current_epoch, K) -> _SegmentPlan:
     lane_epoch = current_epoch + reset_rank
 
     # stable sort by (slot, lane) — lane order inside a slot is preserved.
-    # slots_v is non-negative (< K+1): radix path on CPU, lax sort on TPU
+    # slots_v is non-negative (< K+1), as stable_argsort_bounded requires
     order = stable_argsort_bounded(slots_v)
     s_slots = slots_v[order]
     s_epochs = lane_epoch[order]

@@ -256,7 +256,7 @@ def multimap_append(mm: MultimapState, hashes: jax.Array, live: jax.Array,
     bucket = (hashes & jnp.uint32(H - 1)).astype(jnp.int32)
 
     sortkey = jnp.where(valid, bucket, jnp.int32(H))
-    run = stable_argsort_bounded(sortkey)  # bounded non-negative: radix on CPU
+    run = stable_argsort_bounded(sortkey)  # bounded non-negative (<= H)
     b_s = sortkey[run]
     seq_s = seq[run]
     hash_s = hashes[run]

@@ -1,4 +1,7 @@
-"""Branchless unrolled binary search.
+"""Sort and search primitives the window, group-by and join steps share:
+a stable partition by prefix sums, the one stable argsort of bounded keys
+(`stable_argsort_bounded` — the only place that decides how such a key is
+sorted), and a branchless unrolled binary search.
 
 XLA lowers `jnp.searchsorted` to a `while` HLO whose per-iteration dispatch
 dominated sliding-window steps on TPU (profiled at ~50% of step time: the
@@ -33,78 +36,27 @@ def stable_partition_order(live):
     return jnp.zeros((n,), jnp.int32).at[dest].set(iota)
 
 
-def _host_radix_argsort(a):
-    import numpy as np
-    out = np.empty(a.shape, dtype=np.int32)
-    from .. import native as native_mod
-    nat = native_mod.native
-    if a.ndim == 1:
-        if nat is not None and hasattr(nat, "radix_argsort"):
-            nat.radix_argsort(np.ascontiguousarray(a), out)
-        else:
-            out[...] = np.argsort(a, kind="stable")
-        return out
-    flat = a.reshape(-1, a.shape[-1])
-    oflat = out.reshape(-1, a.shape[-1])
-    for i in range(flat.shape[0]):
-        if nat is not None and hasattr(nat, "radix_argsort"):
-            nat.radix_argsort(np.ascontiguousarray(flat[i]), oflat[i])
-        else:
-            oflat[i] = np.argsort(flat[i], kind="stable")
-    return out
-
-
-#: lane count below which the plain native argsort is used instead of the
-#: packed single-key sort. Historical meaning (kept for the cost model and
-#: the legacy-callback escape hatch): on CPU this was the width above which
-#: the C radix argsort pure_callback won over XLA's comparator sort. A host
-#: callback anywhere in a jitted program disables pjit's C++ fastpath for
-#: EVERY call of that executable (jax `_get_fastpath_data` vetoes
-#: host_callbacks), costing ~0.5-6 ms of python dispatch per step — so the
-#: callback traded per-sort time for per-dispatch time. The packed-key sort
-#: below keeps the asymptotic win on device with no callback.
-_RADIX_SORT_MIN_LANES = 8192
-
-
-def _radix_min_lanes() -> int:
-    import os
-    try:
-        return int(os.environ.get("SIDDHI_RADIX_SORT_MIN", "")
-                   or _RADIX_SORT_MIN_LANES)
-    except ValueError:
-        return _RADIX_SORT_MIN_LANES
-
-
-def _legacy_callback_enabled() -> bool:
-    """Deprecated escape hatch: SIDDHI_RADIX_CALLBACK=1 restores the old
-    CPU `pure_callback` radix argsort (testing / A-B only — it vetoes
-    pjit's fastpath and makes the step superstep-ineligible)."""
-    import os
-    return os.environ.get("SIDDHI_RADIX_CALLBACK", "").strip() == "1"
+#: lane count from which the CPU backend sorts one packed (key, lane) word
+#: instead of calling `jnp.argsort`: XLA CPU's comparator co-sort of (key,
+#: iota) loses to a single-operand sort of one int64 from about this width
+#: (16,384 lanes: 6.9 ms vs 1.4 ms; 131,072 lanes: 59.1 ms vs 12.7 ms).
+_PACKED_SORT_MIN_LANES = 8192
 
 
 def stable_argsort_bounded(x):
     """Stable argsort of NON-NEGATIVE int32 keys, as int32 positions.
 
-    Narrow batches: native `jnp.argsort(stable=True)`. Wide batches: pack
-    `(key << 32) | lane` into one int64 word and run a SINGLE unstable
-    single-operand `lax.sort` — the lane index in the low bits makes the
-    order stable by construction and the low 32 bits of the sorted words
-    ARE the argsort. One sort over one operand instead of argsort's
-    internal (key, iota) co-sort, and — unlike the retired CPU radix
-    `pure_callback` — it stays on device, so the compiled step keeps
-    pjit's C++ fastpath and can ride inside a superstep `lax.scan`
-    (core/superstep.py). Keys are bounded (< 2^31), so the shifted word
-    never overflows int64. The deprecated callback path survives behind
-    SIDDHI_RADIX_CALLBACK=1 for A/B tests only."""
-    import jax
-    from jax import lax, pure_callback
-
-    def legacy_cpu_fn(v):
-        return pure_callback(
-            _host_radix_argsort,
-            jax.ShapeDtypeStruct(v.shape, jnp.int32), v,
-            vmap_method="broadcast_all")
+    Below `_PACKED_SORT_MIN_LANES`, and on every backend but the CPU at any
+    width: `jnp.argsort(stable=True)` (int64 lane math is emulated on TPU,
+    so the packed word would cost more there than the co-sort it saves).
+    From that width on the CPU: pack `(key << 32) | lane` into one int64
+    word and run a SINGLE unstable single-operand `lax.sort` — the lane
+    index in the low bits makes the order stable by construction and the
+    low 32 bits of the sorted words ARE the argsort. Keys are bounded
+    (< 2^31), so the shifted word never overflows int64. Both arms stay
+    on the device: the compiled step keeps pjit's C++ fastpath and can
+    ride inside a superstep `lax.scan` (core/superstep.py)."""
+    from jax import lax
 
     def default_fn(v):
         return jnp.argsort(v, axis=-1, stable=True).astype(jnp.int32)
@@ -115,12 +67,8 @@ def stable_argsort_bounded(x):
         swords = lax.sort(packed, dimension=v.ndim - 1, is_stable=False)
         return (swords & jnp.int64(0xFFFFFFFF)).astype(jnp.int32)
 
-    if x.shape[-1] < _radix_min_lanes():
+    if x.shape[-1] < _PACKED_SORT_MIN_LANES:
         return default_fn(x)
-    if _legacy_callback_enabled():
-        return lax.platform_dependent(x, cpu=legacy_cpu_fn,
-                                      default=default_fn)
-    # int64 lane math is emulated on TPU — keep the native argsort there
     return lax.platform_dependent(x, cpu=packed_fn, default=default_fn)
 
 

@@ -222,21 +222,6 @@ class TestStarveDelivery:
             gate.set()
             rt.shutdown()
 
-    def test_double_buffer_off_never_holds_and_never_counts(self, monkeypatch):
-        monkeypatch.setenv("SIDDHI_DOUBLE_BUFFER", "0")
-        rt = build(self.APP)
-        try:
-            got = []
-            rt.add_query_callback(
-                "q", lambda ts, i, r: got.extend(e.data[0] for e in i or []))
-            h = rt.get_input_handler("S")
-            for k in range(3):
-                h.send_batch(self._chunk(k))
-            assert self._until(lambda: len(got) == 3 * self.BS, 2.0)
-            assert self._counters(rt) == (3, 0, 0)
-        finally:
-            rt.shutdown()
-
     def test_superstep_staging_bypasses_the_double_buffer(self):
         rt = build("@app:superstep(k='2')\n" + self.APP)
         try:

@@ -173,23 +173,92 @@ class TestLintGate:
 
 
 class TestJaxprPass:
-    def test_detects_radix_argsort_host_callback(self, monkeypatch):
-        # the packed-key device sort retired the CPU radix pure_callback;
-        # re-enable it via the legacy escape hatch so the SL201 detector
-        # (host callback in the traced jaxpr) still has a live target
-        monkeypatch.setenv("SIDDHI_RADIX_CALLBACK", "1")
+    def test_detects_cron_window_host_callback(self):
+        # the one host callback a step can carry: #window.cron asks the
+        # host's cron clock for its next fire time, under a lax.cond — so
+        # the walk has to descend below the step's top-level equations
         app = """
         define stream S (symbol string, price double);
-        @info(name='grouped')
-        from S#window.lengthBatch(16)
-        select symbol, avg(price) as ap
-        group by symbol
+        @info(name='cron')
+        from S#window.cron('*/5 * * * * ?')
+        select symbol, price
         insert into Out;
         """
         report = analyze(app, jaxpr=True)
         hits = [d for d in report.diagnostics if d.rule_id == "SL201"]
         assert hits and hits[0].severity is Severity.WARN
-        assert "radix" in hits[0].message or "host" in hits[0].message
+        assert "host" in hits[0].message
+
+    @pytest.mark.parametrize("app", [
+        pytest.param("""
+        define stream S (symbol string, price double);
+        @info(name='grouped')
+        from S#window.lengthBatch(16384)
+        select symbol, avg(price) as ap
+        group by symbol
+        insert into Out;
+        """, id="groupby_16384_lanes"),
+        pytest.param("""
+        define stream T (symbol string, price double);
+        define stream Q (symbol string, bid double);
+        @info(name='joined')
+        from T#window.length(16384) as t join Q#window.length(16384) as q
+          on t.symbol == q.symbol
+        select t.symbol, t.price, q.bid
+        insert into Out;
+        """, id="equi_join_length_16384"),
+    ])
+    def test_wide_sort_steps_stay_on_the_device(self, app):
+        # wide enough for stable_argsort_bounded's packed arm: the traced
+        # step carries no callback and the cost model prices it on device
+        report = analyze(app, jaxpr=True)
+        assert "SL201" not in report.rule_counts()
+        assert "SL204" not in report.rule_counts()
+        queries = [e for e in report.cost["elements"]  # cost_for_plan's
+                   if e["kind"] in ("query", "join")]
+        assert queries
+        assert all(e["dispatch"] == "device" for e in queries)
+
+    @pytest.mark.parametrize("nest", ["cond", "scan", "jit"])
+    def test_walk_descends_into_nested_jaxprs(self, nest):
+        # a callback below the top-level equations, in each construct that
+        # carries a sub-jaxpr in its params
+        import jax
+        import jax.numpy as jnp
+        from siddhi_tpu.analysis.jaxpr_pass import _trace_hazards
+
+        def host_clock(x):
+            return jax.pure_callback(
+                lambda v: v, jax.ShapeDtypeStruct((), jnp.int32), x)
+
+        def step(x):
+            if nest == "cond":
+                return jax.lax.cond(x < 0, host_clock, lambda v: v, x)
+            if nest == "scan":
+                return jax.lax.scan(
+                    lambda c, _: (host_clock(c), None), x, None, length=2)[0]
+            return jax.jit(host_clock)(x)
+
+        hazards = _trace_hazards(step, jnp.int32(1))
+        assert hazards.callbacks, nest
+        assert any("host callback" in v for v in hazards.fastpath_vetoes)
+
+    def test_pass_refuses_a_jax_without_jaxpr_classes(self, monkeypatch):
+        # a walk that cannot recognise a nested jaxpr would see nothing
+        # below the top level and certify the rest unseen: refuse instead
+        import importlib
+
+        import jax.core
+        import jax.extend.core
+        from siddhi_tpu.analysis import jaxpr_pass
+        try:
+            with monkeypatch.context() as m:
+                for mod in (jax.extend.core, jax.core):
+                    m.delattr(mod, "ClosedJaxpr", raising=False)
+                with pytest.raises(ImportError, match="ClosedJaxpr"):
+                    importlib.reload(jaxpr_pass)
+        finally:
+            importlib.reload(jaxpr_pass)
 
     def test_clean_passthrough_has_no_callback_warning(self):
         app = """

@@ -202,15 +202,10 @@ def test_every_new_cell_is_booked_by_a_served_run():
     assert parts <= stage["device"]["total_ms"] <= 1.05 * parts
 
 
-@pytest.mark.parametrize("double_buffer", ["1", "0"])
-def test_hold_is_the_double_buffers_residence(monkeypatch, double_buffer):
-    monkeypatch.setenv("SIDDHI_DOUBLE_BUFFER", double_buffer)
+def test_hold_is_the_double_buffers_residence():
     stats, _, _ = _served_run(frames=3)
     hold = stats["ingress_pipeline"]["TradeStream"]["stage_ms"]["hold"]
-    if double_buffer == "1":
-        assert hold["batches"] > 0 and hold["total_ms"] > 0
-    else:
-        assert hold == {"total_ms": 0.0, "batches": 0, "mean_ms": 0.0}
+    assert hold["batches"] > 0 and hold["total_ms"] > 0
 
 
 def test_fill_ends_before_a_starve_delivery_and_hold_is_short():

@@ -13,8 +13,8 @@ order is unchanged.
 
 Plus the operational surface: the decline taxonomy (ineligible plans fall
 back loudly to per-batch, once, with the reason in stats_snapshot), the
-device-native packed-key argsort vs the retired host radix callback
-(SIDDHI_RADIX_CALLBACK=1 A/B), telemetry batch attribution under K>1
+wide arm of `stable_argsort_bounded` against numpy's stable argsort,
+telemetry batch attribution under K>1
 (one trace per inner batch, stages additive, `superstep_k` stamped), and
 the pure-Python-ring subprocess parity run (SIDDHI_NATIVE=0)."""
 
@@ -234,11 +234,11 @@ class TestSuperstepDecline:
 
 
 class TestDeviceSortParity:
-    """The packed-key `lax.sort` argsort that replaced the host radix
-    callback: stable, and bit-identical to the legacy CPU callback on
-    seeded heavy-tie keys (SIDDHI_RADIX_CALLBACK=1 A/B)."""
+    """`stable_argsort_bounded` at a width that takes its wide arm (the
+    packed-key `lax.sort` on the CPU backend): bit-identical to numpy's
+    stable argsort on seeded heavy-tie keys."""
 
-    LANES = 16384  # above _RADIX_SORT_MIN_LANES -> wide path
+    LANES = 16384  # above _PACKED_SORT_MIN_LANES -> wide arm
 
     def _keys(self, seed):
         rng = np.random.default_rng(seed)
@@ -252,17 +252,6 @@ class TestDeviceSortParity:
             got = np.asarray(stable_argsort_bounded(x))
             want = np.argsort(x, kind="stable").astype(np.int32)
             np.testing.assert_array_equal(got, want)
-
-    def test_packed_sort_matches_legacy_callback(self, monkeypatch):
-        from siddhi_tpu.ops.search import (stable_argsort_bounded,
-                                           _legacy_callback_enabled)
-        x = self._keys(7)
-        assert not _legacy_callback_enabled()
-        dev = np.asarray(stable_argsort_bounded(x))
-        monkeypatch.setenv("SIDDHI_RADIX_CALLBACK", "1")
-        assert _legacy_callback_enabled()
-        legacy = np.asarray(stable_argsort_bounded(x))
-        np.testing.assert_array_equal(dev, legacy)
 
     def test_batched_rows_stable(self):
         from siddhi_tpu.ops.search import stable_argsort_bounded

@@ -3,13 +3,12 @@
 
 Every compiled step of every bench-suite app is certified against pjit's
 C++ dispatch fastpath via `analysis.jaxpr_pass.fastpath_certify`: no host
-callback, no ordered effect. KNOWN_VETOED is EMPTY — the device-resident
-supersteps work retired the last hit-list entries (the CPU radix-argsort
-pure_callbacks, replaced by the on-device packed-key sort in
-ops/search.py) — and the gate is now hard: ANY vetoed step in ANY bench
-app fails CI outright. A host callback in a step would also make the
-plan superstep-ineligible (core/superstep.py), so this gate doubles as
-the superstep-eligibility floor for the bench suite.
+callback, no ordered effect. KNOWN_VETOED is EMPTY — every sort of the
+bench apps' steps runs on the device (ops/search.py) — and the gate is
+hard: ANY vetoed step in ANY bench app fails CI outright. A host callback
+in a step would also make the plan superstep-ineligible
+(core/superstep.py), so this gate doubles as the superstep-eligibility
+floor for the bench suite.
 
     python tools/fastpath_gate.py [--json]
 
@@ -110,8 +109,7 @@ APPS = {
     """,
 }
 
-#: accepted vetoes, keyed "<app>:<step>". EMPTY by design since the
-#: packed-key device sort retired the radix pure_callbacks — adding an
+#: accepted vetoes, keyed "<app>:<step>". EMPTY by design — adding an
 #: entry here requires a written justification next to it, and note that
 #: any entry also forfeits superstep eligibility for its plan.
 KNOWN_VETOED: dict = {}
