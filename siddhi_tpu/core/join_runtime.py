@@ -602,7 +602,7 @@ class JoinQueryRuntime:
             if pair_cap < lane.shape[0]:
                 n_matches = jnp.sum(pv, dtype=jnp.int32)
                 dropped = jnp.maximum(n_matches - pair_cap, 0) + truncated
-                lane, brow, pv = compact_pairs(lane, brow, pv, pair_cap)
+                lane, brow, pv = compact_pairs(brow, pv, k_max, pair_cap)
             else:
                 dropped = truncated
 

@@ -162,25 +162,56 @@ def probe_equi(plan: JoinPlan, probe_scope: Scope, probe_valid: jax.Array,
     return probe_lane, build_row.reshape(-1), cand_valid.reshape(-1)
 
 
-def compact_pairs(probe_lane: jax.Array, build_row: jax.Array,
-                  pair_valid: jax.Array, pair_cap: int):
+def compact_pairs(build_row: jax.Array, pair_valid: jax.Array, k_max: int,
+                  pair_cap: int):
     """Compact the sparse [B*k_max] candidate block to `pair_cap` lanes.
 
     Matches are typically ~1 per probe event, so downstream frame gathers,
     residual verification, and the selector would otherwise run at k_max x
-    the real pair count. One cumsum + one 2-word row scatter; candidate
-    order (probe-lane major) is preserved, keeping emission order intact.
-    Pairs beyond pair_cap are dropped (bounded fan-out, like k_max — size
-    via dtypes.config.join_pair_cap_factor)."""
-    pos = jnp.cumsum(pair_valid.astype(jnp.int32)) - 1
-    dest = jnp.where(pair_valid & (pos < pair_cap), pos, pair_cap)
-    packed = jnp.stack([probe_lane.astype(jnp.int32),
-                        build_row.astype(jnp.int32)], axis=1)
-    rows = jnp.zeros((pair_cap, 2), jnp.int32).at[dest].set(
-        packed, mode="drop")
-    n = jnp.minimum(jnp.sum(pair_valid, dtype=jnp.int32), pair_cap)
-    pv = jnp.arange(pair_cap, dtype=jnp.int32) < n
-    return rows[:, 0], rows[:, 1], pv
+    the real pair count. The block is [B, k_max] in probe-major order (every
+    `probe_*` above hands it over so), and no candidate lane is scattered:
+
+    1. each probe's row is left-justified by a fused k_max x k_max one-hot
+       reduce (dense; order within a probe kept, oldest first);
+    2. an exclusive cumsum of the per-probe counts gives each probe's first
+       pair lane, and ONE scatter of at most B single words writes the
+       probe's number there (sorted; probes with no match share the lane of
+       the next probe that has one, which `max` lets win);
+    3. a cummax fills the probe number forward over its run of pair lanes —
+       this IS the pair's probe lane — and a second one fills the run's
+       start, so a pair's place within its probe costs no gather;
+    4. one `pair_cap`-lane gather reads the survivors from the
+       left-justified rows.
+
+    Candidate order (probe-lane major) is preserved, keeping emission order
+    intact. Pairs beyond pair_cap are dropped, a probe that straddles the
+    cap keeping its oldest (bounded fan-out, like k_max — size via
+    dtypes.config.join_pair_cap_factor); lanes past the survivors read 0.
+    Returns (probe_lane i32[pair_cap], build_row i32[pair_cap],
+    pair_valid bool[pair_cap])."""
+    K = k_max
+    ok = pair_valid.reshape(-1, K)
+    cand = build_row.astype(jnp.int32).reshape(-1, K)
+    B = ok.shape[0]
+    rank = jnp.cumsum(ok.astype(jnp.int32), axis=1) - 1
+    count = rank[:, -1] + 1
+    hit = ok[:, :, None] & (
+        rank[:, :, None] == jnp.arange(K, dtype=jnp.int32)[None, None, :])
+    packed = jnp.sum(jnp.where(hit, cand[:, :, None], 0), axis=1,
+                     dtype=jnp.int32)  # [B, K]
+
+    ends = jnp.cumsum(count)
+    off = ends - count
+    j = jnp.arange(pair_cap, dtype=jnp.int32)
+    heads = jnp.full((pair_cap,), -1, jnp.int32).at[off].max(
+        jnp.arange(B, dtype=jnp.int32), indices_are_sorted=True, mode="drop")
+    lane = jax.lax.cummax(heads)
+    start = jax.lax.cummax(jnp.where(heads >= 0, j, 0))
+    pv = j < jnp.minimum(ends[-1], pair_cap)
+    lane = jnp.where(pv, lane, 0)
+    src = jnp.where(pv, lane * K + (j - start), 0)
+    rows = packed.reshape(-1).at[src].get(mode="promise_in_bounds")
+    return lane, jnp.where(pv, rows, 0), pv
 
 
 class MultimapState(NamedTuple):

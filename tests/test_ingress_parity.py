@@ -270,7 +270,9 @@ def test_paced_frames_match_the_synchronous_path(case):
             # every chunk of the frame is delivered with nothing behind it
             # and no flush: on the parent the last one stood until the next
             # frame of its own stream
-            deadline = time.monotonic() + 5.0
+            # (the first wait includes the steps' compile: seconds on a
+            # loaded machine; the deadline only tells a hold from a delivery)
+            deadline = time.monotonic() + 30.0
             while sum(p._batches for p in pipes) < sent:
                 assert time.monotonic() < deadline, \
                     [p.stats_snapshot() for p in pipes]

@@ -22,7 +22,7 @@ from siddhi_tpu.io import wire
 from .join_reference import LEFT, RIGHT, WindowedJoin
 
 STREAMS = ("cseEventStream", "quoteEventStream")
-STRIDE = 1000  # event indexes per frame number; frames are smaller
+STRIDE = 10000  # event indexes per frame number; frames are smaller
 APP = """
 @app:name('Join{batch}x{window}')
 @Async(buffer.size='{batch}', workers='2')
@@ -145,7 +145,10 @@ class Deployment:
 
 
 SIZES = {"batch_under_window": (64, 200), "batch_is_window": (128, 128),
-         "batch_over_window": (256, 200)}  # the last: join_100k's regime
+         "batch_over_window": (256, 200),  # join_100k's regime
+         # and its step: 4,096 x join_max_matches 16 = 65,536 candidate
+         # lanes compacted to a pair_cap of 32,768 (batches over 2,048 do)
+         "batch_compacts": (4096, 3000)}
 _deployments: dict = {}
 
 
@@ -221,8 +224,9 @@ SCHEDULES = {"alternating": _alternating, "runs_of_one_side":
              _runs_of_one_side, "two_threads": _two_threads}
 
 
-@pytest.mark.parametrize("schedule", SCHEDULES)
-@pytest.mark.parametrize("size", SIZES)
+@pytest.mark.parametrize("size,schedule", [
+    (size, schedule) for size in SIZES for schedule in SCHEDULES
+    if size != "batch_compacts" or schedule == "alternating"])
 def test_engine_equals_the_per_event_reference(size, schedule):
     batch, window = SIZES[size]
     d = _deployment(size, batch, window)

@@ -5,6 +5,7 @@ Mirrors the reference's join suite
 black-box through the public API.
 """
 
+import numpy as np
 import pytest
 
 from siddhi_tpu import SiddhiManager
@@ -187,3 +188,177 @@ class TestHighFanoutPairs:
         assert len(got) == 40
         assert sorted({p[0] for p in got}) == [100, 101, 102, 103]
         assert sorted({p[1] for p in got}) == list(range(10))
+
+
+# ------------------------------------------------- the pair compaction alone
+
+
+def _block(case: str, B: int, K: int, cap: int):
+    """(cand i32[B,K], ok bool[B,K]) of one named shape of candidate block.
+    Candidates are distinct and non-zero so that a zero can only be an
+    invalid lane's."""
+    rng = np.random.default_rng([29, B, K, cap])
+    cand = (1 + rng.permutation(B * K)).astype(np.int32).reshape(B, K)
+    ok = np.zeros((B, K), bool)
+    if case == "empty":
+        pass
+    elif case == "one_a_probe":  # join_100k's regime: any one column each
+        ok[np.arange(B), rng.integers(0, K, B)] = rng.random(B) < 0.9
+    elif case == "sparse_random":
+        ok = rng.random((B, K)) < 0.2
+    elif case == "every_lane":
+        ok[:] = True
+    elif case == "one_probe_has_all":
+        ok[B // 2] = True
+    elif case in ("just_under_cap", "at_cap", "over_cap_inside_a_run"):
+        # whole rows of K until the cap is near, then a run of K - 1 that
+        # ends one short of it, on it, or straddles it
+        full, rest = divmod(cap, K)
+        assert full + 2 < B and K > 2 and rest == 0
+        ok[:full - 1] = True
+        tail = {"just_under_cap": K - 1, "at_cap": K,
+                "over_cap_inside_a_run": K}[case]
+        ok[full, K - tail:] = True  # a probe with none lies between
+        if case == "over_cap_inside_a_run":
+            ok[full - 1, 1:3] = True  # so the cap falls inside row `full`
+            ok[full + 1] = True  # and a whole probe beyond it
+    else:
+        raise AssertionError(case)
+    return cand, ok
+
+
+_COMPACTIONS = [  # (case, B, K, pair_cap)
+    ("empty", 64, 16, 256), ("one_a_probe", 512, 16, 2048),
+    ("sparse_random", 256, 16, 1024), ("sparse_random", 300, 5, 700),
+    ("every_lane", 64, 16, 256), ("every_lane", 64, 16, 1000),
+    ("one_probe_has_all", 64, 16, 256), ("one_probe_has_all", 64, 16, 8),
+    ("just_under_cap", 64, 16, 256), ("at_cap", 64, 16, 256),
+    ("over_cap_inside_a_run", 64, 16, 256),
+    ("sparse_random", 512, 1, 64), ("every_lane", 512, 1, 100),
+    ("empty", 128, 1, 32)]
+
+
+@pytest.mark.parametrize("case,B,K,cap", _COMPACTIONS, ids=[
+    f"{c}-{b}x{k}-cap{p}" for c, b, k, p in _COMPACTIONS])
+def test_compact_pairs_equals_flatnonzero(case, B, K, cap):
+    """`compact_pairs` alone against numpy: the survivors are the first
+    `pair_cap` valid candidates in probe-major order, oldest first within a
+    probe; lanes past them read zero; what the call site counts as dropped
+    is what did not come out."""
+    import jax.numpy as jnp
+
+    from siddhi_tpu.ops.join import compact_pairs
+    cand, ok = _block(case, B, K, cap)
+    lane, row, pv = (np.asarray(x) for x in compact_pairs(
+        jnp.asarray(cand.reshape(-1)), jnp.asarray(ok.reshape(-1)), K, cap))
+    assert lane.shape == row.shape == pv.shape == (cap,)
+    assert lane.dtype == row.dtype == np.int32 and pv.dtype == bool
+    keep = np.flatnonzero(ok.reshape(-1))[:cap]
+    n = keep.size
+    assert pv.tolist() == [True] * n + [False] * (cap - n)
+    assert lane[:n].tolist() == (keep // K).tolist()
+    assert row[:n].tolist() == cand.reshape(-1)[keep].tolist()
+    assert not lane[n:].any() and not row[n:].any()
+    # join_runtime's `dropped` (less the walk's truncations)
+    assert max(int(ok.sum()) - cap, 0) == int(ok.sum()) - int(pv.sum())
+
+
+# ------------------------------- the compacting step, through the engine
+
+
+_BIG = 4096  # x join_max_matches 16 = 65,536 candidate lanes, pair_cap 32,768
+
+
+class TestCompactingStep:
+    """Batches over 2,048 compact their candidate block before any per-pair
+    gather (`join_runtime._make_step`): the same rows in the same order as
+    the narrow batches above give."""
+
+    def test_stream_table_join_sort_path(self):
+        app = ("define stream S (k int, qty int);\n"
+               "define table Prices (k int, price float);\n"
+               "from S join Prices on S.k == Prices.k "
+               "select S.k as k, qty, price insert into OutStream;")
+        rt, got = make(app, batch_size=_BIG)
+        rng = np.random.default_rng(2901)
+        # every third key twice in the table, a quarter of the keys absent
+        table = [(k, float(k)) for k in range(0, 600)] + \
+            [(k, k + 0.5) for k in range(0, 600, 3)]
+        rt.tables["Prices"].insert_rows(table)
+        keys = rng.integers(0, 800, _BIG).tolist()
+        rt.get_input_handler("S").send_batch(
+            [(k, i) for i, k in enumerate(keys)])
+        rt.flush()
+        want = [(k, i, p) for i, k in enumerate(keys)
+                for tk, p in table if tk == k]
+        assert 2 * _BIG > len(want) > _BIG  # compacted, and under the cap
+        assert got == want
+        assert not rt.statistics_report()["overflow"]
+
+    def test_left_outer_join(self):
+        app = ("define stream L (k int, v int);\n"
+               "define stream R (k int, v int);\n"
+               "from L#window.length(3000) left outer join "
+               "R#window.length(3000) on L.k == R.k "
+               "select L.k as k, L.v as lv, R.v as rv "
+               "insert into OutStream;")
+        rt, got = make(app, batch_size=_BIG)
+        rng = np.random.default_rng(2902)
+        right = rng.integers(0, 3000, _BIG).tolist()
+        left = rng.integers(0, 3000, _BIG).tolist()
+        rt.get_input_handler("R").send_batch(
+            [(k, 10_000 + i) for i, k in enumerate(right)])
+        rt.flush()
+        assert got == []  # a right arrival with no match emits nothing
+        rt.get_input_handler("L").send_batch(
+            [(k, i) for i, k in enumerate(left)])
+        rt.flush()
+        window = [(k, 10_000 + i) for i, k in enumerate(right)][-3000:]
+        rows_of: dict = {}
+        for k, v in window:  # oldest first
+            rows_of.setdefault(k, []).append(v)
+        pairs = [(k, i, rv) for i, k in enumerate(left)
+                 for rv in rows_of.get(k, ())]
+        alone = [(k, i) for i, k in enumerate(left) if k not in rows_of]
+        assert len(pairs) > 2048 and len(alone) > 1000
+        # the pair block first, then the unmatched probes' null frames
+        assert got[:len(pairs)] == pairs
+        assert [g[:2] for g in got[len(pairs):]] == alone
+        assert not rt.statistics_report()["overflow"]
+
+    def test_no_scatter_moves_candidate_lanes(self):
+        """What PR 29 bought, kept from coming back: the pair compaction
+        scatters one head word per probe, never the 16x candidate lanes
+        (38 ns an update on a v5e: 80 of the step's 183 ms at join_100k's
+        width). Read from the jaxpr of both probe directions."""
+        import jax
+
+        from siddhi_tpu.analysis.jaxpr_pass import _steps_of, _walk
+        from siddhi_tpu.core import dtypes
+        app = ("define stream L (k int, v int);\n"
+               "define stream R (k int, v int);\n"
+               "from L#window.length(3000) join R#window.length(3000) "
+               "on L.k == R.k "
+               "select L.k as k, L.v as lv, R.v as rv insert into OutStream;")
+        rt, _ = make(app, batch_size=_BIG)
+        (join,) = [q for q in rt.query_runtimes.values()
+                   if hasattr(q, "_step_left")]
+        pair_cap = max(dtypes.config.join_pair_cap_factor * _BIG, 32768)
+        assert pair_cap < _BIG * join.k_max  # the step compacts
+        steps = list(_steps_of(join))
+        assert [tag for tag, _, _ in steps] == ["/left", "/right"]
+        for tag, step, args in steps:
+            scatters = []
+
+            def visit(eqn):
+                if eqn.primitive.name.startswith("scatter"):
+                    operand, _, updates = (v.aval for v in eqn.invars[:3])
+                    scatters.append((operand.shape, updates.shape))
+
+            _walk(jax.make_jaxpr(step.__wrapped__)(*args).jaxpr, visit)
+            assert scatters, tag  # the walk saw the step's body
+            wide = [s for s in scatters if int(np.prod(s[1])) > _BIG]
+            assert not wide, (tag, wide)
+            # the one scatter into the pair block: a head word per probe
+            assert [u for o, u in scatters if o[0] == pair_cap] \
+                == [(_BIG,)], (tag, scatters)

@@ -140,7 +140,11 @@ def test_kill_one_host_shard_failover(worker_fleet, tmp_path):
         heartbeat_interval_s=0.3, miss_threshold=3,
         max_retries=1, retry_initial_s=0.02, retry_max_s=0.05,
         capture=["Out"], bundle_dir=bundles,
-        recorder_cooldown_s=0.0, recorder_min_interval_s=0.0)
+        recorder_cooldown_s=0.0, recorder_min_interval_s=0.0,
+        # the deploy POST waits for a cold worker to build the app: over the
+        # default 5 s when the suite's other workers hold the cores (a dead
+        # host refuses at once, so no step below waits this long)
+        request_timeout_s=30.0)
     front.start()
     try:
         frames = _frames(30)
