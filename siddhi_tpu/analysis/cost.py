@@ -460,14 +460,15 @@ def _pattern_query_cost(node: QueryNode, plan: PlanGraph, registry,
     import dataclasses as dc
 
     from ..core import dtypes
-    from ..core.pattern_runtime import _PatternPlan, _RefRewriter
+    from ..core.pattern_runtime import (_PatternPlan, _RefRewriter,
+                                        pending_capacity_of)
     from ..ops.expr_compile import TypeResolver
     from ..ops.selector import CompiledSelector
 
     ec = ElementCost(name, "pattern", node_index=node.index)
     sis: StateInputStream = node.query.input_stream
     pplan = _PatternPlan(sis, None)
-    P = dtypes.config.pattern_pending_capacity
+    P = pending_capacity_of(node.query)  # the query's own, not the global
 
     ref_types: dict[str, dict] = {}
     for pos in pplan.positions:
@@ -501,7 +502,8 @@ def _pattern_query_cost(node: QueryNode, plan: PlanGraph, registry,
             total += P * (1 + 8)  # frame_valid + frame_ts
         # start_ts/last_seq/armed_ts (int64) + valid + leg_done[P,2] + origin
         total += P * (8 + 8 + 8 + 1 + 2 + 4)
-    total += 1 + 8 + 8 + 8 + 8  # active0/seq/dropped/armed0_ts/gate0_seq
+    # active0/seq/dropped/armed0_ts/gate0_seq/expired/live_hwm
+    total += 1 + 8 + 8 + 8 + 8 + 8 + 4
     ec.state_bytes = total
 
     # --- selector over captured frames (rewritten refs, like the runtime) --

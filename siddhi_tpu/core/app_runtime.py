@@ -1425,11 +1425,12 @@ class SiddhiAppRuntime:
             self._cost_report = rep
         return rep
 
-    def collect_overflow(self) -> None:
+    def collect_overflow(self, report: bool = False) -> None:
         """Sweep every runtime's device state for capacity-overflow counters
         and surface them via Statistics.record_overflow (one-shot warning
         per counter). Syncs a handful of scalars — called from
-        statistics_report() and the heartbeat, not the hot path.
+        statistics_report() (`report`: high waters start anew) and the
+        heartbeat, not the hot path.
 
         Counters: window-ring overwrites of live rows (SlidingState /
         expression windows), key-table unresolved lanes (group-by, distinct
@@ -1443,7 +1444,7 @@ class SiddhiAppRuntime:
         from ..ops.windows import SlidingState
         from ..ops.windows_extra import KeyedSessionState
         from .join_runtime import JoinQueryRuntime
-        from .pattern_runtime import PatternState
+        from .pattern_runtime import PatternQueryRuntime, PatternState
 
         stats = self.ctx.statistics
 
@@ -1509,11 +1510,17 @@ class SiddhiAppRuntime:
             for key, qr in joins.items():
                 pending[key] = [qr._dropped_dev]
             pending = jax.tree_util.tree_map(jnp.copy, pending)
-        fetched = jax.device_get(pending)  # ONE device->host round trip
+            patterns = {n: qr.device_counters(report)
+                        for n, qr in self.query_runtimes.items()
+                        if isinstance(qr, PatternQueryRuntime)}
+        # ONE device->host round trip
+        fetched, counted = jax.device_get((pending, patterns))
         for name, arrs in fetched.items():
             stats.record_overflow(name, int(sum(np.sum(a) for a in arrs)))
         for key, qr in joins.items():
             qr.dropped_synced = int(fetched[key][0])
+        for n, values in counted.items():
+            self.query_runtimes[n].sync_counters(values)
 
     # ---------------------------------------------------------------- debugger
 

@@ -312,7 +312,7 @@ class Statistics:
     def report(self, runtime=None) -> dict:
         elapsed = max(time.time() - self.started_at, 1e-9)
         if runtime is not None:
-            runtime.collect_overflow()
+            runtime.collect_overflow(report=True)
         out = {
             "level": self.level,
             "uptime_seconds": elapsed,
@@ -388,6 +388,14 @@ class Statistics:
                      if isinstance(qr, JoinQueryRuntime)}
             if joins:
                 out["joins"] = joins
+            # pattern queries (core/pattern_runtime.py): steps per fed
+            # stream, pending capacity and fill, expiries, the drop counter
+            from .pattern_runtime import PatternQueryRuntime
+            patterns = {name: qr.stats_snapshot()
+                        for name, qr in runtime.query_runtimes.items()
+                        if isinstance(qr, PatternQueryRuntime)}
+            if patterns:
+                out["patterns"] = patterns
         if runtime is not None:
             wal = getattr(runtime, "wal", None)
             if wal is not None:

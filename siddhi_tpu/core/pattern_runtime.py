@@ -43,10 +43,13 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from ..errors import DefinitionNotExistError, SiddhiAppCreationError
 from ..extension.registry import Registry
 from ..ops.expr_compile import Scope, TypeResolver, compile_expression
+from ..ops.keyed_match import (first_arrival_by_key, gather_lanes,
+                                key_words, scatter_lanes)
 from ..ops.search import stable_partition_order
 from ..ops.selector import CompiledSelector
 from ..query_api.definition import Attribute, AttributeType, StreamDefinition
@@ -63,10 +66,13 @@ from ..query_api.execution import (
     StateType,
     StreamStateElement,
 )
-from ..query_api.expression import Expression, Variable
+from ..query_api.expression import (And, Compare, CompareOp, Expression,
+                                    IsNull, Variable)
+from ..telemetry.tracing import StageCells
 from . import dtypes
 from .context import SiddhiAppContext
 from .event import EventBatch, EventType, StreamCodec
+from .join_runtime import _named
 from .query_runtime import QueryCallback
 from .stream import Receiver, StreamJunction
 
@@ -411,6 +417,75 @@ class _RefRewriter:
         return expr
 
 
+def pending_capacity_of(query: Query) -> int:
+    """A pattern query's pending-table capacity (partial matches held per
+    position): `@capacity(pending='N')` on the query, else the process-wide
+    `config.pattern_pending_capacity`. analysis/cost.py prices the same."""
+    ann = next((a for a in (query.annotations or ())
+                if a.name.lower() == "capacity"), None)
+    text = ann.element("pending") if ann is not None else None
+    if text is None:
+        return dtypes.config.pattern_pending_capacity
+    try:
+        cap = int(text)
+    except ValueError:
+        cap = 0
+    if cap < 1 or cap > 2**30:
+        raise SiddhiAppCreationError(
+            f"@capacity(pending={text!r}): a pattern's pending capacity is "
+            "a whole number of partial matches, from 1 to 2^30")
+    return cap
+
+
+def _conjuncts(expr) -> list:
+    if isinstance(expr, And):
+        return _conjuncts(expr.left) + _conjuncts(expr.right)
+    return [expr]
+
+
+def _refs_read(expr, resolver: TypeResolver) -> set:
+    """Frame refs an expression reads (None: the resolver's default frame)."""
+    if isinstance(expr, Variable):
+        return {resolver.resolve(expr)[0]}
+    refs: set = set()
+    if isinstance(expr, IsNull) and expr.stream_id is not None:
+        refs.add(expr.stream_id)
+    if dataclasses.is_dataclass(expr):
+        for f in dataclasses.fields(expr):
+            v = getattr(expr, f.name)
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                if isinstance(sub, Expression):
+                    refs |= _refs_read(sub, resolver)
+    return refs
+
+
+@dataclass
+class _KeyPlan:
+    """A fed position whose condition is `arriving.attr == captured.attr`
+    and conjuncts that read the arrival alone: matched by key."""
+
+    arr_attr: str
+    cap_ref: str
+    cap_attr: str
+    residual: list  # compiled conjuncts over the arriving frame
+
+
+#: attribute types whose `==` is equality of raw device words
+_KEY_TYPES = (AttributeType.STRING, AttributeType.INT, AttributeType.LONG,
+              AttributeType.BOOL)
+
+
+def _upstream_order(valid, order) -> jax.Array:
+    """Permutation (int32) that puts the valid lanes first, by completing
+    event, then by the partial match's age, then by lane: `order` is
+    (int32 lane of the completing event in its batch, int64 last seq of
+    the entry before it)."""
+    comp, prev = order
+    lanes = jnp.arange(valid.shape[0], dtype=jnp.int32)
+    first = jnp.where(valid, comp, jnp.int32(2 ** 31 - 1))
+    return lax.sort((first, prev, lanes), num_keys=3)[-1]
+
+
 class PendingTable(NamedTuple):
     frames: dict  # {ref: {attr: [P]}}
     frame_valid: dict  # {ref: [P] bool}
@@ -449,13 +524,21 @@ class PatternState(NamedTuple):
     #: iteration finishes — EveryPatternTestCase testQuery5 pairing).
     #: None-default for pre-round-5 snapshot tolerance
     gate0_seq: jax.Array = None  # int64
+    #: entries `within` let go, over the query's life (int64), and the high
+    #: water of live partial matches over all positions since the last
+    #: statistics_report() (int32). None-default like the fields above
+    expired: jax.Array = None
+    live_hwm: jax.Array = None
 
 
 class PatternQueryRuntime:
     """Runtime for one pattern/sequence query."""
 
     def __init__(self, query: Query, ctx: SiddhiAppContext, junctions: dict,
-                 tables: dict, registry: Registry, name: str) -> None:
+                 tables: dict, registry: Registry, name: str,
+                 keyed: bool = True) -> None:
+        """`keyed=False` keeps every position on the dense `[B, P]` mask
+        (the tests hold the keyed match to it)."""
         assert isinstance(query.input_stream, StateInputStream)
         sis: StateInputStream = query.input_stream
         self.query = query
@@ -466,7 +549,7 @@ class PatternQueryRuntime:
         self.output_junction = None
         self.table_executor = None
         self.tables = tables
-        self.P = dtypes.config.pattern_pending_capacity
+        self.P = pending_capacity_of(query)
 
         self.plan = _PatternPlan(sis, ctx)
         plan = self.plan
@@ -539,6 +622,15 @@ class PatternQueryRuntime:
                 leg.compiled = [
                     compile_expression(rewriter.rewrite(f), leg_resolver, registry)
                     for f in leg.filters]
+        #: position index -> _KeyPlan, for the positions matched by key
+        #: (none with `keyed=False`: the dense mask matches them all)
+        self._key_plans: dict[int, _KeyPlan] = {}
+        for pos in plan.positions[1:] if keyed else ():
+            kp = self._key_plan(pos, rewriter, frames, codecs,
+                                set_projections)
+            if kp is not None:
+                self._key_plans[pos.index] = kp
+        self._refuse_oversized_masks()
 
         # --- selector over all captured frames ---
         select_all = []
@@ -571,15 +663,33 @@ class PatternQueryRuntime:
 
         # --- state & jitted steps (one per junction + heartbeat) ---
         self.state = self._init_state()
+        # named per fed stream, so a profiler's `XLA Modules` line tells the
+        # pattern's programs from every other query's `jit_step`
         if self.merged_mode:
             self._steps = {MERGED_SID: jax.jit(
-                self._make_step(MERGED_SID), donate_argnums=(0,))}
+                _named(self._make_step(MERGED_SID), "pattern_step_merged"),
+                donate_argnums=(0,))}
         else:
             self._steps = {
-                sid: jax.jit(self._make_step(sid), donate_argnums=(0,))
+                sid: jax.jit(_named(self._make_step(sid),
+                                    "pattern_step_" + sid),
+                             donate_argnums=(0,))
                 for sid in self.junctions
             }
-        self._heartbeat_step = jax.jit(self._make_step(None), donate_argnums=(0,))
+        self._heartbeat_step = jax.jit(
+            _named(self._make_step(None), "pattern_heartbeat"),
+            donate_argnums=(0,))
+        # statistics_report()["patterns"][name]: per fed stream the dispatch
+        # of one step, and the drop counter's device sync; the counters are
+        # cumulative over steps, read as deltas, and live in the state
+        self.cells = StageCells(
+            tuple("step_" + sid for sid in self._steps) + ("drop_sync",))
+        self._out_lanes = 0
+        self._steps_run = 0
+        #: as last read from the device: at a report, and the drop counter
+        #: at every 64th step too
+        self.synced = {"live": 0, "live_hwm": 0, "expired": 0, "dropped": 0}
+        self._drop_warned = False
         self.has_time_semantics = (
             plan.within_ms is not None
             or (plan.head_group is not None
@@ -587,6 +697,79 @@ class PatternQueryRuntime:
             or any(p.kind == "absent" or
                    (p.kind == "notand" and p.wait_ms is not None)
                    for p in plan.positions))
+
+    # ------------------------------------------------------------ keyed match
+
+    def _key_plan(self, pos: _Position, rewriter, frames, codecs,
+                  set_projections) -> Optional[_KeyPlan]:
+        """The position's key plan if its compiled condition shows one:
+        a plain pattern position (one leg; not a sequence, a count, a
+        sticky or grouped `every`) with a top-level conjunct
+        `arriving.attr == captured.attr` of one attribute type, every other
+        conjunct reading the arriving event alone. Anything else stays on
+        the dense mask."""
+        plan = self.plan
+        groups = ([plan.head_group] if plan.head_group else []) \
+            + plan.mid_groups
+        if (pos.kind != "normal" or pos.sticky or pos.optional
+                or plan.is_sequence
+                or any(g.head <= pos.index <= g.end for g in groups)):
+            return None
+        leg = pos.legs[0]
+        resolver = TypeResolver(frames, leg.ref, codecs, set_projections)
+        own = {None, leg.ref}
+        if frames.get(leg.stream_id) is frames[leg.ref]:
+            own.add(leg.stream_id)  # the bare stream name, where it is this leg
+        captured = set(self._captured_refs(pos.index)) - own
+        key, rest = None, []
+        for f in leg.filters:
+            for c in _conjuncts(rewriter.rewrite(f)):
+                sides = None
+                if (key is None and isinstance(c, Compare)
+                        and c.op == CompareOp.EQUAL
+                        and isinstance(c.left, Variable)
+                        and isinstance(c.right, Variable)):
+                    l, r = resolver.resolve(c.left), resolver.resolve(c.right)
+                    if l[0] in captured and r[0] in own:
+                        l, r = r, l
+                    if (l[0] in own and r[0] in captured and l[2] == r[2]
+                            and l[2] in _KEY_TYPES):
+                        sides = (l, r)
+                if sides is not None:
+                    key = sides
+                elif _refs_read(c, resolver) <= own:
+                    rest.append(c)
+                else:
+                    return None  # a conjunct reads captured attributes
+        if key is None:
+            return None
+        (_, arr_attr, _), (cap_ref, cap_attr, _) = key
+        return _KeyPlan(arr_attr, cap_ref, cap_attr, [
+            compile_expression(c, resolver, self.registry) for c in rest])
+
+    def _refuse_oversized_masks(self) -> None:
+        """A dense position builds `pred[B, P]` whole (XLA does not fuse it
+        away): refuse at build, with the reason, what the first frame would
+        die of."""
+        stats = jax.devices()[0].memory_stats() or {}
+        limit = int(stats.get("bytes_limit", 16 << 30))
+        for pos in self.plan.positions[1:]:
+            if pos.index in self._key_plans:
+                continue
+            for leg in pos.legs:
+                B = (self.merged_junction if self.merged_mode
+                     else self.junctions[leg.stream_id]).batch_size
+                if B * self.P > limit:
+                    raise SiddhiAppCreationError(
+                        f"pattern {self.name!r}: position {pos.index} "
+                        f"({leg.ref}) is matched through a dense "
+                        f"[{B}, {self.P}] condition mask of "
+                        f"{B * self.P:,} bytes, more than the device's "
+                        f"{limit:,}; only a condition with a top-level "
+                        "`arriving.attr == captured.attr` (other conjuncts "
+                        "reading the arriving event alone) is matched by "
+                        "key — lower @capacity(pending=...) or the batch "
+                        "size, or restate the condition")
 
     # ---------------------------------------------------------- merged stream
 
@@ -699,6 +882,8 @@ class PatternQueryRuntime:
                  else self.ctx.timestamp_generator.current_time())
                 if leading_absent else -(2 ** 62)),
             gate0_seq=jnp.int64(0),
+            expired=jnp.int64(0),
+            live_hwm=jnp.int32(0),
         )
 
     # ------------------------------------------------------------------- step
@@ -732,6 +917,30 @@ class PatternQueryRuntime:
         P = pend.valid.shape[0] if pend is not None else 1
         return jnp.broadcast_to(m, (B, P))
 
+    def _first_by_key(self, kp: _KeyPlan, leg, batch: EventBatch,
+                      pend: PendingTable, lane_rank, seq0, now):
+        """(found [P], lane [P]): per pending entry the first arriving lane
+        that carries its key, passes the conjuncts that read the arrival
+        alone, and arrived after the entry's last captured event — what
+        `argmin` over the dense mask finds, in (B + P) log (B + P)."""
+        B = batch.ts.shape[0]
+        scope = Scope()
+        scope.add_frame(leg.ref, batch.cols, batch.ts, batch.valid,
+                        default=True)
+        scope.frames.setdefault(leg.stream_id, batch.cols)
+        scope.valids.setdefault(leg.stream_id, batch.valid)
+        scope.ts.setdefault(leg.stream_id, batch.ts)
+        scope.extras["now"] = now
+        ok = batch.valid
+        for ce in kp.residual:
+            ok = ok & ce(scope)
+        after = jnp.clip(pend.last_seq - seq0, -1, B - 1).astype(jnp.int32)
+        return first_arrival_by_key(
+            key_words(batch.cols[kp.arr_attr]), ok,
+            lane_rank.astype(jnp.int32),
+            key_words(pend.frames[kp.cap_ref][kp.cap_attr]), pend.valid,
+            after)
+
     def _make_step(self, junction_sid: Optional[str]):
         plan = self.plan
         selector = self.selector
@@ -742,6 +951,7 @@ class PatternQueryRuntime:
         within = plan.within_ms
         is_seq = plan.is_sequence
         every = plan.every
+        playback = bool(self.ctx.playback)
 
         hg = plan.head_group
         mid_heads = {g.head: g for g in plan.mid_groups}
@@ -777,6 +987,7 @@ class PatternQueryRuntime:
             # collected outputs: one block per completion source
             out_blocks = []  # (frames {ref: cols}, fvalid {ref}, fts, ts, valid)
             drop_acc = [jnp.int64(0)]  # pending-table insert overflow
+            expired_acc = [jnp.int64(0)]  # entries `within` let go
             armed0_out = [state.armed0_ts]  # leading-absent lazy arming
             gate_ctx = {"active0": active0_box, "gate0": gate0_box}
 
@@ -793,6 +1004,8 @@ class PatternQueryRuntime:
                     # ITERATION (start_ts = the iteration's first capture)
                     ok = ok & (now - pend.start_ts <= jnp.int64(gw))
                 died = pend.valid & ~ok
+                expired_acc[0] = expired_acc[0] + jnp.sum(
+                    died, dtype=jnp.int64)
                 if hg is not None and hg.head < pos_index <= hg.end:
                     # the in-flight head-group iteration expired: re-arm
                     # the gate or the every-loop would stall forever
@@ -812,8 +1025,13 @@ class PatternQueryRuntime:
             # in place: expire() may clear busy latches on EARLIER tables
             # (mid-every origins), which a rebinding comprehension would
             # discard
+            # a position matched by key expires per arrival, against the
+            # arriving event's own timestamp (see the match below); the wall
+            # clock still sweeps it, the app's playback clock does not: on
+            # the served path that one runs ahead with every frame decoded
             for _i in range(len(pending)):
-                pending[_i] = expire(pending[_i], _i + 1)
+                if not (playback and (_i + 1) in self._key_plans):
+                    pending[_i] = expire(pending[_i], _i + 1)
 
             merged = junction_sid == MERGED_SID
 
@@ -1173,6 +1391,7 @@ class PatternQueryRuntime:
 
                 pend0 = pending[pi - 1]
                 mid_g = mid_heads.get(pi)
+                key_plan = self._key_plans.get(pi)
                 leg_iters = list(enumerate(pos.legs))
                 if is_seq and pos.kind == "logical":
                     # two passes: with strict contiguity, the second leg's
@@ -1193,6 +1412,61 @@ class PatternQueryRuntime:
                         continue
                     pend = pending[pi - 1]
                     leg_b = self._leg_batch(batch, leg)
+                    if key_plan is not None:
+                        # matched by key: the entry's first arrival that
+                        # satisfies the condition takes it. `within` as
+                        # upstream's isExpired: EVERY arrival at the position
+                        # first lets go of the entries older than the bound
+                        # against its own timestamp — so an entry lives to its
+                        # match (or through the batch) only if the largest
+                        # stamp among the arrivals up to there is within the
+                        # bound of its start
+                        found, b_star = self._first_by_key(
+                            key_plan, leg, leg_b, pend, lane_rank,
+                            state.seq, now)
+                        # the arrival's row, by ONE packed gather (its
+                        # sequence as the 32-bit rank: a word less)
+                        row = (leg_b.cols, batch.ts,
+                               lane_rank.astype(jnp.int32))
+                        if within is not None:
+                            # (not lax.cummax: of an int64 it takes the
+                            # TPU's compiler 200 s, this scan 3)
+                            newest = lax.associative_scan(
+                                jnp.maximum, jnp.where(
+                                    batch.valid, batch.ts,
+                                    jnp.int64(-(2 ** 62))))
+                            row += (newest,)
+                        cap, cap_ts, comp_rank, *seen = gather_lanes(
+                            row, b_star)
+                        comp_seq = state.seq + comp_rank.astype(jnp.int64)
+                        matched = found
+                        if within is not None:
+                            died = pend.valid & (
+                                jnp.where(found, seen[0], newest[-1])
+                                - pend.start_ts > jnp.int64(within))
+                            expired_acc[0] = expired_acc[0] + jnp.sum(
+                                died, dtype=jnp.int64)
+                            matched = found & ~died
+                            pend = pend._replace(valid=pend.valid & ~died)
+                        ins_frames = dict(pend.frames)
+                        ins_fvalid = dict(pend.frame_valid)
+                        ins_fts = dict(pend.frame_ts)
+                        ins_frames[leg.ref] = cap
+                        ins_fvalid[leg.ref] = matched
+                        ins_fts[leg.ref] = cap_ts
+                        pending[pi - 1] = pend._replace(
+                            valid=pend.valid & ~matched)
+                        self._advance(
+                            pending, out_blocks, pi + 1,
+                            ins_frames, ins_fvalid, ins_fts,
+                            jnp.where(matched, pend.start_ts, 0),
+                            jnp.where(matched,
+                                      jnp.maximum(comp_seq, pend.last_seq),
+                                      pend.last_seq),
+                            cap_ts, matched, drop_acc,
+                            origin=pend.origin, gate_ctx=gate_ctx,
+                            order=(b_star.astype(jnp.int32), pend.last_seq))
+                        continue
                     q = self._leg_cond(leg, leg_b, pend, now)  # [B,P]
                     q = q & pend.valid[None, :]
                     if mid_g is not None:
@@ -1308,7 +1582,8 @@ class PatternQueryRuntime:
                                   jnp.maximum(arr_seq[b_star], pend.last_seq),
                                   pend.last_seq),
                         comp_ts, adv_valid, drop_acc,
-                        origin=adv_origin, gate_ctx=gate_ctx)
+                        origin=adv_origin, gate_ctx=gate_ctx,
+                        order=(b_star.astype(jnp.int32), pend0.last_seq))
 
                 if pos.sticky and (merged or
                                    pos.legs[0].stream_id == junction_sid):
@@ -1385,6 +1660,8 @@ class PatternQueryRuntime:
 
             # ---- merge output blocks through the selector ----
             new_sel, out = self._emit(state.sel_state, out_blocks, now)
+            live = sum((jnp.sum(t.valid, dtype=jnp.int32) for t in pending),
+                       jnp.int32(0))
             new_state = PatternState(
                 pending=tuple(pending),
                 active0=active0_box[0],
@@ -1393,6 +1670,10 @@ class PatternQueryRuntime:
                 dropped=state.dropped + drop_acc[0],
                 armed0_ts=armed0_out[0],
                 gate0_seq=gate0_box[0],
+                expired=expired_acc[0] + (
+                    0 if state.expired is None else state.expired),
+                live_hwm=live if state.live_hwm is None else jnp.maximum(
+                    state.live_hwm, live),
             )
             return new_state, out
 
@@ -1402,7 +1683,8 @@ class PatternQueryRuntime:
 
     def _advance(self, pending: list, out_blocks: list, target_pos: int,
                  frames, fvalid, fts, start_ts, last_seq, armed_ts,
-                 valid, drop_acc=None, origin=None, gate_ctx=None) -> None:
+                 valid, drop_acc=None, origin=None, gate_ctx=None,
+                 order=None) -> None:
         """Move completed entries to `target_pos` (insert into its waiting
         table, or emit if past the last position). Optional count positions
         add an epsilon edge: entries also advance past them immediately
@@ -1413,7 +1695,14 @@ class PatternQueryRuntime:
 
         `origin` carries the spawning context slot for mid-every-group
         iteration entries; `gate_ctx` lets group-boundary crossings re-arm
-        their every-group (head gate scalars / mid busy latches)."""
+        their every-group (head gate scalars / mid busy latches).
+
+        `order` = (completing event's lane in its batch, the entry's own
+        last seq before it), per candidate lane: upstream walks its pending list
+        in arrival order for each arriving event, so candidates leave (into
+        the next table's free slots, or out of the query) by completing
+        event, then by the partial match's age. None: lane order is that
+        order already (arrivals starting a match)."""
         S = len(self.plan.positions)
         P = self.P
         if origin is None:
@@ -1446,25 +1735,42 @@ class PatternQueryRuntime:
                                 last_seq, mode="drop"))
                         origin = jnp.full(valid.shape, -1, jnp.int32)
             if target_pos >= S:
-                out_blocks.append((frames, fvalid, fts, armed_ts, valid))
+                out_blocks.append((frames, fvalid, fts, armed_ts, valid,
+                                   order))
                 return
             pending[target_pos - 1], n_drop = self._insert_entries(
                 pending[target_pos - 1], frames, fvalid, fts,
-                start_ts, last_seq, armed_ts, valid, origin)
+                start_ts, last_seq, armed_ts, valid, origin, order,
+                uses_legs=self._uses_legs(target_pos))
             if drop_acc is not None:
                 drop_acc[0] = drop_acc[0] + n_drop
             if not self.plan.positions[target_pos].optional:
                 return
             target_pos += 1
 
+    def _uses_legs(self, pos_index: int) -> bool:
+        """Whether the position's own table ever sets a `leg_done` flag."""
+        pos = self.plan.positions[pos_index]
+        return (pos.kind == "logical"
+                or (pos.kind == "notand" and pos.wait_ms is not None)
+                or any(g.head == pos_index for g in self.plan.mid_groups))
+
     def _insert_entries(self, dst: PendingTable, frames, fvalid, fts,
                         start_ts, last_seq, armed_ts, valid,
-                        origin=None) -> PendingTable:
-        """Insert [P]-aligned candidate entries into dst's free slots."""
+                        origin=None, order=None,
+                        uses_legs: bool = True) -> PendingTable:
+        """Insert candidate entries into dst's free slots, lowest slot
+        first, in `order` (see _advance; lane order without it)."""
         P = self.P
         free_order = stable_partition_order(~dst.valid)
         n_free = jnp.sum((~dst.valid).astype(jnp.int32))
-        rank = jnp.cumsum(valid.astype(jnp.int32)) - 1
+        if order is None:
+            rank = jnp.cumsum(valid.astype(jnp.int32)) - 1
+        else:
+            lanes = jnp.arange(valid.shape[0], dtype=jnp.int32)
+            perm = _upstream_order(valid, order)
+            rank = jnp.zeros_like(lanes).at[perm].set(
+                lanes, unique_indices=True)
         fits = valid & (rank < n_free)
         n_drop = jnp.sum(valid & ~fits, dtype=jnp.int64)
         slot = jnp.where(fits, free_order[jnp.clip(rank, 0, P - 1)], P)
@@ -1480,22 +1786,27 @@ class PatternQueryRuntime:
                 new_fts[ref] = dst.frame_ts[ref]
                 continue
             new_frames[ref] = {
-                n: dst.frames[ref][n].at[slot].set(src_cols[n], mode="drop")
+                n: scatter_lanes(dst.frames[ref][n], slot, src_cols[n])
                 for n in dst.frames[ref]}
             new_fvalid[ref] = dst.frame_valid[ref].at[slot].set(
                 fvalid.get(ref, valid), mode="drop")
-            new_fts[ref] = dst.frame_ts[ref].at[slot].set(
-                fts.get(ref, jnp.zeros_like(dst.frame_ts[ref])), mode="drop")
+            new_fts[ref] = scatter_lanes(
+                dst.frame_ts[ref], slot,
+                fts.get(ref, 0))
         if origin is None:
             origin = jnp.full(valid.shape, -1, jnp.int32)
         return PendingTable(
             frames=new_frames, frame_valid=new_fvalid, frame_ts=new_fts,
-            start_ts=dst.start_ts.at[slot].set(start_ts, mode="drop"),
-            last_seq=dst.last_seq.at[slot].set(last_seq, mode="drop"),
-            armed_ts=dst.armed_ts.at[slot].set(armed_ts, mode="drop"),
+            start_ts=scatter_lanes(dst.start_ts, slot, start_ts),
+            last_seq=scatter_lanes(dst.last_seq, slot, last_seq),
+            armed_ts=scatter_lanes(dst.armed_ts, slot, armed_ts),
             valid=dst.valid.at[slot].set(valid, mode="drop"),
+            # only a table whose position fills legs or latches (logical,
+            # timed not-and, a mid-every group's head) ever sets a flag: the
+            # others' stay all False, and a [P, 2] scatter is not free
             leg_done=dst.leg_done.at[slot].set(
-                jnp.zeros((slot.shape[0], 2), bool), mode="drop"),
+                jnp.zeros((slot.shape[0], 2), bool), mode="drop")
+            if uses_legs else dst.leg_done,
             origin=dst.origin.at[slot].set(origin.astype(jnp.int32),
                                            mode="drop"),
         ), n_drop
@@ -1530,11 +1841,32 @@ class PatternQueryRuntime:
         scope = Scope()
         tss = jnp.concatenate([b[3] for b in out_blocks])
         valids = jnp.concatenate([b[4] for b in out_blocks])
+        # upstream's order (see _advance). Blocks that carry none (arrivals
+        # completing a one-position pattern, timer completions) come first,
+        # in lane order; a step of such blocks alone is in order as it is
+        perm = None
+        if any(b[5] is not None for b in out_blocks):
+            comps, prevs = [], []
+            for b in out_blocks:
+                W = b[4].shape[0]
+                comp, prev = b[5] if b[5] is not None else (
+                    jnp.full((W,), -1, jnp.int32), jnp.zeros((W,), jnp.int64))
+                comps.append(comp)
+                prevs.append(prev)
+            perm = _upstream_order(valids, (jnp.concatenate(comps),
+                                            jnp.concatenate(prevs)))
+        # a select list that reads each lane alone (no aggregate, order by
+        # or limit) commutes with the permutation: permute its few output
+        # columns instead of every captured frame
+        after = perm is not None and not (
+            selector.has_aggregators or selector.group_vars
+            or selector.emit_final_per_group or selector.order_by
+            or selector.limit is not None or selector.offset is not None)
         for ref in all_refs:
             cols_parts = []
             valid_parts = []
             ts_parts = []
-            for frames, fvalid, fts, ts, v in out_blocks:
+            for frames, fvalid, fts, ts, v, _ in out_blocks:
                 W = ts.shape[0]
                 if ref in frames:
                     cols_parts.append(frames[ref])
@@ -1552,13 +1884,22 @@ class PatternQueryRuntime:
             # zero missing frames so projections emit nulls
             cols = {n: jnp.where(fv, v, jnp.zeros((), v.dtype))
                     for n, v in cols.items()}
-            scope.add_frame(ref, cols, jnp.concatenate(ts_parts), fv,
+            fts_all = jnp.concatenate(ts_parts)
+            if perm is not None and not after:
+                cols, fts_all, fv = jax.tree_util.tree_map(
+                    lambda a: a[perm], (cols, fts_all, fv))
+            scope.add_frame(ref, cols, fts_all, fv,
                             default=(ref == all_refs[0]))
         self._alias_bare_streams(scope)
         scope.extras["now"] = now
+        if perm is not None and not after:
+            tss, valids = tss[perm], valids[perm]
         chunk = EventBatch(ts=tss, cols={}, valid=valids,
                            types=jnp.zeros((tss.shape[0],), jnp.int8))
-        return selector.step(sel_state, chunk, scope)
+        new_sel, out = selector.step(sel_state, chunk, scope)
+        if after:
+            out = gather_lanes(out, perm)
+        return new_sel, out
 
     def _alias_bare_streams(self, scope: Scope) -> None:
         """Let unambiguous bare stream names resolve to their position frame."""
@@ -1585,8 +1926,72 @@ class PatternQueryRuntime:
             # pattern steps bake lane math on the planned capacity; widen
             # bucketed deliveries back (new lanes invalid)
             batch = batch.pad_to(cap)
-        self.state, out = self._steps[sid](self.state, batch, jnp.int64(now))
+        # nests in the feeder's `siddhi.feeder.dispatch` (or whichever
+        # delivery holds the controller lock)
+        with self.cells.span("step_" + sid, "siddhi.pattern.step",
+                             stream=sid):
+            self.state, out = self._steps[sid](self.state, batch,
+                                               jnp.int64(now))
+        self._count(out)
         self._distribute(out, now)
+
+    def _count(self, out: EventBatch) -> None:
+        """Book one step. The counters stay on the device, in the state, but
+        the drop counter at every 64th step, as the join's (an `int()` every
+        batch would serialise the async dispatch pipeline)."""
+        self._out_lanes += out.capacity
+        self._steps_run += 1
+        if not self._drop_warned and self._steps_run % 64 == 0:
+            # a device sync under the controller lock: it waits for every
+            # step dispatched so far
+            with self.cells.span("drop_sync", "siddhi.pattern.drop_sync"):
+                self.synced["dropped"] = int(self.state.dropped)
+            if self.synced["dropped"] > 0:
+                import warnings
+                warnings.warn(
+                    f"pattern {self.name!r}: {self.synced['dropped']} "
+                    "partial matches found the pending table full (or a "
+                    "sticky position's passes spent) and were dropped — "
+                    "raise @capacity(pending=...) on the query",
+                    stacklevel=2)
+                self._drop_warned = True
+
+    def device_counters(self, report: bool = False) -> dict:
+        """Copies of the state's counters for collect_overflow()'s one fetch
+        (under the controller lock: the next step donates the state);
+        `sync_counters` takes the values back. `report`: the caller is
+        statistics_report(), at which the high water starts anew."""
+        st = self.state
+        live = sum((jnp.sum(t.valid, dtype=jnp.int32) for t in st.pending),
+                   jnp.int32(0))
+        held = {"live": live, "live_hwm": st.live_hwm,
+                "expired": st.expired, "dropped": st.dropped}
+        if report:
+            self.state = st._replace(live_hwm=live)
+        return {k: jnp.copy(v) for k, v in held.items() if v is not None}
+
+    def sync_counters(self, fetched: dict) -> None:
+        self.synced.update({k: int(v) for k, v in fetched.items()})
+
+    def stats_snapshot(self) -> dict:
+        """statistics_report()["patterns"][name]. `steps`, `out_lanes` (the
+        out block's lanes, valid or not: what the read-back fetches) and
+        `expired` are cumulative; `live` and `live_hwm` (since the
+        statistics_report() before) count partial matches over all positions;
+        `pending_dropped` is the device counter as last synced."""
+        stage_ms = self.cells.snapshot()
+        return {
+            "steps": {sid: stage_ms["step_" + sid]["batches"]
+                      for sid in self._steps},
+            "pending_capacity": self.P,
+            "positions_by_key": sorted(self._key_plans),
+            "out_lanes": self._out_lanes,
+            "live": self.synced["live"],
+            "live_hwm": self.synced["live_hwm"],
+            "expired": self.synced["expired"],
+            "pending_dropped": self.synced["dropped"],
+            "stage_ms": stage_ms,
+        }
 
     def warmup(self, buckets=None) -> int:
         """AOT-compile every per-junction step (+ the heartbeat step when
@@ -1610,7 +2015,9 @@ class PatternQueryRuntime:
             return
         any_j = next(iter(self.junctions.values()))
         empty = EventBatch.empty(any_j.definition, any_j.batch_size)
-        self.state, out = self._heartbeat_step(self.state, empty, jnp.int64(now))
+        self.state, out = self._heartbeat_step(self.state, empty,
+                                               jnp.int64(now))
+        self._count(out)
         self._distribute(out, now)
 
     def _selector_state(self):

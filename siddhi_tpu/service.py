@@ -627,7 +627,17 @@ class SiddhiService:
                 except SiddhiError as e:
                     self._reply(400, {"error": str(e)})
 
-        return ThreadingHTTPServer((host, port), Handler)
+        return _Server((host, port), Handler)
+
+
+class _Server(ThreadingHTTPServer):
+    #: the listen backlog. socketserver's 5 overflows as soon as the accept
+    #: loop waits a few tens of ms for the interpreter (workers interning a
+    #: frame hold it that long) while more than six clients reconnect — the
+    #: server speaks HTTP/1.0, one connection a frame — and the kernel then
+    #: drops the SYN: that client stands for 1 s at least (its SYN retry).
+    #: 128 holds a connect from every client a deployment has
+    request_queue_size = 128
 
 
 def main(argv=None) -> None:

@@ -219,3 +219,23 @@ class TestDocGen:
                        "## Aggregators", "`distinctcount`",
                        "## Sources", "`inmemory`", "## Sink distribution strategies"):
             assert needle in md
+
+
+def test_the_listen_backlog_holds_a_connect_from_every_client():
+    """Thirty-two clients connect while the accept loop is held up (here:
+    not yet running). socketserver's backlog of 5 let the kernel drop the
+    seventh SYN, and that client stood for a second at least: one producer
+    of a benchmark cell out for a while, its events' stamps behind for
+    good."""
+    import socket
+    httpd = SiddhiService().make_server(port=0)
+    held = []
+    try:
+        for _ in range(32):
+            held.append(socket.create_connection(httpd.server_address,
+                                                 timeout=1.0))
+    finally:
+        for s in held:
+            s.close()
+        httpd.server_close()
+    assert len(held) == 32
