@@ -4,17 +4,20 @@ Reference counterpart: the 30 WindowProcessor classes under
 core/query/processor/stream/window/ that walk per-event linked lists and keep
 `SnapshotableStreamEventQueue` heaps. TPU re-design:
 
-- window contents live in **fixed-capacity device ring buffers** (one array per
-  column + timestamps), addressed by monotonically growing 64-bit "overall
-  arrival indices" (slot = idx % capacity);
+- window contents live in **fixed-capacity device ring buffers** (one packed
+  u32 word matrix for the sliding, expression and lengthBatch windows; one
+  array per column + timestamps for the rest), addressed by monotonically
+  growing 64-bit "overall arrival indices" (slot = idx % capacity);
 - a step consumes a columnar micro-batch and emits a **chunk**: a wider
   EventBatch whose lanes are typed CURRENT / EXPIRED / RESET and ordered
   exactly as the reference's per-event chunk would interleave them
   (e.g. LengthWindowProcessor.java:118-122 emits [expired, current] per
   arrival; LengthBatchWindowProcessor.java:210-243 emits
   [expired(prev flush), RESET, current(flush)] at each flush boundary);
-- ordering is produced by a single stable sort on an emission key, so the
-  whole window step is one fused XLA program with static shapes.
+- ordering is produced by a rank merge (or a stable sort) on an emission
+  key — or, where the interleave is fixed (lengthBatch), by arithmetic on the
+  lane number — so the whole window step is one fused XLA program with
+  static shapes.
 
 The downstream selector consumes chunks with signed-delta grouped scans
 (ops/groupby.py), reproducing per-event aggregate semantics.
@@ -161,7 +164,9 @@ def _sort_chunk(keys, cols, ts, valid, types, width):
 def _merge_order(keys, valids):
     """Global emission permutation for G lane groups whose VALID lanes
     already carry nondecreasing (hi, lo) keys — true for every window-chunk
-    assembly (currents/RESETs/expireds are generated in emission order).
+    assembly that merges by key: sliding, expression, timeBatch and the
+    windows of windows_extra.py (currents/RESETs/expireds are generated in
+    emission order). lengthBatch needs no keys: its interleave is fixed.
 
     INVARIANT (monotone-timestamp ingress): each group's valid-lane keys
     must be nondecreasing in lane order. Window emission keys derive from
@@ -281,6 +286,12 @@ def make_layout(attr_types: dict) -> TypedLayout:
 # ring append, candidate fetch, and the emission-sort gather ONE memory op
 # each, independent of column count. 8-byte payloads (int64/f64 + ts) span
 # two words; bitcasts/stacks fuse into neighbouring elementwise work.
+#
+# Users: SlidingWindow and LengthBatchWindow here, ExpressionWindow
+# (expression_window.py), GeneralExpressionWindow and
+# GeneralExpressionBatchWindow (expression_general.py). TimeBatchWindow,
+# SessionWindow, SortWindow and windows_extra.py still keep one ring array per
+# column (compact / _gather_rel / _scatter_append / _merge_sorted_chunks).
 # --------------------------------------------------------------------------- #
 
 
@@ -715,10 +726,23 @@ class BatchState(NamedTuple):
     wm: jax.Array  # int64 external-time watermark (externalTimeBatch only)
 
 
+class LengthBatchState(NamedTuple):
+    ring: jax.Array  # u32[W, C] packed rows (all columns + ts words)
+    appended: jax.Array  # int64 total valid arrivals ever
+    flushed: jax.Array  # int64 arrivals already emitted (a multiple of N)
+
+
 class LengthBatchWindow(WindowOp):
     """lengthBatch(N): tumbling count window. At each flush boundary emits
     [expired lanes of the previous flush, RESET, N current lanes]
-    (reference: LengthBatchWindowProcessor.java:210-243)."""
+    (reference: LengthBatchWindowProcessor.java:210-243).
+
+    Runs on the packed ring (see the "packed-row payload" banner): the
+    append and the fetch are contiguous slices, and because a count window's
+    emission is a FIXED interleave — per completing flush `[N expired if a
+    previous flush exists], RESET, N currents` — the source lane of every
+    output lane is arithmetic on its lane number. One packed gather applies
+    it; no emission keys, no merge, no per-column memory op."""
 
     def __init__(self, layout: dict, batch_cap: int, length: int,
                  expired_on: bool = True):
@@ -735,113 +759,86 @@ class LengthBatchWindow(WindowOp):
             width += batch_cap + length  # expired lanes
         width += max_flushes  # RESET lanes
         self.chunk_width = width
-        self._max_flushes = max_flushes
+        self.W = _layout_words(layout)
 
-    def init_state(self) -> BatchState:
-        return BatchState(
-            ring_cols=_empty_like_cols(self.layout, self.C),
-            ring_ts=jnp.zeros((self.C,), dtypes.TS_DTYPE),
+    def init_state(self) -> LengthBatchState:
+        return LengthBatchState(
+            ring=jnp.zeros((self.W, self.C), jnp.uint32),
             appended=jnp.int64(0),
             flushed=jnp.int64(0),
-            prev_start=jnp.int64(-1),
-            epoch_base=jnp.int64(0),
-            has_base=jnp.bool_(False),
-            wm=jnp.int64(-(2**62)),
         )
 
-    def step(self, state: BatchState, batch: EventBatch, now: jax.Array):
-        B, N, C = self.B, self.N, self.C
-        Nl = jnp.int64(N)
-        comp_cols, comp_ts, n_valid, _ = compact(batch)
-        appended1 = state.appended + n_valid
+    def step(self, state: LengthBatchState, batch: EventBatch,
+             now: jax.Array):
+        B, N, L = self.B, self.N, self.chunk_width
+        comp_mat, n_valid32 = compact_packed(batch, self.layout)
+        appended1 = state.appended + n_valid32.astype(jnp.int64)
 
-        f_done = state.flushed // Nl  # flushes completed before this batch
-        f_now = appended1 // Nl  # flushes completed after this batch
-        # All per-lane index math below is int32 RELATIVE to state.flushed
-        # (int64 scalars only feed scalar subtractions) — vectorized s64
-        # div/mod is software-emulated on TPU and was the step's hot spot.
-        # Invariant: state.flushed == f_done*N exactly, so for offset p:
-        #   (flushed+p) // N = f_done + p//N,  (flushed+p) % N = p % N.
-        nf = (f_now - f_done).astype(jnp.int32)  # flushes completing now
+        # Invariant: state.flushed is a multiple of N, so everything per lane
+        # is int32 RELATIVE to it (vectorized s64 div/mod is emulated on TPU)
         r0 = (state.appended - state.flushed).astype(jnp.int32)  # partial len
+        nf = (r0 + n_valid32) // N  # flushes completing in this batch
 
-        # completion position (within this batch) of flush f: arrival index of
-        # the flush's last event = (f+1)*N - 1 - appended0
-        # Candidate currents: overall indices [flushed, f_now*N)
-        cur_count_max = B + N
-        p_cur = jnp.arange(cur_count_max, dtype=jnp.int32)
-        cur_exists = p_cur < nf * N
-        cur_cols, cur_ts = _gather_rel(
-            state.ring_cols, state.ring_ts, comp_cols, comp_ts,
-            state.appended, state.flushed, p_cur)
-        cur_flush_rel = p_cur // N
-        cur_comp = (cur_flush_rel + 1) * N - 1 - r0  # batch pos of flush end
-        cur_keys = _emit_key(cur_comp, KIND_CURRENT, p_cur % N, B)
+        # candidate rows, one contiguous fetch: overall indices
+        # [flushed - lead, flushed + B + N) — the currents, led (where
+        # expired lanes are emitted) by the previous flush. B + N + lead <= C.
+        lead = N if self.expired_on else 0
+        E = B + N + lead
+        rows = _fetch_rel_packed(state.ring, comp_mat, state.flushed - lead,
+                                 state.appended, E)
 
-        # RESET lanes: one per completing flush
-        MF = self._max_flushes
-        f_rel = jnp.arange(MF, dtype=jnp.int32)
-        reset_exists = f_rel < nf
-        reset_comp = (f_rel + 1) * N - 1 - r0
-        reset_keys = _emit_key(reset_comp, KIND_RESET,
-                               jnp.zeros((MF,), jnp.int32), B)
-        reset_cols = _empty_like_cols(self.layout, MF)
-        safe_rc = jnp.clip(reset_comp, 0, B - 1)
-        reset_ts = comp_ts[safe_rc]
+        # output lane j -> (flush blk, position r within its block). A block
+        # is [lead expired lanes, RESET, N currents]; the first flush ever
+        # has no previous flush to expire, so its block starts at the RESET.
+        blk_w = lead + 1 + N
+        skip = jnp.where(state.flushed == 0, jnp.int32(lead), jnp.int32(0))
+        j = jnp.arange(L, dtype=jnp.int32) + skip
+        blk, r = j // blk_w, j % blk_w
+        is_reset = r == lead
+        is_cur = r > lead
+        # row of `rows`: expired lane r of flush blk is event r of flush
+        # blk - 1; current lane is event r - lead - 1 of flush blk; a RESET
+        # reads the zero row appended at E
+        src = jnp.where(is_reset, E,
+                        jnp.clip(blk * N + r - is_cur.astype(jnp.int32),
+                                 0, E - 1))
+        out = jnp.concatenate(
+            [rows, jnp.zeros((self.W, 1), jnp.uint32)], axis=1)[:, src]
+        cols, own_ts = _unpack_rows(out, self.layout)
 
-        keys = [cur_keys, reset_keys]
-        colss = [cur_cols, reset_cols]
-        tss = [cur_ts, reset_ts]
-        valids = [cur_exists, reset_exists]
-        types = [jnp.full((cur_count_max,), EventType.CURRENT, jnp.int8),
-                 jnp.full((MF,), EventType.RESET, jnp.int8)]
+        # RESET and expired lanes are stamped with the arrival completing
+        # their flush (the reference re-stamps with current time): the last
+        # current of each block — a strided slice of B//N + 1 stamps (no
+        # more flushes can complete), each repeated over its block
+        flush_ts = jnp.repeat(_packed_ts(rows[:, lead + N - 1::N]), blk_w)
+        flush_ts = jax.lax.dynamic_slice(
+            jnp.pad(flush_ts, (0, max(L + lead - flush_ts.shape[0], 0))),
+            (skip,), (L,))
 
-        if self.expired_on:
-            # expired lanes: events of flush f-1 re-emitted when flush f
-            # completes (only if a previous flush exists); base (f_done-1)*N
-            p_exp = jnp.arange(cur_count_max, dtype=jnp.int32)
-            exp_flush_rel = p_exp // N - 1  # relative to f_done
-            # event of flush f is re-emitted as expired when flush f+1
-            # completes. o_exp >= 0 ⟺ f_done >= 1 or p >= N (two flushes
-            # completing inside the very first batch).
-            exp_exists = ((f_done >= 1) | (p_exp >= N)) & (
-                (exp_flush_rel + 1) < nf)
-            exp_cols, exp_ts_orig = _gather_rel(
-                state.ring_cols, state.ring_ts, comp_cols, comp_ts,
-                state.appended, (f_done - 1) * Nl, p_exp)
-            exp_comp = (exp_flush_rel + 2) * N - 1 - r0
-            exp_keys = _emit_key(exp_comp, KIND_EXPIRED, p_exp % N, B)
-            safe_ec = jnp.clip(exp_comp, 0, B - 1)
-            exp_ts = comp_ts[safe_ec]  # reference re-stamps with current time
-            keys.append(exp_keys)
-            colss.append(exp_cols)
-            tss.append(exp_ts)
-            valids.append(exp_exists)
-            types.append(jnp.full((cur_count_max,), EventType.EXPIRED, jnp.int8))
+        chunk = EventBatch(
+            ts=jnp.where(is_cur, own_ts, flush_ts),
+            cols=cols,
+            valid=blk < nf,
+            types=jnp.where(
+                is_cur, jnp.int8(EventType.CURRENT),
+                jnp.where(is_reset, jnp.int8(EventType.RESET),
+                          jnp.int8(EventType.EXPIRED))),
+        )
 
-        chunk = _merge_sorted_chunks(keys, colss, tss, valids, types,
-                                     self.chunk_width)
-
-        new_ring_cols, new_ring_ts = _scatter_append(
-            state.ring_cols, state.ring_ts, comp_cols, comp_ts,
-            state.appended, n_valid)
-        new_state = BatchState(
-            ring_cols=new_ring_cols,
-            ring_ts=new_ring_ts,
+        new_state = LengthBatchState(
+            ring=_append_packed(state.ring, comp_mat, state.appended,
+                                n_valid32),
             appended=appended1,
-            flushed=f_now * Nl,
-            prev_start=(f_now - 1) * Nl,
-            epoch_base=state.epoch_base,
-            has_base=state.has_base,
-            wm=state.wm,
+            flushed=state.flushed + (nf * N).astype(jnp.int64),
         )
         return new_state, chunk
 
-    def contents(self, state: BatchState, now: jax.Array):
+    def contents(self, state: LengthBatchState, now: jax.Array):
         """Joins see the accumulating (unflushed) bucket (reference:
         BatchingFindableWindowProcessor over the current batch buffer)."""
+        ring_cols, ring_ts = _unpack_rows(state.ring, self.layout)
         live = _ring_live_mask(self.C, state.flushed, state.appended)
-        return state.ring_cols, state.ring_ts, live
+        return ring_cols, ring_ts, live
 
 
 def _emit_key(comp_pos, kind, within, B):

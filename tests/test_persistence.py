@@ -193,6 +193,93 @@ class TestPersistRestore:
         with pytest.raises(CannotRestoreStateError):
             rt.restore(blob)
 
+    LB_APP = ("@app:name('LbApp')\n"
+              "define stream S (k string, p double, v long);\n"
+              "@info(name='q') from S#window.lengthBatch(5) "
+              "select k, sum(p) as total, sum(v) as vol, count() as n "
+              "group by k insert all events into Out;")
+
+    def _lb_runtime(self, got):
+        rt = SiddhiManager().create_siddhi_app_runtime(self.LB_APP,
+                                                       batch_size=4)
+        rt.add_callback("Out", lambda evs: got.extend(
+            (e.is_expired, e.data) for e in evs))
+        rt.start()
+        return rt
+
+    ROWS = [(k, 0.5 * i, 2**40 + i) for i, k in enumerate("abacbbcaabcabcc")]
+
+    def test_length_batch_packed_ring_round_trips_mid_window(self):
+        # the ring is one packed u32 matrix: a snapshot taken with a partial
+        # bucket and a previous flush in it must restore word for word, and
+        # the restored runtime must go on exactly as the uninterrupted one
+        import pickle
+
+        import numpy as np
+
+        from siddhi_tpu.ops.windows import LengthBatchState
+
+        whole, got1, got2 = [], [], []
+        rt0 = self._lb_runtime(whole)
+        for row in self.ROWS:
+            rt0.get_input_handler("S").send(row)
+        rt0.flush()
+
+        rt1 = self._lb_runtime(got1)
+        for row in self.ROWS[:8]:  # one flush done, three rows pending
+            rt1.get_input_handler("S").send(row)
+        rt1.flush()
+        blob = rt1.snapshot()
+        wstate = pickle.loads(blob)["queries"]["q"][0]
+        assert isinstance(wstate, LengthBatchState)
+        assert wstate.ring.dtype == np.uint32 and wstate.ring.ndim == 2
+        assert (int(wstate.appended), int(wstate.flushed)) == (8, 5)
+
+        rt2 = self._lb_runtime(got2)
+        rt2.restore(blob)
+        for a, b in zip(rt1.query_runtimes["q"].state[0],
+                        rt2.query_runtimes["q"].state[0]):
+            assert np.array_equal(np.asarray(a), np.asarray(b))
+        for row in self.ROWS[8:]:
+            rt2.get_input_handler("S").send(row)
+        rt2.flush()
+        # the restored previous flush expired: its groups ran down to zero
+        assert any(data[3] == 0 for _, data in got2)
+        assert got1 + got2 == whole
+
+    def test_snapshot_with_per_column_ring_is_refused(self):
+        # a revision written before lengthBatch moved to the packed ring
+        # holds BatchState(ring_cols=..., ring_ts=...): refuse it, never
+        # assign its leaves to the new state
+        import pickle
+
+        import numpy as np
+
+        from siddhi_tpu.errors import CannotRestoreStateError
+        from siddhi_tpu.ops.windows import BatchState
+
+        got = []
+        rt = self._lb_runtime(got)
+        for row in self.ROWS[:8]:
+            rt.get_input_handler("S").send(row)
+        rt.flush()
+        snap = pickle.loads(rt.snapshot())
+        wstate, sstate, rstate = snap["queries"]["q"]
+        C = wstate.ring.shape[1]
+        old = BatchState(
+            ring_cols={"k": np.zeros(C, np.int32), "p": np.zeros(C, np.float32),
+                       "v": np.zeros(C, np.int64)},
+            ring_ts=np.zeros(C, np.int64),
+            appended=np.int64(8), flushed=np.int64(5),
+            prev_start=np.int64(0), epoch_base=np.int64(0),
+            has_base=np.bool_(False), wm=np.int64(-(2**62)))
+        snap["queries"]["q"] = (old, sstate, rstate)
+        before = [np.asarray(x).copy() for x in rt.query_runtimes["q"].state[0]]
+        with pytest.raises(CannotRestoreStateError):
+            rt.restore(pickle.dumps(snap))
+        for a, b in zip(before, rt.query_runtimes["q"].state[0]):
+            assert np.array_equal(a, np.asarray(b))
+
 
 class TestIncrementalFileSystemStore:
     """Reference: IncrementalFileSystemPersistenceStore.java:37 — delta
