@@ -274,21 +274,12 @@ def _ladder_rungs(batch_cap: int) -> int:
 
 
 def _make_window(handlers_window, layout, batch_cap: int, expired_on: bool,
-                 registry):
-    """Mirror of the runtime window construction (allocation-free)."""
-    from ..core.query_runtime import eval_constant
-    from ..extension.registry import ExtensionKind
-    from ..ops.window_factories import WindowFactory
-    from ..ops.windows import PassThroughWindow
-    if handlers_window is None:
-        return PassThroughWindow(layout, batch_cap)
-    factory = registry.require(ExtensionKind.WINDOW, handlers_window.namespace,
-                               handlers_window.name)
-    assert isinstance(factory, WindowFactory)
-    params = [eval_constant(p) for p in handlers_window.parameters]
-    registry.validate_params(ExtensionKind.WINDOW, handlers_window.namespace,
-                             handlers_window.name, params, what="window")
-    return factory.make(layout, batch_cap, params, expired_on)
+                 registry, annotations=()):
+    """The runtime's own window construction (allocation-free), so that a
+    capacity the app states (`@capacity(window=, expire=)`) is priced."""
+    from ..ops.window_factories import make_window
+    return make_window(handlers_window, layout, batch_cap, expired_on,
+                       registry, annotations=annotations)
 
 
 # --------------------------------------------------------------------------
@@ -330,7 +321,7 @@ def _single_query_cost(node: QueryNode, plan: PlanGraph, registry,
     if snapshot_full:
         expired_on = True
     window = _make_window(c.single.handlers.window, layout, batch_cap,
-                          expired_on, registry)
+                          expired_on, registry, query.annotations)
     is_sliding = c.single.handlers.window is not None and \
         type(window).__name__ in ("SlidingWindow", "ExpressionWindow",
                                   "GeneralExpressionWindow")
@@ -398,7 +389,7 @@ def _join_query_cost(node: QueryNode, plan: PlanGraph, registry,
             # under their OWN elements (shared state, counted once)
             layout = make_layout(attrs)
             window = _make_window(ins.handlers.window, layout, batch_cap,
-                                  True, registry)
+                                  True, registry, node.query.annotations)
         sides.append((ins, ref, kind, attrs, window))
 
     (lins, lref, lkind, lattrs, lwin), (rins, rref, rkind, rattrs, rwin) = sides
@@ -565,7 +556,7 @@ def _named_window_cost(name: str, defn, registry,
         return ec
     layout = make_layout(attrs)
     window = _make_window(getattr(defn, "window", None), layout, batch_cap,
-                          True, registry)
+                          True, registry, defn.annotations)
     ec.state_bytes = _eval_state_bytes(window.init_state)
     if getattr(defn, "window", None) is None:
         ec.notes.append("no window spec: pass-through emission, no "

@@ -1459,6 +1459,7 @@ class SiddhiAppRuntime:
                 add("key_table_unresolved", obj.misses)
             elif isinstance(obj, SlidingState):
                 add("window_ring_overflow", obj.overflow)
+                add("window_expiry_deferred", obj.deferred)
             elif isinstance(obj, KeyedSessionState):
                 add("session_key_dropped", obj.dropped)
             elif isinstance(obj, PatternState):
@@ -1510,9 +1511,13 @@ class SiddhiAppRuntime:
             for key, qr in joins.items():
                 pending[key] = [qr._dropped_dev]
             pending = jax.tree_util.tree_map(jnp.copy, pending)
+            # pattern tables and sliding windows: the account each keeps
+            # in its state (high waters start anew at a report)
             patterns = {n: qr.device_counters(report)
                         for n, qr in self.query_runtimes.items()
-                        if isinstance(qr, PatternQueryRuntime)}
+                        if isinstance(qr, PatternQueryRuntime)
+                        or (isinstance(qr, QueryRuntime)
+                            and qr.cells is not None)}
         # ONE device->host round trip
         fetched, counted = jax.device_get((pending, patterns))
         for name, arrs in fetched.items():

@@ -396,6 +396,15 @@ class Statistics:
                         if isinstance(qr, PatternQueryRuntime)}
             if patterns:
                 out["patterns"] = patterns
+            # queries over a sliding window (core/query_runtime.py): the
+            # ring's capacity and fill, rows in and out, the loss counters
+            from .query_runtime import QueryRuntime
+            windows = {name: qr.stats_snapshot()
+                       for name, qr in runtime.query_runtimes.items()
+                       if isinstance(qr, QueryRuntime)
+                       and qr.cells is not None}
+            if windows:
+                out["windows"] = windows
         if runtime is not None:
             wal = getattr(runtime, "wal", None)
             if wal is not None:

@@ -22,6 +22,9 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
+from typing import NamedTuple, Optional
+
+from ..errors import SiddhiAppCreationError
 from ..query_api.definition import AttributeType
 
 #: sentinel string code for null
@@ -171,3 +174,38 @@ def promote(a: AttributeType, b: AttributeType) -> AttributeType:
     if not (is_numeric(a) and is_numeric(b)):
         raise TypeError(f"cannot apply arithmetic to {a}/{b}")
     return a if _RANK[a] >= _RANK[b] else b
+
+
+class StatedCapacity(NamedTuple):
+    """What `@capacity(...)` on a query (or a named window) states: a
+    pattern's partial matches per position (`pending`), a sliding window's
+    ring rows (`window`) and the rows that may leave it in one step
+    (`expire`). None: the app says nothing, the defaults hold."""
+
+    pending: Optional[int] = None
+    window: Optional[int] = None
+    expire: Optional[int] = None
+
+
+def stated_capacity(annotations) -> StatedCapacity:
+    """The one parser of `@capacity(pending=, window=, expire=)`; whoever
+    builds the structure validates the number against what it sizes."""
+    ann = next((a for a in (annotations or ())
+                if a.name.lower() == "capacity"), None)
+    if ann is None:
+        return StatedCapacity()
+    stated = {}
+    for key in StatedCapacity._fields:
+        text = ann.element(key)
+        if text is None:
+            continue
+        try:
+            n = int(text)
+        except ValueError:
+            n = 0
+        if n < 1 or n > 2**30:
+            raise SiddhiAppCreationError(
+                f"@capacity({key}={text!r}): a capacity is a whole number "
+                "of rows, from 1 to 2^30")
+        stated[key] = n
+    return StatedCapacity(**stated)

@@ -23,15 +23,14 @@ import jax
 import jax.numpy as jnp
 
 from ..errors import DefinitionNotExistError, SiddhiAppCreationError
-from ..extension.registry import ExtensionKind, Registry
+from ..extension.registry import Registry
 from ..ops.expr_compile import Scope, TypeResolver, compile_expression
 from ..ops.join import (JoinPlan, _hash_exprs, collect_vars, compact_pairs,
                         multimap_append, multimap_buckets, multimap_init,
                         plan_join, probe_cross, probe_equi, probe_equi_mm)
 from ..ops.selector import CompiledSelector
-from ..ops.window_factories import WindowFactory
-from ..ops.windows import (PassThroughWindow, SlidingWindow, WindowOp,
-                           _unpack_rows)
+from ..ops.window_factories import make_window
+from ..ops.windows import SlidingWindow, WindowOp, _unpack_rows
 from ..query_api.definition import Attribute, AttributeType, StreamDefinition
 from ..query_api.execution import (
     EventTrigger,
@@ -45,7 +44,7 @@ from ..telemetry.tracing import StageCells
 from . import dtypes
 from .context import SiddhiAppContext
 from .event import EventBatch, EventType, StreamCodec
-from .query_runtime import QueryCallback, eval_constant
+from .query_runtime import QueryCallback
 from .stream import Receiver, StreamJunction
 
 
@@ -103,7 +102,7 @@ class _Side:
     window (probed via its shared contents; its emissions also trigger)."""
 
     def __init__(self, ins: SingleInputStream, ctx, registry, junctions, tables,
-                 windows=None, aggregations=None, per=None):
+                 windows=None, aggregations=None, per=None, annotations=()):
         self.ref = ins.reference_id  # alias or stream id
         self.stream_id = ins.stream_id
         self.is_table = ins.stream_id in tables
@@ -165,16 +164,9 @@ class _Side:
             from ..ops.windows import make_layout
             layout = make_layout(self.attr_types)
             batch_cap = self.junction.batch_size
-            wh = ins.handlers.window
-            if wh is not None:
-                factory = registry.require(ExtensionKind.WINDOW, wh.namespace, wh.name)
-                assert isinstance(factory, WindowFactory)
-                params = [eval_constant(p) for p in wh.parameters]
-                registry.validate_params(ExtensionKind.WINDOW, wh.namespace,
-                                         wh.name, params, what="window")
-                self.window = factory.make(layout, batch_cap, params, True)
-            else:
-                self.window = PassThroughWindow(layout, batch_cap)
+            self.window = make_window(
+                ins.handlers.window, layout, batch_cap, True, registry,
+                annotations=annotations, playback=bool(ctx.playback))
         self.handlers = ins.handlers
 
 
@@ -213,9 +205,9 @@ class JoinQueryRuntime:
         self.k_max = dtypes.config.join_max_matches
 
         self.left = _Side(jis.left, ctx, registry, junctions, tables, windows,
-                          aggregations, jis.per)
+                          aggregations, jis.per, query.annotations)
         self.right = _Side(jis.right, ctx, registry, junctions, tables, windows,
-                           aggregations, jis.per)
+                           aggregations, jis.per, query.annotations)
         if self.left.is_table and self.right.is_table:
             raise SiddhiAppCreationError("cannot join two tables in a stream query")
         if self.left.is_aggregation and self.right.is_aggregation:

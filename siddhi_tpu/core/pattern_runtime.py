@@ -421,20 +421,9 @@ def pending_capacity_of(query: Query) -> int:
     """A pattern query's pending-table capacity (partial matches held per
     position): `@capacity(pending='N')` on the query, else the process-wide
     `config.pattern_pending_capacity`. analysis/cost.py prices the same."""
-    ann = next((a for a in (query.annotations or ())
-                if a.name.lower() == "capacity"), None)
-    text = ann.element("pending") if ann is not None else None
-    if text is None:
-        return dtypes.config.pattern_pending_capacity
-    try:
-        cap = int(text)
-    except ValueError:
-        cap = 0
-    if cap < 1 or cap > 2**30:
-        raise SiddhiAppCreationError(
-            f"@capacity(pending={text!r}): a pattern's pending capacity is "
-            "a whole number of partial matches, from 1 to 2^30")
-    return cap
+    stated = dtypes.stated_capacity(query.annotations).pending
+    return dtypes.config.pattern_pending_capacity if stated is None \
+        else stated
 
 
 def _conjuncts(expr) -> list:

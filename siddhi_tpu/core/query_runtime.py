@@ -28,7 +28,8 @@ from ..errors import SiddhiAppCreationError
 from ..extension.registry import ExtensionKind, Registry
 from ..ops.expr_compile import Scope, TypeResolver, compile_expression
 from ..ops.selector import CompiledSelector
-from ..ops.window_factories import WindowFactory
+from ..ops.window_factories import make_window
+from ..telemetry.tracing import StageCells
 from ..ops.windows import PassThroughWindow, WindowOp
 from ..query_api.definition import AttributeType, StreamDefinition, Attribute
 from ..query_api.execution import (
@@ -37,7 +38,7 @@ from ..query_api.execution import (
     Query,
     SingleInputStream,
 )
-from ..query_api.expression import Constant, Expression, Variable
+from ..query_api.expression import Expression, Variable
 from . import dtypes
 from .context import SiddhiAppContext
 from .event import Event, EventBatch, EventType, StreamCodec
@@ -58,17 +59,6 @@ class FunctionQueryCallback(QueryCallback):
 
     def receive(self, timestamp: int, in_events, remove_events) -> None:
         self.fn(timestamp, in_events, remove_events)
-
-
-def eval_constant(expr: Expression):
-    """Evaluate a compile-time-constant window/extension parameter (sizes,
-    periods). Variables pass through as AST nodes — some windows take
-    attribute references (externalTime's tsAttr, sort keys)."""
-    if isinstance(expr, Constant):
-        return expr.value
-    if isinstance(expr, Variable):
-        return expr
-    raise SiddhiAppCreationError(f"expected a constant parameter, got {expr!r}")
 
 
 @dataclass
@@ -263,13 +253,10 @@ class QueryRuntime(Receiver):
         if self._snapshot_full_window:
             expired_on = True
         wh = in_stream.handlers.window
+        self.window: WindowOp = make_window(
+            wh, layout, batch_cap, expired_on, registry,
+            annotations=query.annotations, playback=bool(ctx.playback))
         if wh is not None:
-            factory = registry.require(ExtensionKind.WINDOW, wh.namespace, wh.name)
-            assert isinstance(factory, WindowFactory)
-            params = [eval_constant(p) for p in wh.parameters]
-            registry.validate_params(ExtensionKind.WINDOW, wh.namespace,
-                                     wh.name, params, what="window")
-            self.window: WindowOp = factory.make(layout, batch_cap, params, expired_on)
             et = getattr(ctx, "event_time", None)
             if (et is not None and et.lateness_ms
                     and getattr(self.window, "ts_attr", None) is not None):
@@ -278,8 +265,6 @@ class QueryRuntime(Receiver):
                 # allowed lateness so panes stay open for rows the ingress
                 # gate still buffers. Set BEFORE first trace (static attr).
                 self.window.lateness_ms = int(et.lateness_ms)
-        else:
-            self.window = PassThroughWindow(layout, batch_cap)
         # ExpressionWindow shares SlidingState + FIFO suffix semantics, so
         # the removal-capable extrema path (and the grouped-min rejection)
         # applies to it identically
@@ -386,6 +371,16 @@ class QueryRuntime(Receiver):
         self._has_custom_aggs = any(
             spec.custom_scan is not None for _, spec, _ in self.selector.agg_specs)
         self._batches_seen = 0
+        #: a sliding window's account (statistics_report()["windows"]): its
+        #: counters live in its state on the device and are synced at a
+        #: report, the two loss counters also at every 64th step
+        from ..ops.windows import SlidingWindow as _Sliding
+        self.cells = (StageCells(("drop_sync",))
+                      if isinstance(self.window, _Sliding) else None)
+        self._out_lanes = 0
+        self._loss_warned = False
+        self.synced = {"live": 0, "live_hwm": 0, "appended": 0, "expired": 0,
+                       "ring_overflow": 0, "expiry_deferred": 0}
         self._capacity_warned = False
         self._capacity_pressure = False
         self._snapshot_warned = False
@@ -616,6 +611,7 @@ class QueryRuntime(Receiver):
         self.state, out = self._step(self.state, batch, jnp.int64(now),
                                      self._table_states())
         traced = self.ctx.statistics.compiles.get(self.name, 0) != traced
+        self._out_lanes += out.capacity
         self._distribute(out, now)
         elapsed = time.perf_counter_ns() - t0
         self.ctx.statistics.track_latency(self.name, elapsed)
@@ -632,6 +628,9 @@ class QueryRuntime(Receiver):
         compaction cadence + snapshot-overflow warning. Shared between
         on_batch and SharedStepGroup dispatch (core/shared.py)."""
         self._batches_seen += 1
+        if (self.cells is not None and not self._loss_warned
+                and self._batches_seen % 64 == 0):
+            self._sync_window_loss()
         # adaptive cadence: cheap (one scalar sync) but sparse normally;
         # tight once a table runs hot so compaction outruns overflow.
         # Warnings are one-shot, but the checks (and their compactions)
@@ -651,6 +650,60 @@ class QueryRuntime(Receiver):
                     "missing from periodic snapshots — raise "
                     "config.snapshot_group_capacity", stacklevel=2)
                 self._snapshot_warned = True
+
+    def _sync_window_loss(self) -> None:
+        """The window's two loss counters, as the join's and the pattern's
+        drop counters: an `int()` under the controller lock waits for every
+        step dispatched so far, so every 64th step and not each."""
+        wstate = self.state[0]
+        with self.cells.span("drop_sync", "siddhi.window.drop_sync"):
+            lost = jax.device_get((wstate.overflow, wstate.deferred))
+        self.synced["ring_overflow"], self.synced["expiry_deferred"] = \
+            int(lost[0]), int(lost[1])
+        if sum(self.synced[k] for k in ("ring_overflow", "expiry_deferred")):
+            import warnings
+            warnings.warn(
+                f"query {self.name!r}: the window overwrote "
+                f"{self.synced['ring_overflow']} live rows and let "
+                f"{self.synced['expiry_deferred']} rows go late (more due "
+                "in one step than its expiry width) — raise "
+                "@capacity(window=..., expire=...) on the query",
+                stacklevel=2)
+            self._loss_warned = True
+
+    def device_counters(self, report: bool = False) -> dict:
+        """Copies of the window state's counters for collect_overflow()'s
+        one fetch (under the controller lock: the next step donates the
+        state); `sync_counters` takes the values back. `report`: the caller
+        is statistics_report(), at which the high water starts anew."""
+        ws = self.state[0]
+        live = ws.appended - ws.expired
+        held = {"live": live, "live_hwm": ws.live_hwm,
+                "appended": ws.appended, "expired": ws.expired,
+                "ring_overflow": ws.overflow, "expiry_deferred": ws.deferred}
+        held = {k: jnp.copy(v) for k, v in held.items()}
+        if report:
+            self.state = (ws._replace(live_hwm=live), *self.state[1:])
+        return held
+
+    def sync_counters(self, fetched: dict) -> None:
+        self.synced.update({k: int(v) for k, v in fetched.items()})
+
+    def stats_snapshot(self) -> dict:
+        """statistics_report()["windows"][name], for a query over a sliding
+        window. `steps`, `out_lanes` (the out block's lanes, valid or not:
+        what the read-back fetches), `appended` and `expired` are
+        cumulative; `live` is the rows in the window after the last step
+        synced, `live_hwm` the most since the statistics_report() before;
+        the two loss counters are the device's as last synced."""
+        return {
+            "capacity": self.window.C,
+            "expire_width": self.window.E,
+            "steps": self._batches_seen,
+            "out_lanes": self._out_lanes,
+            **self.synced,
+            "stage_ms": self.cells.snapshot(),
+        }
 
     def _check_custom_agg_capacity(self) -> None:
         """distinctCount's (group,value) pair table is append-only inside

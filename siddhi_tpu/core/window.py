@@ -23,9 +23,9 @@ import jax
 import jax.numpy as jnp
 
 from ..errors import SiddhiAppCreationError
-from ..extension.registry import ExtensionKind, Registry
-from ..ops.window_factories import WindowFactory
-from ..ops.windows import PassThroughWindow, WindowOp
+from ..extension.registry import Registry
+from ..ops.window_factories import make_window
+from ..ops.windows import WindowOp
 from ..query_api.definition import AttributeType, StreamDefinition, WindowDefinition
 from . import dtypes
 from .context import SiddhiAppContext
@@ -53,19 +53,12 @@ class NamedWindow:
         from ..ops.windows import make_layout
         layout = make_layout(self.attr_types)
         batch_cap = ctx.effective_batch_size
-        wh = definition.window
-        if wh is not None:
-            factory = registry.require(ExtensionKind.WINDOW, wh.namespace, wh.name)
-            assert isinstance(factory, WindowFactory)
-            from .query_runtime import eval_constant
-            params = [eval_constant(p) for p in wh.parameters]
-            registry.validate_params(ExtensionKind.WINDOW, wh.namespace,
-                                     wh.name, params, what="window")
-            self.window: WindowOp = factory.make(layout, batch_cap, params, True)
-        else:
-            # `define window W (...)` with no spec: pass-through emission, no
-            # retained contents (reference: empty window)
-            self.window = PassThroughWindow(layout, batch_cap)
+        # `define window W (...)` with no spec: pass-through emission, no
+        # retained contents (reference: empty window)
+        self.window: WindowOp = make_window(
+            definition.window, layout, batch_cap, True, registry,
+            annotations=definition.annotations,
+            playback=bool(ctx.playback))
 
         self.state = self.window.init_state()
         self._append = jax.jit(

@@ -52,6 +52,7 @@ from .windows import (
     KIND_CURRENT,
     KIND_EXPIRED,
     SlidingState,
+    sliding_state0,
     WindowOp,
     _layout_words,
     _pack_rows,
@@ -173,13 +174,7 @@ class ExpressionWindow(WindowOp):
         self.W = _layout_words(layout)
 
     def init_state(self) -> SlidingState:
-        return SlidingState(
-            ring=jnp.zeros((self.W, self.C), jnp.uint32),
-            appended=jnp.int64(0),
-            expired=jnp.int64(0),
-            wm=jnp.int64(-(2**62)),
-            overflow=jnp.int64(0),
-        )
+        return sliding_state0(self.W, self.C)
 
     def _metric_seq(self, conj: _Conjunct, ring_cols, ring_ts, comp_cols,
                     comp_ts, expired, winlen0, n_valid32, fill):
@@ -312,12 +307,12 @@ class ExpressionWindow(WindowOp):
         expired1 = state.expired + s_end.astype(jnp.int64)
         over0 = jnp.maximum(state.appended - state.expired - C, 0)
         over1 = jnp.maximum(appended1 - expired1 - C, 0)
-        new_state = SlidingState(
+        new_state = state._replace(
             ring=new_ring,
             appended=appended1,
             expired=expired1,
-            wm=state.wm,
             overflow=state.overflow + jnp.maximum(over1 - over0, 0),
+            live_hwm=jnp.maximum(state.live_hwm, appended1 - expired1),
         )
         return new_state, chunk
 

@@ -8,8 +8,10 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Callable
 
+from ..core.dtypes import stated_capacity
 from ..errors import SiddhiAppCreationError
 from ..extension.registry import GLOBAL, ExtensionKind
+from ..query_api.expression import Constant, Variable
 from .windows import (
     LengthBatchWindow,
     PassThroughWindow,
@@ -24,6 +26,41 @@ from .windows import (
 @dataclass
 class WindowFactory:
     make: Callable  # (layout, batch_cap, params: list, expired_on: bool) -> WindowOp
+
+
+def eval_constant(expr):
+    """Evaluate a compile-time-constant window/extension parameter (sizes,
+    periods). Variables pass through as AST nodes — some windows take
+    attribute references (externalTime's tsAttr, sort keys)."""
+    if isinstance(expr, Constant):
+        return expr.value
+    if isinstance(expr, Variable):
+        return expr
+    raise SiddhiAppCreationError(f"expected a constant parameter, got {expr!r}")
+
+
+def make_window(handler, layout, batch_cap: int, expired_on: bool, registry,
+                *, annotations=(), playback: bool = False) -> WindowOp:
+    """The window of a query, a join side, a named window — and of the cost
+    model, which must price what the runtime builds. `annotations` are those
+    of whoever owns it: `@capacity(window=, expire=)` there sizes its ring
+    (the window refuses what it cannot hold); `playback`: the app's clock
+    is its events'."""
+    if handler is None:
+        window = PassThroughWindow(layout, batch_cap)
+    else:
+        factory = registry.require(ExtensionKind.WINDOW, handler.namespace,
+                                   handler.name)
+        assert isinstance(factory, WindowFactory)
+        params = [eval_constant(p) for p in handler.parameters]
+        registry.validate_params(ExtensionKind.WINDOW, handler.namespace,
+                                 handler.name, params, what="window")
+        window = factory.make(layout, batch_cap, params, expired_on)
+    stated = stated_capacity(annotations)
+    if stated.window is not None or stated.expire is not None:
+        window.resize(stated.window, stated.expire)
+    window.playback = playback
+    return window
 
 
 def _int_param(params, i, name, what="window"):

@@ -164,9 +164,10 @@ def _sort_chunk(keys, cols, ts, valid, types, width):
 def _merge_order(keys, valids):
     """Global emission permutation for G lane groups whose VALID lanes
     already carry nondecreasing (hi, lo) keys — true for every window-chunk
-    assembly that merges by key: sliding, expression, timeBatch and the
-    windows of windows_extra.py (currents/RESETs/expireds are generated in
-    emission order). lengthBatch needs no keys: its interleave is fixed.
+    assembly that merges by key: expression, timeBatch and the windows of
+    windows_extra.py (currents/RESETs/expireds are generated in emission
+    order). lengthBatch needs no keys (its interleave is fixed), and
+    SlidingWindow none either: it counts its ranks against running maxima.
 
     INVARIANT (monotone-timestamp ingress): each group's valid-lane keys
     must be nondecreasing in lane order. Window emission keys derive from
@@ -363,36 +364,61 @@ def compact_packed(batch: EventBatch, layout: dict):
     return mat[:, order], jnp.sum(live, dtype=jnp.int32)
 
 
+def _ring_lanes(ring: jax.Array, base, n: int):
+    """Ring lanes (base + [0, n)) % C, n <= C, touching 2n lanes and never
+    the whole ring. XLA clamps a dynamic slice's origin so that it fits: the
+    slice at `base` then starts `shift` lanes early, and as many of the
+    wanted lanes lie at the ring's head, so the wanted lanes are a second
+    slice, at `shift`, of that one beside the ring's first n lanes. Returns
+    them, and for a writer the clamped origin and `shift`."""
+    W, C = ring.shape
+    start = jnp.minimum(base, C - n)
+    shift = base - start
+    body = jax.lax.dynamic_slice(ring, (jnp.int32(0), start), (W, n))
+    pair = jnp.concatenate([body, ring[:, :n]], axis=1)
+    lanes = jax.lax.dynamic_slice(pair, (jnp.int32(0), shift), (W, n))
+    return lanes, body, start, shift
+
+
 def _append_packed(ring: jax.Array, comp_mat: jax.Array, appended0,
                    n_valid32) -> jax.Array:
     """Contiguous FIFO append of comp_mat[:, :n_valid] at ring lane
     appended0%C. Requires B <= C (callers size rings accordingly). No
-    scatter: one doubled-ring copy + blend + dynamic-update-slice + head
-    fold, all contiguous along the lane axis."""
+    scatter and no copy of the ring: the B lanes at the write position are
+    read, blended and written back by two dynamic-update-slices (the run up
+    to the ring's end, then the wrapped rest at its head), so a donated
+    ring is updated in place and the step's cost follows B, never C."""
     W, C = ring.shape
     B = comp_mat.shape[1]
     base = (appended0 % C).astype(jnp.int32)
-    ext = jnp.concatenate([ring, ring[:, :B]], axis=1)  # [W, C+B]
-    old = jax.lax.dynamic_slice(ext, (jnp.int32(0), base), (W, B))
     p = jnp.arange(B, dtype=jnp.int32)
+    old, body, start, shift = _ring_lanes(ring, base, B)
     blend = jnp.where((p < n_valid32)[None, :], comp_mat, old)
-    ext = jax.lax.dynamic_update_slice(ext, blend, (jnp.int32(0), base))
-    # lanes written past C wrap to the head
-    wrapped = (jnp.arange(B, dtype=jnp.int32) < base + B - C)[None, :]
-    head = jnp.where(wrapped, ext[:, C:], ext[:, :B])
-    return jnp.concatenate([head, ext[:, B:C]], axis=1)
+    # an update's origin is clamped like a slice's: the block written at
+    # `start` keeps `body`'s first `shift` lanes and takes the batch from
+    # there on, rolled[k] = blend[(k - shift) % B]; the batch's last `shift`
+    # lanes wrap to the head
+    rolled = jax.lax.dynamic_slice(
+        jnp.concatenate([blend, blend], axis=1),
+        (jnp.int32(0), B - shift), (W, B))
+    wrapped = (p < shift)[None, :]
+    ring = jax.lax.dynamic_update_slice(
+        ring, jnp.where(wrapped, body, rolled), (jnp.int32(0), start))
+    # the head is read AFTER the first write: on a ring under 2B lanes the
+    # two regions overlap, and the head's unwrapped lanes must keep it
+    return jax.lax.dynamic_update_slice(
+        ring, jnp.where(wrapped, rolled, ring[:, :B]),
+        (jnp.int32(0), jnp.int32(0)))
 
 
 def _fetch_rel_packed(ring: jax.Array, comp_mat: jax.Array, base_idx,
                       appended0, E: int) -> jax.Array:
     """Rows at overall indices base_idx + [0, E): from the ring for pre-batch
     rows, from the compacted batch for this batch's arrivals. Contiguous:
-    two dynamic slices + one blend (the packed `_gather_rel`)."""
+    dynamic slices of E lanes + one blend (the packed `_gather_rel`)."""
     W, C = ring.shape
     B = comp_mat.shape[1]
-    base = (base_idx % C).astype(jnp.int32)
-    ext = jnp.concatenate([ring, ring[:, :E]], axis=1)
-    cand = jax.lax.dynamic_slice(ext, (jnp.int32(0), base), (W, E))
+    cand = _ring_lanes(ring, (base_idx % C).astype(jnp.int32), E)[0]
     rel0 = (appended0 - base_idx).astype(jnp.int32)  # first batch offset
     # align batch lanes so slice lane i reads comp_mat[:, i - rel0]. The
     # slice origin E - rel0 ranges over [0, E] (rel0 >= 0), so the padded
@@ -464,9 +490,19 @@ class WindowOp:
     #: shape-bucketed (narrower) batches directly; runtimes pad batches
     #: back to full capacity for windows that bake their B
     shape_polymorphic = False
+    #: the app runs under @app:playback (set where the window is built,
+    #: before the first trace): time comes from the events, not the wall
+    playback = False
 
     def init_state(self):
         raise NotImplementedError
+
+    def resize(self, window: Optional[int], expire: Optional[int]) -> None:
+        """@capacity(window=, expire=): only a window with such a ring."""
+        raise SiddhiAppCreationError(
+            f"@capacity(window=..., expire=...) sizes the ring of a sliding "
+            f"window (time, delay, externalTime; expire also length and "
+            f"timeLength); {type(self).__name__} has none")
 
     def step(self, state, batch: EventBatch, now: jax.Array):
         raise NotImplementedError
@@ -497,19 +533,48 @@ class SlidingState(NamedTuple):
     ring: jax.Array  # u32[W, C] packed rows (all columns + ts words)
     appended: jax.Array  # int64 total valid arrivals ever
     expired: jax.Array  # int64 total expirations ever
-    wm: jax.Array  # int64 external-time watermark (externalTime mode only)
+    wm: jax.Array  # int64 the window's clock after the last step (time modes)
     overflow: jax.Array  # int64 lifetime live rows overwritten past capacity
+    deferred: jax.Array  # int64 lifetime rows that left late: E ran out
+    live_hwm: jax.Array  # int64 most live rows after a step, since a report
+
+
+def sliding_state0(words: int, capacity: int) -> SlidingState:
+    return SlidingState(
+        ring=jnp.zeros((words, capacity), jnp.uint32),
+        appended=jnp.int64(0),
+        expired=jnp.int64(0),
+        wm=jnp.int64(-(2**62)),
+        overflow=jnp.int64(0),
+        deferred=jnp.int64(0),
+        live_hwm=jnp.int64(0),
+    )
+
+
+def _cummax(x: jax.Array) -> jax.Array:
+    # a log-depth scan: `lax.cummax` of an int64 takes minutes to compile
+    # for the TPU (PERF.md, PR 30)
+    return jax.lax.associative_scan(jnp.maximum, x)
 
 
 class SlidingWindow(WindowOp):
     """Unified FIFO sliding window: length(N) and time(W) (and timeLength) are
-    the same machine with different expiry rules. Events expire strictly in
-    arrival order (timestamps are monotone per stream junction), so the window
-    is always a contiguous [expired, appended) range of overall indices.
+    the same machine with different expiry rules. Events leave strictly in
+    arrival order, so the window is always a contiguous [expired, appended)
+    range of overall indices.
 
-    Reference: LengthWindowProcessor.java:105-143, TimeWindowProcessor.java:133
-    (scheduler-driven TIMER expiry becomes watermark-driven: the `now` scalar
-    advances with each batch / heartbeat and flushes due expirations).
+    Reference: LengthWindowProcessor.java:105-143, TimeWindowProcessor.java:133.
+    The time rule is upstream's FIFO walk, whatever the stamps' order: per
+    arriving event the clock moves to the running maximum of the stamps, the
+    head is popped while `head.ts + W <= clock` (the walk stops at the first
+    head that is not due: a row behind it waits, however old), then the
+    arrival is appended. Which clock: under @app:playback (`playback`, set
+    by the runtime) and for externalTime the running maximum of the stamps
+    THIS WINDOW has processed, carried in the state — never the app's clock
+    as some other thread observed it, which on the served path runs frames
+    ahead of the batch — and a timer batch's `now`; on the wall clock the
+    stamps of the batch, then `now` (scheduler-driven TIMER expiry becomes
+    watermark-driven: heartbeats flush due expirations). docs/PARITY.md.
     """
 
     shape_polymorphic = True  # step() reads B from the batch (bucketing)
@@ -535,160 +600,207 @@ class SlidingWindow(WindowOp):
         #: default 0 keeps the traced jaxpr identical to the pre-lateness
         #: form (optimizer parity + SL204 fastpath certification)
         self.lateness_ms = 0
+        self.W = _layout_words(layout)
+        self._capacity = capacity
+        self._size(capacity, max_expired)
+
+    def _size(self, capacity: Optional[int], max_expired: Optional[int]):
+        """Ring capacity C and expiry width E (rows that may leave a step)."""
+        counted = self.length is not None and self.time_ms is None
         # packed FIFO appends require B <= C (no last-C overwrite dance)
-        if length is not None and time_ms is None:
-            self.C = max(length, batch_cap, 1)
+        if counted:
+            self.C = max(self.length, self.B, 1)
         else:
             self.C = max(capacity or dtypes.config.default_window_capacity,
-                         batch_cap)
+                         self.B)
         self.E = max_expired if max_expired is not None else (
-            batch_cap if (length is not None and time_ms is None) else max(batch_cap, 1024))
-        # the packed candidate fetch slices E rows from a ring extended by E —
-        # a ring smaller than E (tiny timeLength counts) would crash at trace
-        # time or misalign once the base wraps
+            self.B if counted else max(self.B, 1024))
+        # the packed candidate fetch slices E rows of the ring — a ring
+        # smaller than E (tiny timeLength counts) cannot hold them
         self.C = max(self.C, self.E)
         self.chunk_width = self.B + self.E
-        self.W = _layout_words(layout)
+
+    def resize(self, window: Optional[int], expire: Optional[int]) -> None:
+        """@capacity(window=, expire=) on the query: the ring's rows and the
+        rows that may leave it in one step, as the deployed app states them
+        (the defaults are sized for a test, not for a minute of traffic).
+        Before the first trace; refuses what cannot hold."""
+        if window is not None:
+            if self.length is not None:
+                raise SiddhiAppCreationError(
+                    f"@capacity(window={window}): the ring of a length or "
+                    f"timeLength window holds its count, {self.length} rows;"
+                    " only a window bounded by time alone takes a capacity")
+            if window < self.B:
+                raise SiddhiAppCreationError(
+                    f"@capacity(window={window}): a window's ring holds at "
+                    f"least one batch, {self.B} rows")
+        if expire is not None and expire > max(window or self.C, self.B):
+            raise SiddhiAppCreationError(
+                f"@capacity(expire={expire}): more rows than the ring's "
+                f"{window or self.C} cannot leave it in one step")
+        self._size(window if window is not None else self._capacity,
+                   expire)
+        stats = jax.devices()[0].memory_stats() or {}
+        limit = int(stats.get("bytes_limit", 16 << 30))
+        ring = 4 * self.W * self.C
+        if ring > limit:
+            raise SiddhiAppCreationError(
+                f"@capacity(window={self.C}): a ring of {self.C:,} rows of "
+                f"{self.W} words is {ring:,} bytes, more than the device's "
+                f"{limit:,}")
 
     def init_state(self) -> SlidingState:
-        return SlidingState(
-            ring=jnp.zeros((self.W, self.C), jnp.uint32),
-            appended=jnp.int64(0),
-            expired=jnp.int64(0),
-            wm=jnp.int64(-(2**62)),
-            overflow=jnp.int64(0),
-        )
+        return sliding_state0(self.W, self.C)
 
     def step(self, state: SlidingState, batch: EventBatch, now: jax.Array):
         # B is the INCOMING batch capacity (<= self.B under shape-bucketed
         # dispatch): every lane-count shape below derives from it, so one
         # window instance serves the whole bucket ladder (one trace per rung)
-        B, E, C = batch.capacity, self.E, self.C
+        B, E = batch.capacity, self.E
         comp_mat, n_valid32 = compact_packed(batch, self.layout)
         n_valid = n_valid32.astype(jnp.int64)
 
         if self.ts_attr is not None:
-            # external clock: the time axis is an event attribute; the
-            # watermark advances to the max attribute value seen. The packed
+            # external clock: the time axis is an event attribute. The packed
             # ts words are REPLACED by the attribute clock so ring rows carry
             # the expiry-relevant time.
             tcols, _ = _unpack_rows(comp_mat, self.layout)
             comp_ts = tcols[self.ts_attr].astype(jnp.int64)
             w = jax.lax.bitcast_convert_type(comp_ts, jnp.uint32)
             comp_mat = comp_mat.at[-2].set(w[..., 0]).at[-1].set(w[..., 1])
-            mx = jnp.max(jnp.where(
-                jnp.arange(B) < n_valid, comp_ts, jnp.int64(-(2**62))))
-            if self.lateness_ms:
-                # watermark-driven emission: trail max-seen by the allowed
-                # lateness so panes close only once the ingress gate can no
-                # longer release rows into them (deterministic regardless
-                # of arrival order)
-                mx = mx - jnp.int64(self.lateness_ms)
-            wm = jnp.maximum(state.wm, mx)
-            now = wm
         else:
             comp_ts = _packed_ts(comp_mat)
-            wm = state.wm
 
         appended1 = state.appended + n_valid
+        p = jnp.arange(B, dtype=jnp.int32)
+        cur_valid = p < n_valid32
 
         # ---- expiry candidates: the E oldest in-window events ----
         # One contiguous packed fetch (ring rows blended with batch rows);
         # per-lane index math stays int32 (s64 lane math is emulated on TPU).
         pe = jnp.arange(E, dtype=jnp.int32)
-        win_len1 = (appended1 - state.expired).astype(jnp.int32)
-        cand_exists = pe < win_len1
+        win_len0 = (state.appended - state.expired).astype(jnp.int32)
+        win_len1 = win_len0 + n_valid32
         cand_mat = _fetch_rel_packed(
             state.ring, comp_mat, state.expired, state.appended, E)
-        cand_ts = _packed_ts(cand_mat)
 
-        if self.time_ms is not None and self.length is None:
-            # time(W): candidate expires once now >= cand_ts + W; the trigger
-            # position is the first batch arrival with ts >= cand_ts + W (ties:
-            # expire before processing the arrival), or end-of-batch if only
-            # the final watermark covers it.
-            deadline = cand_ts + jnp.int64(self.time_ms)
-            trig = searchsorted32(
-                jnp.where(jnp.arange(B) < n_valid, comp_ts, BIG), deadline,
-                side="left")
-            expires = cand_exists & (deadline <= now)
-            emit_ts = deadline
-        elif self.time_ms is None:
-            # length(N): candidate o is evicted by arrival with overall index
-            # o + N (the N+1'th event); trigger position within this batch:
-            # trig = (expired + pe + N) - appended, all relative → int32.
+        # Every rule below says how many candidates have left, in FIFO
+        # order, by the time arrival i is appended (`pops`, nondecreasing in
+        # i) and by the end of the step (`n_exp`); the rest is shared.
+        wm, deferred = state.wm, state.deferred
+        if self.time_ms is not None:
+            # a candidate leaves at the first arrival whose clock reaches the
+            # running maximum of the deadlines up to it, before that arrival
+            # is counted (ties: expire first); never before it has arrived
+            # itself; at the step's end if only the final clock covers it.
+            own = _packed_ts(cand_mat) + jnp.int64(self.time_ms)
+            deadline = _cummax(jnp.where(pe < win_len1, own, BIG))
+            lane_clock = _cummax(jnp.where(cur_valid, comp_ts, -BIG))
+            tracked = self.ts_attr is not None or self.playback
+            if tracked:
+                lane_clock = jnp.maximum(lane_clock, state.wm)
+                # the allowed lateness holds the step's last clock back, so
+                # that panes close only once the ingress gate can release no
+                # more rows into them; a timer batch brings its own clock
+                end = lane_clock[-1] - jnp.int64(self.lateness_ms)
+                if self.ts_attr is None:
+                    end = jnp.where(n_valid32 > 0, end, now)
+                end = jnp.maximum(state.wm, end)
+            else:
+                end = now
+            n_time = jnp.minimum(jnp.sum(deadline <= end, dtype=jnp.int32),
+                                 win_len1)
+            if tracked and self.ts_attr is None:
+                # a data batch under playback ends with its last arrival's
+                # own pops, and that arrival stays: upstream looks at the
+                # head again only at the next event
+                n_time = jnp.minimum(
+                    n_time, win_len1 - (n_valid32 > 0).astype(jnp.int32))
+            pops_time = jnp.minimum(
+                searchsorted32(deadline, lane_clock, side="right"),
+                jnp.minimum(win_len0 + p, n_time))
+            # rows the step before should have let go and could not: E ran
+            # out (its newest row may stand, as above)
+            deferred = deferred + jnp.sum(
+                (deadline <= state.wm) & (pe < win_len0 - 1), dtype=jnp.int64)
+            wm = jnp.maximum(state.wm, end)
+        if self.length is not None:
+            # length(N): candidate o is evicted by the arrival with overall
+            # index o + N (the N+1'th event), all relative -> int32
             rel = (state.expired + jnp.int64(self.length)
                    - state.appended).astype(jnp.int32)
-            trig = pe + rel
-            expires = cand_exists & (trig < n_valid32)
+            pops_len = jnp.clip(p + 1 - rel, 0, jnp.minimum(E, win_len1))
+            n_len = jnp.clip(n_valid32 - rel, 0, jnp.minimum(E, win_len1))
+        if self.time_ms is None:
+            pops, n_exp = pops_len, n_len
+        elif self.length is None:
+            pops, n_exp = pops_time, n_time
+        else:
+            # timeLength(W, N): whichever rule fires first
+            pops = jnp.maximum(pops_time, pops_len)
+            n_exp = jnp.maximum(n_time, n_len)
+        pops = jnp.where(cur_valid, jnp.minimum(pops, n_exp), n_exp)
+        expires = pe < n_exp
+        # trigger position of candidate j: the arrivals that did not see it
+        # leave, counted by a histogram of `pops` (sorted indices) and a
+        # prefix sum; n_valid where only the step's end lets it go
+        trig = jnp.cumsum(jnp.zeros((E + 1,), jnp.int32).at[pops].add(
+            1, indices_are_sorted=True))[:E]
+        trig = jnp.minimum(trig, n_valid32)
+
+        # stamps of the expired lanes
+        safe_trig = jnp.clip(trig, 0, B - 1)
+        if self.time_ms is None:
             # reference stamps evicted events with current time
             # (LengthWindowProcessor.java:121)
-            safe_trig = jnp.clip(trig, 0, B - 1)
             emit_ts = comp_ts[safe_trig]
+        elif self.length is None:
+            emit_ts = own
         else:
-            # timeLength(W, N): expire on whichever rule fires first.
-            deadline = cand_ts + jnp.int64(self.time_ms)
-            trig_time = searchsorted32(
-                jnp.where(jnp.arange(B) < n_valid, comp_ts, BIG), deadline,
-                side="left")
-            rel = (state.expired + jnp.int64(self.length)
-                   - state.appended).astype(jnp.int32)
-            trig_len = pe + rel
-            time_fires = deadline <= now
-            len_fires = trig_len < n_valid32
-            trig = jnp.where(
-                time_fires & len_fires, jnp.minimum(trig_time, trig_len),
-                jnp.where(time_fires, trig_time, trig_len))
-            expires = cand_exists & (time_fires | len_fires)
-            safe_trig = jnp.clip(trig, 0, B - 1)
-            emit_ts = jnp.where(
-                time_fires & (trig_time <= trig_len), deadline, comp_ts[safe_trig])
-
-        n_expired_new = jnp.sum(expires.astype(jnp.int64))
-        # Expirations are FIFO: `expires` is a prefix of candidates by
-        # construction for length windows; for time windows with monotone ts
-        # it is also a prefix. (Non-prefix would indicate ts disorder.)
+            by_time = (trig >= n_valid32) | (pops_time[safe_trig] > pe)
+            emit_ts = jnp.where(by_time, own, comp_ts[safe_trig])
 
         # ---- assemble chunk: E expired lanes + B current lanes ----
-        p = jnp.arange(B, dtype=jnp.int32)
-        cur_valid = p < n_valid32
-
-        keys_exp = jnp.clip(trig, 0, B) * 4 + KIND_EXPIRED
-        keys_cur = p * 4 + KIND_CURRENT
-
-        all_hi = jnp.concatenate([keys_exp, keys_cur])
-        all_lo = jnp.concatenate([pe, p])
+        # emission order: candidate j goes out before the arrival that sees
+        # it leave, so its rank is j + trig[j] and arrival i's is i + pops[i];
+        # lanes that emit nothing follow in concatenation order
         all_mat = jnp.concatenate([cand_mat, comp_mat], axis=1)
         all_emit = jnp.concatenate([emit_ts, comp_ts])
-        all_valid = jnp.concatenate([expires, cur_valid])
         all_types = jnp.concatenate([
             jnp.full((E,), EventType.EXPIRED, jnp.int8),
             jnp.full((B,), EventType.CURRENT, jnp.int8),
         ])
-
         if self.is_delay:
             # delay(W): expired lanes are re-emitted as CURRENT after the
             # delay; arrivals are swallowed (reference DelayWindowProcessor).
             all_types = jnp.full((E + B,), EventType.CURRENT, jnp.int8)
-            all_valid = jnp.concatenate([expires, jnp.zeros((B,), bool)])
-            exp_v, cur_v = expires, jnp.zeros((B,), bool)
+            cur_out = jnp.zeros((B,), bool)
+            n_cur, lead = jnp.int32(0), jnp.zeros((E,), jnp.int32)
         else:
-            exp_v, cur_v = expires, cur_valid
-
-        # both groups emit in nondecreasing (hi, lo) order (expiry triggers
-        # follow candidate age; currents follow arrival): rank-merge
-        order = _merge_order([(keys_exp, pe), (keys_cur, p)],
-                             [exp_v, cur_v])[:B + E]
-        chunk = _gather_chunk_packed(order, all_mat, all_emit, all_valid,
-                                     all_types, self.layout)
+            cur_out, n_cur, lead = cur_valid, n_valid32, trig
+        total = n_exp + n_cur
+        rank_exp = jnp.where(expires, pe + lead, total + pe - n_exp)
+        rank_cur = jnp.where(cur_out, p + pops,
+                             total + (E - n_exp) + p - n_cur)
+        order = jnp.zeros((E + B,), jnp.int32).at[rank_exp].set(pe) \
+            .at[rank_cur].set(E + p)
+        chunk = _gather_chunk_packed(
+            order, all_mat, all_emit, jnp.concatenate([expires, cur_out]),
+            all_types, self.layout)
 
         # ---- ring update ----
+        # in place, after every read of the old ring. XLA's TPU pipeline
+        # orders the two itself; its CPU pipeline copies the whole ring
+        # unless the write DEPENDS on the read, so it is made to, by a
+        # no-op: n_valid32 <= B always, and `held` is 0 or 1
+        held = (cand_mat[0, 0] >> 31).astype(jnp.int32)
         new_ring = _append_packed(state.ring, comp_mat, state.appended,
-                                  n_valid32)
+                                  jnp.minimum(n_valid32, B + held))
 
         # live rows overwritten by ring wrap (a time window holding more
         # than C un-expired rows): new excess this step, monotone
-        expired1 = state.expired + n_expired_new
+        expired1 = state.expired + n_exp.astype(jnp.int64)
         over0 = jnp.maximum(state.appended - state.expired - self.C, 0)
         over1 = jnp.maximum(appended1 - expired1 - self.C, 0)
         new_state = SlidingState(
@@ -697,6 +809,8 @@ class SlidingWindow(WindowOp):
             expired=expired1,
             wm=wm,
             overflow=state.overflow + jnp.maximum(over1 - over0, 0),
+            deferred=deferred,
+            live_hwm=jnp.maximum(state.live_hwm, appended1 - expired1),
         )
         return new_state, chunk
 
