@@ -48,8 +48,8 @@ from jax import lax
 from ..errors import DefinitionNotExistError, SiddhiAppCreationError
 from ..extension.registry import Registry
 from ..ops.expr_compile import Scope, TypeResolver, compile_expression
-from ..ops.keyed_match import (first_arrival_by_key, gather_lanes,
-                                key_words, scatter_lanes)
+from ..ops.keyed_match import first_arrival_by_key, key_words
+from ..ops.lanes import gather_lanes, scatter_lanes
 from ..ops.search import stable_partition_order
 from ..ops.selector import CompiledSelector
 from ..query_api.definition import Attribute, AttributeType, StreamDefinition
@@ -1762,6 +1762,8 @@ class PatternQueryRuntime:
                 lanes, unique_indices=True)
         fits = valid & (rank < n_free)
         n_drop = jnp.sum(valid & ~fits, dtype=jnp.int64)
+        # a free slot of its own per fitting lane, P (dropped) for the rest:
+        # no two in-bounds slots are equal, as scatter_lanes asks
         slot = jnp.where(fits, free_order[jnp.clip(rank, 0, P - 1)], P)
 
         new_frames = {}

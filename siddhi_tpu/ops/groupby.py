@@ -12,6 +12,10 @@ zero the accumulator (batch windows). Batched faithfully as:
      each slot segment; carry-in comes from the persistent state table
   3. results scatter back to original lane order; segment totals update state
 
+Columns that share an index cross a gather packed side by side, and an 8-byte
+column crosses a scatter as two 32-bit words (ops/lanes.py: the TPU emulates
+64-bit integers, and prices a gather by its index, not by its row).
+
 RESET is handled with *epochs*: a per-key epoch counter increments on reset;
 a state-table value whose epoch is stale reads as the aggregator's zero. This
 keeps the scan a pure prefix-sum (no data-dependent control flow, XLA-friendly).
@@ -29,6 +33,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from .lanes import gather_lanes, scatter_lanes
 from .search import searchsorted32, stable_argsort_bounded
 
 
@@ -79,14 +84,31 @@ def grouped_scan(
     lands); identity is +/-inf (or dtype extremes for ints).
     """
     K = state.values.shape[0]
-    plan = _segment_plan(slots, valid, resets, current_epoch, K)
-    new_values, s_out = _scan_component(
-        state.values, state.epoch, deltas, valid, plan, op)
+    combine, identity = _OPS[op](deltas.dtype)
+    plan = _segment_plan(
+        slots, valid, resets, current_epoch, K,
+        (jnp.where(valid, deltas, jnp.full_like(deltas, identity)),))
+    within = _segmented_scan(plan.s_cols[0], plan.seg_start, combine,
+                             identity)
+
+    # carry-in: only the segment whose epoch matches the state's stored epoch
+    # for that slot gets the stored value; stale epochs read the identity.
+    stored_vals, stored_epoch = gather_lanes(
+        (state.values, state.epoch), plan.safe_slots)
+    carry = jnp.where(
+        plan.epoch_ok_slots & (stored_epoch == plan.s_epochs), stored_vals,
+        jnp.full_like(stored_vals, identity))
+    carry_seg, = gather_lanes((carry,), plan.start_idx)
+
+    s_out = combine(carry_seg, within)
+    new_values = scatter_lanes(state.values, plan.write_slot,
+                               s_out.astype(state.values.dtype))
     new_epoch = state.epoch.at[plan.write_slot].set(
         plan.s_epochs.astype(state.epoch.dtype), mode="drop")
-    # scatter back to lane order (one scatter; an inverse-permutation gather
-    # would cost an extra scatter to build the inverse)
-    out = jnp.zeros_like(s_out).at[plan.order].set(s_out)
+    # scatter back to lane order (an inverse permutation and a packed gather
+    # cost the same on the chip: PERF.md, PR 33). `order` is a permutation:
+    # no two lanes write one place, so an 8-byte column's words may go apart
+    out = scatter_lanes(jnp.zeros_like(s_out), plan.order, s_out)
     return GroupState(new_values, new_epoch), out
 
 
@@ -97,6 +119,9 @@ class _SegmentPlan(NamedTuple):
     order: jax.Array
     s_slots: jax.Array
     s_epochs: jax.Array
+    #: the caller's per-lane columns in sorted order (they ride the one row
+    #: gather that sorts the slots and epochs)
+    s_cols: tuple
     seg_start: jax.Array
     safe_slots: jax.Array
     epoch_ok_slots: jax.Array  # s_slots < K (validity of gathers)
@@ -106,7 +131,8 @@ class _SegmentPlan(NamedTuple):
     start_idx: jax.Array
 
 
-def _segment_plan(slots, valid, resets, current_epoch, K) -> _SegmentPlan:
+def _segment_plan(slots, valid, resets, current_epoch, K,
+                  cols: tuple = ()) -> _SegmentPlan:
     sentinel = jnp.int32(K)
     slots_v = jnp.where(valid, slots, sentinel)
 
@@ -119,8 +145,8 @@ def _segment_plan(slots, valid, resets, current_epoch, K) -> _SegmentPlan:
     # stable sort by (slot, lane) — lane order inside a slot is preserved.
     # slots_v is non-negative (< K+1), as stable_argsort_bounded requires
     order = stable_argsort_bounded(slots_v)
-    s_slots = slots_v[order]
-    s_epochs = lane_epoch[order]
+    s_slots, s_epochs, *s_cols = gather_lanes(
+        (slots_v, lane_epoch, *cols), order)
 
     # a new segment starts when slot changes OR lane epoch changes
     prev_slot = jnp.concatenate([jnp.full((1,), -1, s_slots.dtype), s_slots[:-1]])
@@ -129,8 +155,10 @@ def _segment_plan(slots, valid, resets, current_epoch, K) -> _SegmentPlan:
 
     safe_slots = jnp.minimum(s_slots, K - 1)
 
-    # state writes come from the last lane of each *slot* run (unique per
-    # slot, so the scatter has no duplicate indices; last epoch's value wins)
+    # state writes come from the last lane of each *slot* run, every other
+    # lane writes the out-of-bounds sentinel and is dropped: NO TWO IN-BOUNDS
+    # ENTRIES OF write_slot ARE EQUAL (last epoch's value wins), which is
+    # what lets scatter_lanes send an 8-byte table's words apart
     next_slot = jnp.concatenate([s_slots[1:], jnp.full((1,), -1, s_slots.dtype)])
     is_slot_end = s_slots != next_slot
     write_slot = jnp.where((s_slots < K) & is_slot_end, s_slots, sentinel)
@@ -140,32 +168,8 @@ def _segment_plan(slots, valid, resets, current_epoch, K) -> _SegmentPlan:
     start_idx = jax.lax.associative_scan(
         jnp.maximum, jnp.where(seg_start, idx, 0))
 
-    return _SegmentPlan(order, s_slots, s_epochs, seg_start, safe_slots,
-                        s_slots < K, write_slot, start_idx)
-
-
-def _scan_component(values, epoch_table, deltas, valid, plan: _SegmentPlan,
-                    op: str):
-    """One component's segmented scan + carry + state write over a shared
-    plan. Returns (new_values, sorted-order outputs)."""
-    combine, identity = _OPS[op](deltas.dtype)
-    s_deltas = jnp.where(valid, deltas,
-                         jnp.full_like(deltas, identity))[plan.order]
-    within = _segmented_scan(s_deltas, plan.seg_start, combine, identity)
-
-    # carry-in: only the segment whose epoch matches the state's stored epoch
-    # for that slot gets the stored value; stale epochs read the identity.
-    stored_vals = values[plan.safe_slots]
-    stored_epoch = epoch_table[plan.safe_slots]
-    carry = jnp.where(
-        plan.epoch_ok_slots & (stored_epoch == plan.s_epochs), stored_vals,
-        jnp.full_like(stored_vals, identity))
-    carry_seg = carry[plan.start_idx]  # shared start-index gather
-
-    s_out = combine(carry_seg, within)
-    new_values = values.at[plan.write_slot].set(
-        s_out.astype(values.dtype), mode="drop")
-    return new_values, s_out
+    return _SegmentPlan(order, s_slots, s_epochs, tuple(s_cols), seg_start,
+                        safe_slots, s_slots < K, write_slot, start_idx)
 
 
 def grouped_scan_fused(
@@ -185,22 +189,28 @@ def grouped_scan_fused(
 
     Returns (new_values_list, new_shared_epoch, per-lane outputs list)."""
     K = shared_epoch.shape[0]
-    plan = _segment_plan(slots, valid, resets, current_epoch, K)
-    stored_epoch = shared_epoch[plan.safe_slots]
+    plan = _segment_plan(
+        slots, valid, resets, current_epoch, K,
+        tuple(jnp.where(valid, d, jnp.zeros((), d.dtype))
+              for d in deltas_list))
+    stored_epoch, *stored_vals = gather_lanes(
+        (shared_epoch, *values_list), plan.safe_slots)
     epoch_live = plan.epoch_ok_slots & (stored_epoch == plan.s_epochs)
-    inv_order = invert_permutation(plan.order)  # ONE scatter, n gathers
-    new_values, outs = [], []
-    for values, deltas in zip(values_list, deltas_list):
-        sd = jnp.where(valid, deltas, jnp.zeros((), deltas.dtype))[plan.order]
+    carries = gather_lanes(
+        [jnp.where(epoch_live, sv, jnp.zeros_like(sv)) for sv in stored_vals],
+        plan.start_idx)
+    s_outs = []
+    for values, sd, carry_seg in zip(values_list, plan.s_cols, carries):
         within = _segmented_scan(sd, plan.seg_start, lambda a, b: a + b,
                                  jnp.zeros((), sd.dtype))
-        stored_vals = values[plan.safe_slots]
-        carry = jnp.where(epoch_live, stored_vals, jnp.zeros_like(stored_vals))
-        s_out = carry[plan.start_idx] + within.astype(values.dtype)
-        new_values.append(values.at[plan.write_slot].set(s_out, mode="drop"))
-        outs.append(s_out[inv_order])
+        s_outs.append(carry_seg + within.astype(values.dtype))
+    new_values = [scatter_lanes(values, plan.write_slot, s_out)
+                  for values, s_out in zip(values_list, s_outs)]
     new_epoch = shared_epoch.at[plan.write_slot].set(
         plan.s_epochs.astype(shared_epoch.dtype), mode="drop")
+    # ONE scatter builds the inverse, one row gather brings every
+    # component back to lane order
+    outs = gather_lanes(s_outs, invert_permutation(plan.order))
     return new_values, new_epoch, outs
 
 
@@ -225,11 +235,11 @@ def ungrouped_scan(
     s_deltas = jnp.where(valid, deltas, jnp.full_like(deltas, identity))
     within = _segmented_scan(s_deltas, seg_start, combine, identity)
     stored = state.values[0]
-    carry_lane = jnp.where(state.epoch[0] == lane_epoch, stored,
-                           jnp.full_like(stored, identity))
-    carry_at_start = jnp.where(seg_start, carry_lane,
-                               jnp.full_like(carry_lane, identity))
-    carry_seg = _segment_broadcast_op(carry_at_start, seg_start, identity)
+    # a segment is a run of one lane epoch and the carry depends on nothing
+    # else, so every lane already holds its segment's carry: no broadcast
+    # from the segment's start (an emulated-int64 gather a step on the TPU)
+    carry_seg = jnp.where(state.epoch[0] == lane_epoch, stored,
+                          jnp.full_like(stored, identity))
     s_out = combine(carry_seg, within)
     new_state = GroupState(
         values=state.values.at[0].set(s_out[-1].astype(state.values.dtype)),
@@ -257,11 +267,9 @@ def ungrouped_scan_fused(
         combine, identity = _OPS["sum"](deltas.dtype)
         s_deltas = jnp.where(valid, deltas, jnp.full_like(deltas, identity))
         within = _segmented_scan(s_deltas, seg_start, combine, identity)
-        carry_lane = jnp.where(epoch_ok, values[0],
-                               jnp.full_like(values[0], identity))
-        carry_at_start = jnp.where(seg_start, carry_lane,
-                                   jnp.full_like(carry_lane, identity))
-        carry_seg = _segment_broadcast_op(carry_at_start, seg_start, identity)
+        # per lane, as in ungrouped_scan: the carry follows the lane epoch
+        carry_seg = jnp.where(epoch_ok, values[0],
+                              jnp.full_like(values[0], identity))
         s_out = combine(carry_seg, within)
         new_values.append(values.at[0].set(s_out[-1].astype(values.dtype)))
         outs.append(s_out)

@@ -23,13 +23,15 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .lanes import words
+
 _NO_LANE = 2**62  # Python int literal: above every packed (position, lane)
 
 
 def key_words(col: jax.Array) -> tuple:
     """A key column as the int32 words the sort compares: equal words iff
     equal values. Callers pass int32, bool or int64 columns."""
-    return tuple(_words(col))
+    return tuple(words(col))
 
 
 def first_arrival_by_key(lane_words: tuple, lane_ok: jax.Array,
@@ -82,53 +84,3 @@ def first_arrival_by_key(lane_words: tuple, lane_ok: jax.Array,
     lane = jnp.full((P,), -1, jnp.int32).at[dest].set(
         jnp.where(hit, cand_lane, -1), mode="drop", unique_indices=True)
     return lane >= 0, jnp.maximum(lane, 0)
-
-
-def _words(a: jax.Array) -> list:
-    """A column as 32-bit words (two for an 8-byte type, high first)."""
-    if a.dtype.itemsize == 8:
-        bits = a if a.dtype == jnp.int64 else lax.bitcast_convert_type(
-            a, jnp.int64)
-        return [(bits >> 32).astype(jnp.int32), bits.astype(jnp.int32)]
-    if a.dtype.itemsize == 4:
-        return [lax.bitcast_convert_type(a, jnp.int32)]
-    return [a.astype(jnp.int32)]  # bool, int8, int16
-
-
-def _from_words(words: list, dtype) -> jax.Array:
-    if jnp.dtype(dtype).itemsize == 8:
-        hi, lo = words
-        bits = (hi.astype(jnp.int64) << 32) \
-            | lo.astype(jnp.uint32).astype(jnp.int64)
-        return bits if dtype == jnp.int64 else lax.bitcast_convert_type(
-            bits, dtype)
-    if jnp.dtype(dtype).itemsize == 4:
-        return lax.bitcast_convert_type(words[0], dtype)
-    return words[0].astype(dtype)
-
-
-def gather_lanes(tree, idx: jax.Array):
-    """Every `[N]` leaf of `tree` at lanes `idx`, through ONE row gather of
-    the leaves packed side by side as 32-bit words: a gather costs by the
-    index, not by the row's width (a 2^20-lane gather of one word takes
-    9.9 ms on a v5e, of an emulated int64 17.5: PERF.md, PR 30)."""
-    leaves, treedef = jax.tree_util.tree_flatten(tree)
-    rows = jnp.stack([w for a in leaves for w in _words(a)], axis=1)[idx]
-    out, at = [], 0
-    for a in leaves:
-        n = 2 if a.dtype.itemsize == 8 else 1
-        out.append(_from_words([rows[:, at + k] for k in range(n)], a.dtype))
-        at += n
-    return jax.tree_util.tree_unflatten(treedef, out)
-
-
-def scatter_lanes(dst: jax.Array, slot: jax.Array, src) -> jax.Array:
-    """`dst.at[slot].set(src, mode="drop")` for a `[P]` column; an 8-byte
-    column goes as two columns of 32-bit words, because the TPU scatters an
-    emulated int64 twelve times slower than a word (131,072 updates into
-    2^20 lanes: 15.8 ms against 1.3, PERF.md, PR 30)."""
-    src = jnp.broadcast_to(jnp.asarray(src, dst.dtype), slot.shape)
-    if dst.dtype.itemsize != 8:
-        return dst.at[slot].set(src, mode="drop")
-    return _from_words([d.at[slot].set(w, mode="drop") for d, w in zip(
-        _words(dst), _words(src))], dst.dtype)

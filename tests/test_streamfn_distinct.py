@@ -96,6 +96,25 @@ class TestDistinctCount:
         currents = [e.data[1] for e in got if not e.is_expired]
         assert currents == [1, 1, 2]
 
+    @pytest.mark.parametrize("flush_each", [True, False],
+                             ids=["a_batch_an_event", "one_batch"])
+    def test_a_value_that_leaves_and_returns(self, flush_each):
+        # the (u1, a) pair's count goes 1 -> 0 -> 1 -> 0 -> 1 (inside ONE
+        # batch without the flushes): each transition reads the whole
+        # post-update count of the hashed pair table
+        rt = build(self.APP.format(window="#window.length(2)"))
+        got = q_callback(rt, "q")
+        h = rt.get_input_handler("T")
+        pages = ["a", "b", "c", "a", "b", "c", "a", "a"]
+        for page in pages:
+            h.send(("u1", page, 1))
+            if flush_each:
+                rt.flush()
+        rt.flush()
+        currents = [e.data[1] for e in got if not e.is_expired]
+        assert currents == [len(set(pages[max(0, i - 1):i + 1]))
+                            for i in range(len(pages))]
+
     def test_float_values_distinct_by_bits(self):
         app = ("define stream T (user string, price double, v long);\n"
                "@info(name='q') from T select user, distinctCount(price) as n "

@@ -55,14 +55,17 @@ class Deployment:
         self.symbols: list = []
         self.stamps: list = []
 
-    def send(self, stamps, seed: int) -> None:
-        """One frame with these stamps, through the wire decoder and the
-        ingress pipeline; the frame is run before the next is sent, so the
-        serialisation is the order of the calls."""
+    def send(self, stamps, seed: int, symbols=None) -> None:
+        """One frame with these stamps (and symbols: seeded without),
+        through the wire decoder and the ingress pipeline; the frame is run
+        before the next is sent, so the serialisation is the order of the
+        calls."""
         stamps = np.asarray(stamps, np.int64)
         n = stamps.size
-        ids = np.random.default_rng([5, seed]).integers(0, SYMBOLS, n)
-        symbols = [f"S{k:03d}" for k in ids.tolist()]
+        if symbols is None:
+            ids = np.random.default_rng([5, seed]).integers(0, SYMBOLS, n)
+            symbols = [f"S{k:03d}" for k in ids.tolist()]
+        symbols = list(symbols)
         cols = {"symbol": np.array(symbols, dtype=object),
                 "price": np.ones(n, np.float32),
                 "volume": np.ones(n, np.int64), "timestamp": stamps}
@@ -132,6 +135,23 @@ def test_a_stale_arrival_leaves_at_the_next_arrival_not_before(deploy):
     d.send([5000, 5001, 100, 5002, 5003], 1)  # 100 is older than the window
     d.send([5004, 6500, 6501], 2)
     ts, got = d.rows()
+    assert got == distinct_counts(d.symbols, d.stamps, 1000)
+
+
+@pytest.mark.parametrize("frames", [
+    # X's one row leaves at the arrival of the next X: 1 -> 0 -> 1
+    [([0, 1], "XY"), ([1500, 1501], "XZ")],
+    # and again inside the same batch, with rows that came in it: the pair
+    # count is read at 0 and at 1 twice over (`pair_post == 0`, `== 1`)
+    [([0], "X"), ([1200, 1300, 2300, 2301, 3400], "XYXXX")],
+    # a second X keeps the pair alive while the first leaves: 2 -> 1 -> 2
+    [([0, 600], "XX"), ([1100, 1700], "XX")],
+], ids=["leaves_and_returns", "twice_in_one_batch", "a_duplicate_stays"])
+def test_a_symbol_that_leaves_and_returns_inside_one_batch(deploy, frames):
+    d = deploy(app_text())
+    for f, (stamps, symbols) in enumerate(frames):
+        d.send(stamps, f, symbols=symbols)
+    _, got = d.rows()
     assert got == distinct_counts(d.symbols, d.stamps, 1000)
 
 
