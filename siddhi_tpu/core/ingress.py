@@ -491,7 +491,6 @@ class IngressPipeline:
             if item is None:
                 self._q.task_done()
                 return
-            run = Span("siddhi.ingress.worker_run", start=item[1]).begin()
             t0 = time.perf_counter_ns()
             try:
                 kind, start, m, ts, payload = item
@@ -580,7 +579,6 @@ class IngressPipeline:
                 except Exception:
                     pass
             finally:
-                run.end()
                 self._q.task_done()
 
     # ---------------------------------------------------------------- feeder

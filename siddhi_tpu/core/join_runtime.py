@@ -40,7 +40,7 @@ from ..query_api.execution import (
     Query,
     SingleInputStream,
 )
-from ..telemetry.tracing import StageCells
+from ..telemetry.tracing import StageCells, stage
 from . import dtypes
 from .context import SiddhiAppContext
 from .event import EventBatch, EventType, StreamCodec
@@ -507,171 +507,177 @@ class JoinQueryRuntime:
             mm_probe, mm_build = (mml, mmr) if from_left else (mmr, mml)
 
             # --- probe-side filter + window append ---
-            pscope = Scope()
-            pscope.add_frame(probe_side.ref, batch.cols, batch.ts, batch.valid,
-                             default=True)
-            pscope.extras["now"] = now
-            mask = batch.valid
-            if probe_side.is_named_window:
-                # window emissions carry CURRENT + EXPIRED; only arrivals probe
-                mask = mask & (batch.types == EventType.CURRENT)
-            for f in filters:
-                mask = mask & f(pscope)
-            batch = dataclasses.replace(batch, valid=mask)
-            pscope.valids[probe_side.ref] = mask
-
-            if not (probe_side.is_table or probe_side.is_named_window
-                    or probe_side.is_aggregation):
-                appended0 = getattr(w_probe, "appended", None)
-                w_probe, _chunk = probe_side.window.step(w_probe, batch, now)
-                if probe_side._mm_buckets is not None:
-                    live = mask & (batch.types == EventType.CURRENT)
-                    hashes = _hash_exprs(probe_side._mm_build_keys, pscope)
-                    mm_probe = multimap_append(mm_probe, hashes, live,
-                                               appended0)
-
-            # --- build-side contents (multimap path never materializes
-            #     the full ring — candidates gather packed rows below) ---
-            if use_mm:
-                b_cols = b_ts = b_valid = None
-            elif build_side.is_table:
-                b_cols = build_tstate.cols
-                b_ts = build_tstate.ts
-                b_valid = build_tstate.valid
-            elif build_side.is_named_window:
-                b_cols, b_ts, b_valid = build_side.named_window.contents(
-                    build_tstate, now)
-            elif build_side.is_aggregation:
-                b_cols, b_ts, b_valid = build_side.agg_view.contents(
-                    build_tstate, now)
-            else:
-                b_cols, b_ts, b_valid = build_side.window.contents(w_build, now)
-            if (not use_mm) and build_side.filters and (
-                    build_side.is_table or build_side.is_named_window
-                    or build_side.is_aggregation):
-                # stream sides are filtered before their ring append; probed
-                # contents (tables / named windows) are filtered here
-                bscope = Scope()
-                bscope.add_frame(build_side.ref, b_cols, b_ts, b_valid,
+            with stage("filter"):
+                pscope = Scope()
+                pscope.add_frame(probe_side.ref, batch.cols, batch.ts, batch.valid,
                                  default=True)
-                bscope.extras["now"] = now
-                for f in build_side.filters:
-                    b_valid = b_valid & f(bscope)
+                pscope.extras["now"] = now
+                mask = batch.valid
+                if probe_side.is_named_window:
+                    # window emissions carry CURRENT + EXPIRED; only arrivals probe
+                    mask = mask & (batch.types == EventType.CURRENT)
+                for f in filters:
+                    mask = mask & f(pscope)
+                batch = dataclasses.replace(batch, valid=mask)
+                pscope.valids[probe_side.ref] = mask
 
-            # --- candidate pairs ---
-            truncated = jnp.int32(0)
-            if use_mm:
-                bw = build_side.window
-                window_len = w_build.appended - jnp.maximum(
-                    w_build.expired, w_build.appended - bw.C)
-                lane, brow, pv, truncated = probe_equi_mm(
-                    plan, pscope, mask, mm_build, w_build.appended,
-                    window_len, k_max)
-                if bw.time_ms is not None:
-                    # probe-time expiry BEFORE pair compaction, mirroring
-                    # SlidingWindow.contents(): a time window whose own side
-                    # went idle holds rows past their deadline that would
-                    # otherwise consume pair_cap slots and evict live matches
-                    tsw = w_build.ring[-2:, brow]
-                    cand_ts = jax.lax.bitcast_convert_type(
-                        jnp.stack([tsw[0], tsw[1]], axis=-1), jnp.int64)
-                    pv = pv & (cand_ts + jnp.int64(bw.time_ms) > now)
-            elif plan.probe_keys:
-                lane, brow, pv = probe_equi(
-                    plan, pscope, mask, b_cols, b_ts, b_valid,
-                    build_side.ref, k_max)
-            else:
-                lane, brow, pv = probe_cross(mask, b_valid, k_max)
-            # compact the sparse [B*k_max] block before any per-pair gather —
-            # frame materialization, verification, and the selector then run
-            # at ~the real match count instead of k_max x batch. Small blocks
-            # keep full width (compaction would only risk truncation there);
-            # big blocks cap at factor*B with a monitored drop counter.
-            B_probe = batch.ts.shape[0]
-            pair_cap = min(lane.shape[0],
-                           max(dtypes.config.join_pair_cap_factor * B_probe,
-                               32768))
-            if pair_cap < lane.shape[0]:
-                n_matches = jnp.sum(pv, dtype=jnp.int32)
-                dropped = jnp.maximum(n_matches - pair_cap, 0) + truncated
-                lane, brow, pv = compact_pairs(brow, pv, k_max, pair_cap)
-            else:
-                dropped = truncated
+            with stage("window"):
+                if not (probe_side.is_table or probe_side.is_named_window
+                        or probe_side.is_aggregation):
+                    appended0 = getattr(w_probe, "appended", None)
+                    w_probe, _chunk = probe_side.window.step(w_probe, batch, now)
+                    if probe_side._mm_buckets is not None:
+                        live = mask & (batch.types == EventType.CURRENT)
+                        hashes = _hash_exprs(probe_side._mm_build_keys, pscope)
+                        mm_probe = multimap_append(mm_probe, hashes, live,
+                                                   appended0)
+
+            with stage("probe"):
+                # --- build-side contents (multimap path never materializes
+                #     the full ring — candidates gather packed rows below) ---
+                if use_mm:
+                    b_cols = b_ts = b_valid = None
+                elif build_side.is_table:
+                    b_cols = build_tstate.cols
+                    b_ts = build_tstate.ts
+                    b_valid = build_tstate.valid
+                elif build_side.is_named_window:
+                    b_cols, b_ts, b_valid = build_side.named_window.contents(
+                        build_tstate, now)
+                elif build_side.is_aggregation:
+                    b_cols, b_ts, b_valid = build_side.agg_view.contents(
+                        build_tstate, now)
+                else:
+                    b_cols, b_ts, b_valid = build_side.window.contents(w_build, now)
+                if (not use_mm) and build_side.filters and (
+                        build_side.is_table or build_side.is_named_window
+                        or build_side.is_aggregation):
+                    # stream sides are filtered before their ring append; probed
+                    # contents (tables / named windows) are filtered here
+                    bscope = Scope()
+                    bscope.add_frame(build_side.ref, b_cols, b_ts, b_valid,
+                                     default=True)
+                    bscope.extras["now"] = now
+                    for f in build_side.filters:
+                        b_valid = b_valid & f(bscope)
+
+                # --- candidate pairs ---
+                truncated = jnp.int32(0)
+                if use_mm:
+                    bw = build_side.window
+                    window_len = w_build.appended - jnp.maximum(
+                        w_build.expired, w_build.appended - bw.C)
+                    lane, brow, pv, truncated = probe_equi_mm(
+                        plan, pscope, mask, mm_build, w_build.appended,
+                        window_len, k_max)
+                    if bw.time_ms is not None:
+                        # probe-time expiry BEFORE pair compaction, mirroring
+                        # SlidingWindow.contents(): a time window whose own side
+                        # went idle holds rows past their deadline that would
+                        # otherwise consume pair_cap slots and evict live matches
+                        tsw = w_build.ring[-2:, brow]
+                        cand_ts = jax.lax.bitcast_convert_type(
+                            jnp.stack([tsw[0], tsw[1]], axis=-1), jnp.int64)
+                        pv = pv & (cand_ts + jnp.int64(bw.time_ms) > now)
+                elif plan.probe_keys:
+                    lane, brow, pv = probe_equi(
+                        plan, pscope, mask, b_cols, b_ts, b_valid,
+                        build_side.ref, k_max)
+                else:
+                    lane, brow, pv = probe_cross(mask, b_valid, k_max)
+            with stage("compact"):
+                # compact the sparse [B*k_max] block before any per-pair gather —
+                # frame materialization, verification, and the selector then run
+                # at ~the real match count instead of k_max x batch. Small blocks
+                # keep full width (compaction would only risk truncation there);
+                # big blocks cap at factor*B with a monitored drop counter.
+                B_probe = batch.ts.shape[0]
+                pair_cap = min(lane.shape[0],
+                               max(dtypes.config.join_pair_cap_factor * B_probe,
+                                   32768))
+                if pair_cap < lane.shape[0]:
+                    n_matches = jnp.sum(pv, dtype=jnp.int32)
+                    dropped = jnp.maximum(n_matches - pair_cap, 0) + truncated
+                    lane, brow, pv = compact_pairs(brow, pv, k_max, pair_cap)
+                else:
+                    dropped = truncated
 
             # --- pair frames ---
-            p_cols = {k: v[lane] for k, v in batch.cols.items()}
-            p_ts = batch.ts[lane]
-            if use_mm:
-                rows = w_build.ring[:, brow]  # [W, P] packed lane gather
-                g_cols, g_ts = _unpack_rows(rows, build_side.window.layout)
-            else:
-                g_cols = {k: v[brow] for k, v in b_cols.items()}
-                g_ts = b_ts[brow]
-
-            pair = Scope()
-            if from_left:
-                pair.add_frame(probe_side.ref, p_cols, p_ts, pv, default=True)
-                pair.add_frame(build_side.ref, g_cols, g_ts, pv)
-            else:
-                pair.add_frame(build_side.ref, g_cols, g_ts, pv)
-                pair.add_frame(probe_side.ref, p_cols, p_ts, pv, default=True)
-                pair.default_frame = probe_side.ref
-            pair.extras["now"] = now
-
-            # --- exact verification: full ON condition + within ---
-            if plan.residual is not None:
-                pv = pv & plan.residual(pair)
-            if within is not None:
-                pv = pv & (jnp.abs(p_ts - g_ts) <= jnp.int64(within))
-
-            P = lane.shape[0]
-            B = batch.ts.shape[0]
-            if outer:
-                # unmatched probe lanes join a null build frame
-                matched = jax.ops.segment_max(
-                    pv.astype(jnp.int32), lane, num_segments=B) > 0
-                o_valid = mask & ~matched
+            with stage("frames"):
+                p_cols = {k: v[lane] for k, v in batch.cols.items()}
+                p_ts = batch.ts[lane]
                 if use_mm:
-                    zero_g = {k: jnp.zeros((B,), jnp.dtype(dt))
-                              for k, dt in build_side.window.layout.items()}
+                    rows = w_build.ring[:, brow]  # [W, P] packed lane gather
+                    g_cols, g_ts = _unpack_rows(rows, build_side.window.layout)
                 else:
-                    zero_g = {k: jnp.zeros((B,), v.dtype)
-                              for k, v in b_cols.items()}
-                lane = jnp.concatenate([lane, jnp.arange(B)])
-                all_pv = jnp.concatenate([pv, o_valid])
-                has_build = jnp.concatenate(
-                    [jnp.ones((P,), bool), jnp.zeros((B,), bool)])
-                p_cols = {k: jnp.concatenate([v, batch.cols[k]])
-                          for k, v in p_cols.items()}
-                p_ts = jnp.concatenate([p_ts, batch.ts])
-                g_cols = {k: jnp.concatenate([v, zero_g[k]])
+                    g_cols = {k: v[brow] for k, v in b_cols.items()}
+                    g_ts = b_ts[brow]
+
+                pair = Scope()
+                if from_left:
+                    pair.add_frame(probe_side.ref, p_cols, p_ts, pv, default=True)
+                    pair.add_frame(build_side.ref, g_cols, g_ts, pv)
+                else:
+                    pair.add_frame(build_side.ref, g_cols, g_ts, pv)
+                    pair.add_frame(probe_side.ref, p_cols, p_ts, pv, default=True)
+                    pair.default_frame = probe_side.ref
+                pair.extras["now"] = now
+
+                # --- exact verification: full ON condition + within ---
+                if plan.residual is not None:
+                    pv = pv & plan.residual(pair)
+                if within is not None:
+                    pv = pv & (jnp.abs(p_ts - g_ts) <= jnp.int64(within))
+
+                P = lane.shape[0]
+                B = batch.ts.shape[0]
+                if outer:
+                    # unmatched probe lanes join a null build frame
+                    matched = jax.ops.segment_max(
+                        pv.astype(jnp.int32), lane, num_segments=B) > 0
+                    o_valid = mask & ~matched
+                    if use_mm:
+                        zero_g = {k: jnp.zeros((B,), jnp.dtype(dt))
+                                  for k, dt in build_side.window.layout.items()}
+                    else:
+                        zero_g = {k: jnp.zeros((B,), v.dtype)
+                                  for k, v in b_cols.items()}
+                    lane = jnp.concatenate([lane, jnp.arange(B)])
+                    all_pv = jnp.concatenate([pv, o_valid])
+                    has_build = jnp.concatenate(
+                        [jnp.ones((P,), bool), jnp.zeros((B,), bool)])
+                    p_cols = {k: jnp.concatenate([v, batch.cols[k]])
+                              for k, v in p_cols.items()}
+                    p_ts = jnp.concatenate([p_ts, batch.ts])
+                    g_cols = {k: jnp.concatenate([v, zero_g[k]])
+                              for k, v in g_cols.items()}
+                    g_ts = jnp.concatenate([g_ts, jnp.zeros((B,), g_ts.dtype)])
+                    pv = all_pv
+                else:
+                    has_build = jnp.ones((P,), bool)
+
+                # zero the build frame on no-build lanes so projections emit nulls
+                bf_valid = pv & has_build
+                g_cols = {k: jnp.where(bf_valid, v, jnp.zeros((), v.dtype))
                           for k, v in g_cols.items()}
-                g_ts = jnp.concatenate([g_ts, jnp.zeros((B,), g_ts.dtype)])
-                pv = all_pv
-            else:
-                has_build = jnp.ones((P,), bool)
 
-            # zero the build frame on no-build lanes so projections emit nulls
-            bf_valid = pv & has_build
-            g_cols = {k: jnp.where(bf_valid, v, jnp.zeros((), v.dtype))
-                      for k, v in g_cols.items()}
+                out_scope = Scope()
+                lf_cols, lf_ts = (p_cols, p_ts) if from_left else (g_cols, g_ts)
+                rf_cols, rf_ts = (g_cols, g_ts) if from_left else (p_cols, p_ts)
+                lf_valid = pv if from_left else bf_valid
+                rf_valid = bf_valid if from_left else pv
+                out_scope.add_frame(self.left.ref, lf_cols, lf_ts, lf_valid,
+                                    default=True)
+                out_scope.add_frame(self.right.ref, rf_cols, rf_ts, rf_valid)
+                out_scope.extras["now"] = now
 
-            out_scope = Scope()
-            lf_cols, lf_ts = (p_cols, p_ts) if from_left else (g_cols, g_ts)
-            rf_cols, rf_ts = (g_cols, g_ts) if from_left else (p_cols, p_ts)
-            lf_valid = pv if from_left else bf_valid
-            rf_valid = bf_valid if from_left else pv
-            out_scope.add_frame(self.left.ref, lf_cols, lf_ts, lf_valid,
-                                default=True)
-            out_scope.add_frame(self.right.ref, rf_cols, rf_ts, rf_valid)
-            out_scope.extras["now"] = now
-
-            W = pv.shape[0]
-            chunk = EventBatch(
-                ts=p_ts, cols={},
-                valid=pv,
-                types=jnp.zeros((W,), jnp.int8))  # CURRENT
-            sel, out = selector.step(sel, chunk, out_scope)
+            with stage("selector"):
+                W = pv.shape[0]
+                chunk = EventBatch(
+                    ts=p_ts, cols={},
+                    valid=pv,
+                    types=jnp.zeros((W,), jnp.int8))  # CURRENT
+                sel, out = selector.step(sel, chunk, out_scope)
 
             new_wl, new_wr = (w_probe, w_build) if from_left else (w_build, w_probe)
             new_mml, new_mmr = ((mm_probe, mm_build) if from_left
@@ -803,15 +809,17 @@ class JoinQueryRuntime:
                 scope = Scope()
                 scope.add_frame(side.ref, b.cols, b.ts, b.valid, default=True)
                 scope.extras["now"] = n
-                mask = b.valid
-                for f in filters:
-                    mask = mask & f(scope)
-                b = dataclasses.replace(b, valid=mask)
-                w2, _chunk = side.window.step(w, b, n)
-                if side._mm_buckets is not None:
-                    live = mask & (b.types == EventType.CURRENT)
-                    hashes = _hash_exprs(side._mm_build_keys, scope)
-                    mm = multimap_append(mm, hashes, live, w.appended)
+                with stage("filter"):
+                    mask = b.valid
+                    for f in filters:
+                        mask = mask & f(scope)
+                    b = dataclasses.replace(b, valid=mask)
+                with stage("window"):
+                    w2, _chunk = side.window.step(w, b, n)
+                    if side._mm_buckets is not None:
+                        live = mask & (b.types == EventType.CURRENT)
+                        hashes = _hash_exprs(side._mm_build_keys, scope)
+                        mm = multimap_append(mm, hashes, live, w.appended)
                 return w2, mm
 
             side._append_fn = jax.jit(_named(fn, "join_append"))

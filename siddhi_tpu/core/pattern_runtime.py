@@ -68,7 +68,7 @@ from ..query_api.execution import (
 )
 from ..query_api.expression import (And, Compare, CompareOp, Expression,
                                     IsNull, Variable)
-from ..telemetry.tracing import StageCells
+from ..telemetry.tracing import StageCells, stage
 from . import dtypes
 from .context import SiddhiAppContext
 from .event import EventBatch, EventType, StreamCodec
@@ -881,30 +881,32 @@ class PatternQueryRuntime:
                   now) -> jax.Array:
         """[B,P] (or [B,1] for position 0) filter mask for one leg."""
         B = batch.ts.shape[0]
-        scope = Scope()
-        cols_b = {k: v[:, None] for k, v in batch.cols.items()}
-        scope.add_frame(leg.ref, cols_b, batch.ts[:, None],
-                        batch.valid[:, None], default=True)
-        # bare stream name alias
-        scope.frames.setdefault(leg.stream_id, cols_b)
-        scope.valids.setdefault(leg.stream_id, batch.valid[:, None])
-        scope.ts.setdefault(leg.stream_id, batch.ts[:, None])
-        if pend is not None:
-            for ref, cols in pend.frames.items():
-                if ref == leg.ref:
-                    # logical positions capture their OWN legs in the pending
-                    # table; the leg's frame here must stay the ARRIVING
-                    # event, not the (possibly empty) capture — otherwise a
-                    # leg filter evaluates against zeros and never matches
-                    continue
-                scope.add_frame(ref, cols, pend.frame_ts[ref],
-                                pend.frame_valid[ref])
-        scope.extras["now"] = now
-        m = batch.valid[:, None]
-        for ce in leg.compiled:
-            m = m & ce(scope)
-        P = pend.valid.shape[0] if pend is not None else 1
-        return jnp.broadcast_to(m, (B, P))
+        with stage("filter"):
+            scope = Scope()
+            cols_b = {k: v[:, None] for k, v in batch.cols.items()}
+            scope.add_frame(leg.ref, cols_b, batch.ts[:, None],
+                            batch.valid[:, None], default=True)
+            # bare stream name alias
+            scope.frames.setdefault(leg.stream_id, cols_b)
+            scope.valids.setdefault(leg.stream_id, batch.valid[:, None])
+            scope.ts.setdefault(leg.stream_id, batch.ts[:, None])
+            if pend is not None:
+                for ref, cols in pend.frames.items():
+                    if ref == leg.ref:
+                        # logical positions capture their OWN legs in the
+                        # pending table; the leg's frame here must stay the
+                        # ARRIVING event, not the (possibly empty) capture —
+                        # otherwise a leg filter evaluates against zeros and
+                        # never matches
+                        continue
+                    scope.add_frame(ref, cols, pend.frame_ts[ref],
+                                    pend.frame_valid[ref])
+            scope.extras["now"] = now
+            m = batch.valid[:, None]
+            for ce in leg.compiled:
+                m = m & ce(scope)
+            P = pend.valid.shape[0] if pend is not None else 1
+            return jnp.broadcast_to(m, (B, P))
 
     def _first_by_key(self, kp: _KeyPlan, leg, batch: EventBatch,
                       pend: PendingTable, lane_rank, seq0, now):
@@ -920,15 +922,18 @@ class PatternQueryRuntime:
         scope.valids.setdefault(leg.stream_id, batch.valid)
         scope.ts.setdefault(leg.stream_id, batch.ts)
         scope.extras["now"] = now
-        ok = batch.valid
-        for ce in kp.residual:
-            ok = ok & ce(scope)
-        after = jnp.clip(pend.last_seq - seq0, -1, B - 1).astype(jnp.int32)
-        return first_arrival_by_key(
-            key_words(batch.cols[kp.arr_attr]), ok,
-            lane_rank.astype(jnp.int32),
-            key_words(pend.frames[kp.cap_ref][kp.cap_attr]), pend.valid,
-            after)
+        with stage("filter"):
+            ok = batch.valid
+            for ce in kp.residual:
+                ok = ok & ce(scope)
+        with stage("match"):
+            after = jnp.clip(pend.last_seq - seq0, -1,
+                             B - 1).astype(jnp.int32)
+            return first_arrival_by_key(
+                key_words(batch.cols[kp.arr_attr]), ok,
+                lane_rank.astype(jnp.int32),
+                key_words(pend.frames[kp.cap_ref][kp.cap_attr]), pend.valid,
+                after)
 
     def _make_step(self, junction_sid: Optional[str]):
         plan = self.plan
@@ -968,10 +973,11 @@ class PatternQueryRuntime:
                          else jnp.int64(0)]
             B = batch.ts.shape[0]
 
-            n_valid = jnp.sum(batch.valid.astype(jnp.int64))
-            # arrival sequence per lane (valid lanes, in lane order)
-            lane_rank = jnp.cumsum(batch.valid.astype(jnp.int64)) - 1
-            arr_seq = jnp.where(batch.valid, state.seq + lane_rank, BIGSEQ)
+            with stage("filter"):
+                n_valid = jnp.sum(batch.valid.astype(jnp.int64))
+                # arrival sequence per lane (valid lanes, in lane order)
+                lane_rank = jnp.cumsum(batch.valid.astype(jnp.int64)) - 1
+                arr_seq = jnp.where(batch.valid, state.seq + lane_rank, BIGSEQ)
 
             # collected outputs: one block per completion source
             out_blocks = []  # (frames {ref: cols}, fvalid {ref}, fts, ts, valid)
@@ -1018,9 +1024,10 @@ class PatternQueryRuntime:
             # arriving event's own timestamp (see the match below); the wall
             # clock still sweeps it, the app's playback clock does not: on
             # the served path that one runs ahead with every frame decoded
-            for _i in range(len(pending)):
-                if not (playback and (_i + 1) in self._key_plans):
-                    pending[_i] = expire(pending[_i], _i + 1)
+            with stage("match/expire"):
+                for _i in range(len(pending)):
+                    if not (playback and (_i + 1) in self._key_plans):
+                        pending[_i] = expire(pending[_i], _i + 1)
 
             merged = junction_sid == MERGED_SID
 
@@ -1031,32 +1038,34 @@ class PatternQueryRuntime:
                 leg = pos.legs[0]
                 leg_b = self._leg_batch(batch, leg)
                 m = self._leg_cond(leg, leg_b, None, now)[:, 0]  # [B]
-                gated = hg is not None and pi == 0
-                if not every or gated:
-                    # non-every: only the first match consumes the start
-                    # state. Grouped head-every: the gate admits ONE
-                    # iteration at a time — the first qualifying arrival
-                    # past the previous completion's seq starts it, and
-                    # the gate re-opens when the iteration leaves the
-                    # group (EveryPatternTestCase testQuery5 pairing)
-                    a0 = active0_box[0]
-                    if gated:
-                        m = m & (arr_seq >= gate0_box[0])
-                    mseq = jnp.where(m, arr_seq, BIGSEQ)
-                    only = jnp.zeros((B,), bool).at[jnp.argmin(mseq)].set(
-                        True)
-                    m = m & only & a0
-                    active0_box[0] = a0 & ~m.any()
-                frames = {leg.ref: dict(leg_b.cols)}
-                fvalid = {leg.ref: m}
-                fts = {leg.ref: batch.ts}
-                for pos_e in plan.positions[:pi]:  # skipped zero-min refs
-                    for lg in pos_e.legs:
-                        frames[lg.ref] = {
-                            n: jnp.zeros((B,), dtypes.device_dtype(t))
-                            for n, t in self.ref_types[lg.ref].items()}
-                        fvalid[lg.ref] = jnp.zeros((B,), bool)
-                        fts[lg.ref] = jnp.zeros((B,), dtypes.TS_DTYPE)
+                with stage("match"):
+                    gated = hg is not None and pi == 0
+                    if not every or gated:
+                        # non-every: only the first match consumes the start
+                        # state. Grouped head-every: the gate admits ONE
+                        # iteration at a time — the first qualifying arrival
+                        # past the previous completion's seq starts it, and
+                        # the gate re-opens when the iteration leaves the
+                        # group (EveryPatternTestCase testQuery5 pairing)
+                        a0 = active0_box[0]
+                        if gated:
+                            m = m & (arr_seq >= gate0_box[0])
+                        mseq = jnp.where(m, arr_seq, BIGSEQ)
+                        only = jnp.zeros((B,), bool).at[jnp.argmin(mseq)].set(
+                            True)
+                        m = m & only & a0
+                        active0_box[0] = a0 & ~m.any()
+                with stage("frames"):
+                    frames = {leg.ref: dict(leg_b.cols)}
+                    fvalid = {leg.ref: m}
+                    fts = {leg.ref: batch.ts}
+                    for pos_e in plan.positions[:pi]:  # skipped zero-min refs
+                        for lg in pos_e.legs:
+                            frames[lg.ref] = {
+                                n: jnp.zeros((B,), dtypes.device_dtype(t))
+                                for n, t in self.ref_types[lg.ref].items()}
+                            fvalid[lg.ref] = jnp.zeros((B,), bool)
+                            fts[lg.ref] = jnp.zeros((B,), dtypes.TS_DTYPE)
                 self._advance(pending, out_blocks, pi + 1, frames, fvalid,
                               fts, batch.ts, arr_seq, batch.ts, m, drop_acc,
                               gate_ctx=gate_ctx)
@@ -1070,59 +1079,61 @@ class PatternQueryRuntime:
 
                 # ---- absent completion (time-driven, runs on every step) ----
                 if pos.kind == "absent" and pi > 0:
-                    due = pend.valid & (now >= pend.armed_ts +
-                                        jnp.int64(pos.wait_ms))
-                    killed_late = jnp.zeros_like(pend.valid)
-                    if junction_sid is not None and \
-                            (merged or pos.legs[0].stream_id == junction_sid):
-                        # a matching event kills waiting entries first
-                        kill = self._leg_cond(
-                            pos.legs[0], self._leg_batch(batch, pos.legs[0]),
-                            pend, now)
-                        kill = kill & (arr_seq[:, None] > pend.last_seq[None, :])
-                        in_period = (batch.ts[:, None] <
-                                     pend.armed_ts[None, :] + jnp.int64(pos.wait_ms))
-                        killed = (kill & in_period).any(axis=0)
-                        # a match PAST the deadline lands in the NEXT (sticky
-                        # re-armed) period: the completed period still fires,
-                        # then the arming is consumed
-                        killed_late = (kill & ~in_period).any(axis=0)
-                        pend = pend._replace(valid=pend.valid & ~killed)
-                        due = due & ~killed
-                    # completions advance with an invalid (absent) frame
-                    comp_frames = dict(pend.frames)
-                    comp_fvalid = dict(pend.frame_valid)
-                    comp_fts = dict(pend.frame_ts)
-                    ref = pos.legs[0].ref
-                    comp_frames[ref] = {
-                        n: jnp.zeros((P,), dtypes.device_dtype(t))
-                        for n, t in self.ref_types[ref].items()}
-                    comp_fvalid[ref] = jnp.zeros((P,), bool)
-                    comp_fts[ref] = jnp.zeros((P,), dtypes.TS_DTYPE)
-                    comp_ts = pend.armed_ts + jnp.int64(pos.wait_ms)
+                    with stage("match"):
+                        due = pend.valid & (now >= pend.armed_ts +
+                                            jnp.int64(pos.wait_ms))
+                        killed_late = jnp.zeros_like(pend.valid)
+                        if junction_sid is not None and \
+                                (merged or pos.legs[0].stream_id == junction_sid):
+                            # a matching event kills waiting entries first
+                            kill = self._leg_cond(
+                                pos.legs[0], self._leg_batch(batch, pos.legs[0]),
+                                pend, now)
+                            kill = kill & (arr_seq[:, None] > pend.last_seq[None, :])
+                            in_period = (batch.ts[:, None] <
+                                         pend.armed_ts[None, :] + jnp.int64(pos.wait_ms))
+                            killed = (kill & in_period).any(axis=0)
+                            # a match PAST the deadline lands in the NEXT (sticky
+                            # re-armed) period: the completed period still fires,
+                            # then the arming is consumed
+                            killed_late = (kill & ~in_period).any(axis=0)
+                            pend = pend._replace(valid=pend.valid & ~killed)
+                            due = due & ~killed
+                        # completions advance with an invalid (absent) frame
+                        comp_frames = dict(pend.frames)
+                        comp_fvalid = dict(pend.frame_valid)
+                        comp_fts = dict(pend.frame_ts)
+                        ref = pos.legs[0].ref
+                        comp_frames[ref] = {
+                            n: jnp.zeros((P,), dtypes.device_dtype(t))
+                            for n, t in self.ref_types[ref].items()}
+                        comp_fvalid[ref] = jnp.zeros((P,), bool)
+                        comp_fts[ref] = jnp.zeros((P,), dtypes.TS_DTYPE)
+                        comp_ts = pend.armed_ts + jnp.int64(pos.wait_ms)
                     self._advance(
                         pending, out_blocks, pi + 1,
                         comp_frames, comp_fvalid, comp_fts,
                         jnp.where(pend.valid, pend.start_ts, 0),
                         pend.last_seq, comp_ts, due, drop_acc,
                         origin=pend.origin, gate_ctx=gate_ctx)
-                    if pos.sticky:
-                        # `-> every not X for t`: one fire per elapsed quiet
-                        # period — re-arm for the next period; a matching
-                        # arrival consumes the arming permanently
-                        # (EveryAbsentPatternTestCase testQueryAbsent4),
-                        # whether it landed in the current period (killed
-                        # above) or past its deadline (killed_late). A step
-                        # crossing several periods fires once and catches
-                        # up on later steps (batch granularity).
-                        pend = pend._replace(
-                            armed_ts=jnp.where(
-                                due, pend.armed_ts + jnp.int64(pos.wait_ms),
-                                pend.armed_ts),
-                            valid=pend.valid & ~killed_late)
-                    else:
-                        pend = pend._replace(valid=pend.valid & ~due)
-                    pending[pi - 1] = pend
+                    with stage("match"):
+                        if pos.sticky:
+                            # `-> every not X for t`: one fire per elapsed quiet
+                            # period — re-arm for the next period; a matching
+                            # arrival consumes the arming permanently
+                            # (EveryAbsentPatternTestCase testQueryAbsent4),
+                            # whether it landed in the current period (killed
+                            # above) or past its deadline (killed_late). A step
+                            # crossing several periods fires once and catches
+                            # up on later steps (batch granularity).
+                            pend = pend._replace(
+                                armed_ts=jnp.where(
+                                    due, pend.armed_ts + jnp.int64(pos.wait_ms),
+                                    pend.armed_ts),
+                                valid=pend.valid & ~killed_late)
+                        else:
+                            pend = pend._replace(valid=pend.valid & ~due)
+                        pending[pi - 1] = pend
                     return
 
                 # ---- timed logical absent: `not X for t and Y` ---------
@@ -1136,65 +1147,66 @@ class PatternQueryRuntime:
                 # heartbeats.
                 if pos.kind == "notand" and pos.wait_ms is not None \
                         and pi > 0:
-                    a_leg, p_leg = pos.legs
-                    Pn = pend.valid.shape[0]
-                    deadline = pend.armed_ts + jnp.int64(pos.wait_ms)
-                    if junction_sid is not None and (
-                            merged or a_leg.stream_id == junction_sid):
-                        kq = self._leg_cond(
-                            a_leg, self._leg_batch(batch, a_leg), pend, now)
-                        kq = kq & (arr_seq[:, None] > pend.last_seq[None, :])
-                        kq = kq & (batch.ts[:, None] < deadline[None, :])
-                        killed = kq.any(axis=0) & pend.valid
-                        pend = pend._replace(valid=pend.valid & ~killed)
-                    if junction_sid is not None and (
-                            merged or p_leg.stream_id == junction_sid):
-                        leg_b = self._leg_batch(batch, p_leg)
-                        q = self._leg_cond(p_leg, leg_b, pend, now)
-                        q = q & pend.valid[None, :] \
-                            & ~pend.leg_done[:, 1][None, :] \
-                            & (arr_seq[:, None] > pend.last_seq[None, :])
-                        if within is not None:
-                            q = q & (batch.ts[:, None]
-                                     - pend.start_ts[None, :]
-                                     <= jnp.int64(within))
-                        qseq = jnp.where(q, arr_seq[:, None], BIGSEQ)
-                        b_star = jnp.argmin(qseq, axis=0)
-                        matched = q.any(axis=0)
-                        cap = {n: v[b_star] for n, v in leg_b.cols.items()}
-                        cap_ts = batch.ts[b_star]
-                        nf = dict(pend.frames)
-                        nfv = dict(pend.frame_valid)
-                        nft = dict(pend.frame_ts)
-                        nf[p_leg.ref] = {
-                            n: jnp.where(matched, cap[n],
-                                         pend.frames[p_leg.ref][n])
-                            for n in cap}
-                        nfv[p_leg.ref] = pend.frame_valid[p_leg.ref] | matched
-                        nft[p_leg.ref] = jnp.where(
-                            matched, cap_ts, pend.frame_ts[p_leg.ref])
-                        pend = pend._replace(
-                            frames=nf, frame_valid=nfv, frame_ts=nft,
-                            leg_done=pend.leg_done.at[:, 1].set(
-                                pend.leg_done[:, 1] | matched),
-                            last_seq=jnp.where(
-                                matched,
-                                jnp.maximum(arr_seq[b_star], pend.last_seq),
-                                pend.last_seq))
-                    due = pend.valid & pend.leg_done[:, 1] & (now >= deadline)
-                    comp_frames = dict(pend.frames)
-                    comp_fv = dict(pend.frame_valid)
-                    comp_ft = dict(pend.frame_ts)
-                    aref = a_leg.ref
-                    comp_frames[aref] = {
-                        n: jnp.zeros((Pn,), dtypes.device_dtype(t))
-                        for n, t in self.ref_types[aref].items()}
-                    comp_fv[aref] = jnp.zeros((Pn,), bool)
-                    comp_ft[aref] = jnp.zeros((Pn,), dtypes.TS_DTYPE)
-                    comp_ts = jnp.maximum(deadline,
-                                          pend.frame_ts[p_leg.ref])
-                    new_pend = pend._replace(valid=pend.valid & ~due)
-                    pending[pi - 1] = new_pend
+                    with stage("match"):
+                        a_leg, p_leg = pos.legs
+                        Pn = pend.valid.shape[0]
+                        deadline = pend.armed_ts + jnp.int64(pos.wait_ms)
+                        if junction_sid is not None and (
+                                merged or a_leg.stream_id == junction_sid):
+                            kq = self._leg_cond(
+                                a_leg, self._leg_batch(batch, a_leg), pend, now)
+                            kq = kq & (arr_seq[:, None] > pend.last_seq[None, :])
+                            kq = kq & (batch.ts[:, None] < deadline[None, :])
+                            killed = kq.any(axis=0) & pend.valid
+                            pend = pend._replace(valid=pend.valid & ~killed)
+                        if junction_sid is not None and (
+                                merged or p_leg.stream_id == junction_sid):
+                            leg_b = self._leg_batch(batch, p_leg)
+                            q = self._leg_cond(p_leg, leg_b, pend, now)
+                            q = q & pend.valid[None, :] \
+                                & ~pend.leg_done[:, 1][None, :] \
+                                & (arr_seq[:, None] > pend.last_seq[None, :])
+                            if within is not None:
+                                q = q & (batch.ts[:, None]
+                                         - pend.start_ts[None, :]
+                                         <= jnp.int64(within))
+                            qseq = jnp.where(q, arr_seq[:, None], BIGSEQ)
+                            b_star = jnp.argmin(qseq, axis=0)
+                            matched = q.any(axis=0)
+                            cap = {n: v[b_star] for n, v in leg_b.cols.items()}
+                            cap_ts = batch.ts[b_star]
+                            nf = dict(pend.frames)
+                            nfv = dict(pend.frame_valid)
+                            nft = dict(pend.frame_ts)
+                            nf[p_leg.ref] = {
+                                n: jnp.where(matched, cap[n],
+                                             pend.frames[p_leg.ref][n])
+                                for n in cap}
+                            nfv[p_leg.ref] = pend.frame_valid[p_leg.ref] | matched
+                            nft[p_leg.ref] = jnp.where(
+                                matched, cap_ts, pend.frame_ts[p_leg.ref])
+                            pend = pend._replace(
+                                frames=nf, frame_valid=nfv, frame_ts=nft,
+                                leg_done=pend.leg_done.at[:, 1].set(
+                                    pend.leg_done[:, 1] | matched),
+                                last_seq=jnp.where(
+                                    matched,
+                                    jnp.maximum(arr_seq[b_star], pend.last_seq),
+                                    pend.last_seq))
+                        due = pend.valid & pend.leg_done[:, 1] & (now >= deadline)
+                        comp_frames = dict(pend.frames)
+                        comp_fv = dict(pend.frame_valid)
+                        comp_ft = dict(pend.frame_ts)
+                        aref = a_leg.ref
+                        comp_frames[aref] = {
+                            n: jnp.zeros((Pn,), dtypes.device_dtype(t))
+                            for n, t in self.ref_types[aref].items()}
+                        comp_fv[aref] = jnp.zeros((Pn,), bool)
+                        comp_ft[aref] = jnp.zeros((Pn,), dtypes.TS_DTYPE)
+                        comp_ts = jnp.maximum(deadline,
+                                              pend.frame_ts[p_leg.ref])
+                        new_pend = pend._replace(valid=pend.valid & ~due)
+                        pending[pi - 1] = new_pend
                     self._advance(
                         pending, out_blocks, pi + 1,
                         comp_frames, comp_fv, comp_ft,
@@ -1216,56 +1228,58 @@ class PatternQueryRuntime:
                     # measure the quiet period from virtual 0 (which would
                     # both fire spuriously and disarm the kill); realtime
                     # arms at runtime build (reference: query start)
-                    first_ts = jnp.min(jnp.where(
-                        batch.valid, batch.ts, jnp.int64(2 ** 62)))
-                    armed0 = jnp.where(
-                        state.armed0_ts >= 0, state.armed0_ts,
-                        jnp.minimum(first_ts, now))
-                    deadline = armed0 + jnp.int64(pos.wait_ms)
-                    km_any = jnp.bool_(False)
-                    km_late_any = jnp.bool_(False)
-                    kill_ts = jnp.int64(-(2 ** 62))
-                    if junction_sid is not None and (
-                            merged or pos.legs[0].stream_id == junction_sid):
-                        leg0 = pos.legs[0]
-                        km_all = self._leg_cond(
-                            leg0, self._leg_batch(batch, leg0), None,
-                            now)[:, 0]
-                        km = km_all & (batch.ts < deadline)
-                        km_any = km.any()
-                        # a match past the deadline breaks the NEXT period
-                        # (the completed one still fires below); measurement
-                        # restarts from the latest matching arrival
-                        km_late_any = (km_all & ~(batch.ts < deadline)).any()
-                        kill_ts = jnp.max(jnp.where(
-                            km_all, batch.ts, jnp.int64(-(2 ** 62))))
-                    due = active0 & ~km_any & (now >= deadline)
-                    ref = pos.legs[0].ref
-                    ins_valid = jnp.zeros((P,), bool).at[0].set(due)
-                    frames = {ref: {
-                        n: jnp.zeros((P,), dtypes.device_dtype(t))
-                        for n, t in self.ref_types[ref].items()}}
-                    fvalid = {ref: jnp.zeros((P,), bool)}
-                    fts = {ref: jnp.zeros((P,), dtypes.TS_DTYPE)}
+                    with stage("match"):
+                        first_ts = jnp.min(jnp.where(
+                            batch.valid, batch.ts, jnp.int64(2 ** 62)))
+                        armed0 = jnp.where(
+                            state.armed0_ts >= 0, state.armed0_ts,
+                            jnp.minimum(first_ts, now))
+                        deadline = armed0 + jnp.int64(pos.wait_ms)
+                        km_any = jnp.bool_(False)
+                        km_late_any = jnp.bool_(False)
+                        kill_ts = jnp.int64(-(2 ** 62))
+                        if junction_sid is not None and (
+                                merged or pos.legs[0].stream_id == junction_sid):
+                            leg0 = pos.legs[0]
+                            km_all = self._leg_cond(
+                                leg0, self._leg_batch(batch, leg0), None,
+                                now)[:, 0]
+                            km = km_all & (batch.ts < deadline)
+                            km_any = km.any()
+                            # a match past the deadline breaks the NEXT period
+                            # (the completed one still fires below); measurement
+                            # restarts from the latest matching arrival
+                            km_late_any = (km_all & ~(batch.ts < deadline)).any()
+                            kill_ts = jnp.max(jnp.where(
+                                km_all, batch.ts, jnp.int64(-(2 ** 62))))
+                        due = active0 & ~km_any & (now >= deadline)
+                        ref = pos.legs[0].ref
+                        ins_valid = jnp.zeros((P,), bool).at[0].set(due)
+                        frames = {ref: {
+                            n: jnp.zeros((P,), dtypes.device_dtype(t))
+                            for n, t in self.ref_types[ref].items()}}
+                        fvalid = {ref: jnp.zeros((P,), bool)}
+                        fts = {ref: jnp.zeros((P,), dtypes.TS_DTYPE)}
                     self._advance(
                         pending, out_blocks, 1, frames, fvalid, fts,
                         jnp.full((P,), deadline),
                         jnp.full((P,), state.seq - 1),
                         jnp.full((P,), deadline), ins_valid, drop_acc,
                         gate_ctx=gate_ctx)
-                    if every:
-                        # `every not X for t -> ...`: perpetual quiet-period
-                        # monitor (EveryAbsentPatternTestCase testQueryAbsent5
-                        # — one entry advances per elapsed period) — re-arm
-                        # at each fired boundary; a matching arrival (in the
-                        # current period OR past its deadline) restarts
-                        # measurement from its own timestamp
-                        armed0 = jnp.where(
-                            km_any | km_late_any, kill_ts,
-                            jnp.where(due, deadline, armed0))
-                    else:
-                        active0_box[0] = active0 & ~km_any & ~due
-                    armed0_out[0] = armed0
+                    with stage("match"):
+                        if every:
+                            # `every not X for t -> ...`: perpetual quiet-period
+                            # monitor (EveryAbsentPatternTestCase testQueryAbsent5
+                            # — one entry advances per elapsed period) — re-arm
+                            # at each fired boundary; a matching arrival (in the
+                            # current period OR past its deadline) restarts
+                            # measurement from its own timestamp
+                            armed0 = jnp.where(
+                                km_any | km_late_any, kill_ts,
+                                jnp.where(due, deadline, armed0))
+                        else:
+                            active0_box[0] = active0 & ~km_any & ~due
+                        armed0_out[0] = armed0
                     return
 
                 if not feeds:
@@ -1289,49 +1303,52 @@ class PatternQueryRuntime:
                 # earlier than any X advances it (absent frame rides empty,
                 # reference AbsentLogicalPreStateProcessor without a timer)
                 if pos.kind == "notand":
-                    pend = pending[pi - 1]
-                    Pn = pend.valid.shape[0]
-                    a_leg, p_leg = pos.legs
-                    kseq = jnp.full((Pn,), BIGSEQ)
-                    if merged or a_leg.stream_id == junction_sid:
-                        kq = self._leg_cond(
-                            a_leg, self._leg_batch(batch, a_leg), pend, now)
-                        kq = kq & (arr_seq[:, None] > pend.last_seq[None, :])
-                        kseq = jnp.min(jnp.where(kq, arr_seq[:, None],
-                                                 BIGSEQ), axis=0)
-                    pseq = jnp.full((Pn,), BIGSEQ)
-                    b_star = jnp.zeros((Pn,), jnp.int64)
-                    leg_b = None
-                    if merged or p_leg.stream_id == junction_sid:
-                        leg_b = self._leg_batch(batch, p_leg)
-                        q = self._leg_cond(p_leg, leg_b, pend, now)
-                        q = q & pend.valid[None, :] & (
-                            arr_seq[:, None] > pend.last_seq[None, :])
-                        if within is not None:
-                            q = q & (batch.ts[:, None] - pend.start_ts[None, :]
-                                     <= jnp.int64(within))
-                        qs = jnp.where(q, arr_seq[:, None], BIGSEQ)
-                        b_star = jnp.argmin(qs, axis=0)
-                        pseq = jnp.min(qs, axis=0)
-                    advanced = pend.valid & (pseq < kseq)
-                    killed = pend.valid & (kseq < BIGSEQ) & ~advanced
+                    with stage("match"):
+                        pend = pending[pi - 1]
+                        Pn = pend.valid.shape[0]
+                        a_leg, p_leg = pos.legs
+                        kseq = jnp.full((Pn,), BIGSEQ)
+                        if merged or a_leg.stream_id == junction_sid:
+                            kq = self._leg_cond(
+                                a_leg, self._leg_batch(batch, a_leg), pend, now)
+                            kq = kq & (arr_seq[:, None] > pend.last_seq[None, :])
+                            kseq = jnp.min(jnp.where(kq, arr_seq[:, None],
+                                                     BIGSEQ), axis=0)
+                        pseq = jnp.full((Pn,), BIGSEQ)
+                        b_star = jnp.zeros((Pn,), jnp.int64)
+                        leg_b = None
+                        if merged or p_leg.stream_id == junction_sid:
+                            leg_b = self._leg_batch(batch, p_leg)
+                            q = self._leg_cond(p_leg, leg_b, pend, now)
+                            q = q & pend.valid[None, :] & (
+                                arr_seq[:, None] > pend.last_seq[None, :])
+                            if within is not None:
+                                q = q & (batch.ts[:, None] - pend.start_ts[None, :]
+                                         <= jnp.int64(within))
+                            qs = jnp.where(q, arr_seq[:, None], BIGSEQ)
+                            b_star = jnp.argmin(qs, axis=0)
+                            pseq = jnp.min(qs, axis=0)
+                        advanced = pend.valid & (pseq < kseq)
+                        killed = pend.valid & (kseq < BIGSEQ) & ~advanced
                     if leg_b is not None:
-                        cap = {n: v[b_star] for n, v in leg_b.cols.items()}
-                        cap_ts = batch.ts[b_star]
-                        ins_frames = dict(pend.frames)
-                        ins_fvalid = dict(pend.frame_valid)
-                        ins_fts = dict(pend.frame_ts)
-                        ins_frames[p_leg.ref] = cap
-                        ins_fvalid[p_leg.ref] = advanced
-                        ins_fts[p_leg.ref] = cap_ts
-                        ins_frames[a_leg.ref] = {
-                            n: jnp.zeros((Pn,), dtypes.device_dtype(t))
-                            for n, t in self.ref_types[a_leg.ref].items()}
-                        ins_fvalid[a_leg.ref] = jnp.zeros((Pn,), bool)
-                        ins_fts[a_leg.ref] = jnp.zeros((Pn,),
-                                                       dtypes.TS_DTYPE)
-                        pending[pi - 1] = pend._replace(
-                            valid=pend.valid & ~(advanced | killed))
+                        with stage("frames"):
+                            cap = {n: v[b_star] for n, v in leg_b.cols.items()}
+                            cap_ts = batch.ts[b_star]
+                        with stage("match"):
+                            ins_frames = dict(pend.frames)
+                            ins_fvalid = dict(pend.frame_valid)
+                            ins_fts = dict(pend.frame_ts)
+                            ins_frames[p_leg.ref] = cap
+                            ins_fvalid[p_leg.ref] = advanced
+                            ins_fts[p_leg.ref] = cap_ts
+                            ins_frames[a_leg.ref] = {
+                                n: jnp.zeros((Pn,), dtypes.device_dtype(t))
+                                for n, t in self.ref_types[a_leg.ref].items()}
+                            ins_fvalid[a_leg.ref] = jnp.zeros((Pn,), bool)
+                            ins_fts[a_leg.ref] = jnp.zeros((Pn,),
+                                                           dtypes.TS_DTYPE)
+                            pending[pi - 1] = pend._replace(
+                                valid=pend.valid & ~(advanced | killed))
                         self._advance(
                             pending, out_blocks, pi + 1,
                             ins_frames, ins_fvalid, ins_fts,
@@ -1342,8 +1359,9 @@ class PatternQueryRuntime:
                             cap_ts, advanced, drop_acc,
                             origin=pend.origin, gate_ctx=gate_ctx)
                     else:
-                        pending[pi - 1] = pend._replace(
-                            valid=pend.valid & ~killed)
+                        with stage("match"):
+                            pending[pi - 1] = pend._replace(
+                                valid=pend.valid & ~killed)
                     return
 
                 def _joint_kill(pi=pi, pos=pos):
@@ -1351,20 +1369,21 @@ class PatternQueryRuntime:
                     # arrival may legitimately match EITHER remaining leg);
                     # re-run before every leg pass so a breaker that becomes
                     # "next" after an in-batch leg match is still caught
-                    pend = pending[pi - 1]
-                    q_any = jnp.zeros(
-                        (B, pend.valid.shape[0]), bool)
-                    for lj, lg in enumerate(pos.legs):
-                        if not merged and lg.stream_id != junction_sid:
-                            continue
-                        ql = self._leg_cond(lg, self._leg_batch(batch, lg),
-                                            pend, now)
-                        q_any = q_any | (ql & ~pend.leg_done[None, :, lj])
-                    nxt = (arr_seq[:, None] == pend.last_seq[None, :] + 1) \
-                        & batch.valid[:, None]
-                    killed = (nxt & ~q_any).any(axis=0) & pend.valid
-                    pending[pi - 1] = pend._replace(
-                        valid=pend.valid & ~killed)
+                    with stage("match"):
+                        pend = pending[pi - 1]
+                        q_any = jnp.zeros(
+                            (B, pend.valid.shape[0]), bool)
+                        for lj, lg in enumerate(pos.legs):
+                            if not merged and lg.stream_id != junction_sid:
+                                continue
+                            ql = self._leg_cond(lg, self._leg_batch(batch, lg),
+                                                pend, now)
+                            q_any = q_any | (ql & ~pend.leg_done[None, :, lj])
+                        nxt = (arr_seq[:, None] == pend.last_seq[None, :] + 1) \
+                            & batch.valid[:, None]
+                        killed = (nxt & ~q_any).any(axis=0) & pend.valid
+                        pending[pi - 1] = pend._replace(
+                            valid=pend.valid & ~killed)
 
                 #: ordering snapshot for pattern-mode logical legs — sibling
                 #: matches in this batch must not block the other leg's
@@ -1415,36 +1434,42 @@ class PatternQueryRuntime:
                             state.seq, now)
                         # the arrival's row, by ONE packed gather (its
                         # sequence as the 32-bit rank: a word less)
-                        row = (leg_b.cols, batch.ts,
-                               lane_rank.astype(jnp.int32))
+                        with stage("frames"):
+                            row = (leg_b.cols, batch.ts,
+                                   lane_rank.astype(jnp.int32))
                         if within is not None:
                             # (not lax.cummax: of an int64 it takes the
                             # TPU's compiler 200 s, this scan 3)
-                            newest = lax.associative_scan(
-                                jnp.maximum, jnp.where(
-                                    batch.valid, batch.ts,
-                                    jnp.int64(-(2 ** 62))))
+                            with stage("match/expire"):
+                                newest = lax.associative_scan(
+                                    jnp.maximum, jnp.where(
+                                        batch.valid, batch.ts,
+                                        jnp.int64(-(2 ** 62))))
                             row += (newest,)
-                        cap, cap_ts, comp_rank, *seen = gather_lanes(
-                            row, b_star)
-                        comp_seq = state.seq + comp_rank.astype(jnp.int64)
-                        matched = found
+                        with stage("frames"):
+                            cap, cap_ts, comp_rank, *seen = gather_lanes(
+                                row, b_star)
+                        with stage("match"):
+                            comp_seq = state.seq + comp_rank.astype(jnp.int64)
+                            matched = found
                         if within is not None:
-                            died = pend.valid & (
-                                jnp.where(found, seen[0], newest[-1])
-                                - pend.start_ts > jnp.int64(within))
-                            expired_acc[0] = expired_acc[0] + jnp.sum(
-                                died, dtype=jnp.int64)
-                            matched = found & ~died
-                            pend = pend._replace(valid=pend.valid & ~died)
+                            with stage("match/expire"):
+                                died = pend.valid & (
+                                    jnp.where(found, seen[0], newest[-1])
+                                    - pend.start_ts > jnp.int64(within))
+                                expired_acc[0] = expired_acc[0] + jnp.sum(
+                                    died, dtype=jnp.int64)
+                                matched = found & ~died
+                                pend = pend._replace(valid=pend.valid & ~died)
                         ins_frames = dict(pend.frames)
                         ins_fvalid = dict(pend.frame_valid)
                         ins_fts = dict(pend.frame_ts)
                         ins_frames[leg.ref] = cap
                         ins_fvalid[leg.ref] = matched
                         ins_fts[leg.ref] = cap_ts
-                        pending[pi - 1] = pend._replace(
-                            valid=pend.valid & ~matched)
+                        with stage("match"):
+                            pending[pi - 1] = pend._replace(
+                                valid=pend.valid & ~matched)
                         self._advance(
                             pending, out_blocks, pi + 1,
                             ins_frames, ins_fvalid, ins_fts,
@@ -1456,113 +1481,117 @@ class PatternQueryRuntime:
                             origin=pend.origin, gate_ctx=gate_ctx,
                             order=(b_star.astype(jnp.int32), pend.last_seq))
                         continue
-                    q = self._leg_cond(leg, leg_b, pend, now)  # [B,P]
-                    q = q & pend.valid[None, :]
-                    if mid_g is not None:
-                        # mid-every group head: an entry with an iteration
-                        # in flight (busy latch) does not start another —
-                        # re-armed when the iteration completes past the
-                        # group end (_advance gate hook)
-                        q = q & ~pend.leg_done[:, 0][None, :]
-                    if is_seq:
-                        q = q & (arr_seq[:, None] == pend.last_seq[None, :] + 1)
-                    elif pos.kind == "logical":
-                        q = q & (arr_seq[:, None] > pend0.last_seq[None, :])
-                    else:
-                        q = q & (arr_seq[:, None] > pend.last_seq[None, :])
-                    if within is not None:
-                        q = q & (batch.ts[:, None] - pend.start_ts[None, :]
-                                 <= jnp.int64(within))
-
-                    if is_seq and pos.kind != "logical":
-                        # strict: an arrival at seq == last_seq+1 that does NOT
-                        # match kills the entry
-                        nxt = (arr_seq[:, None] == pend.last_seq[None, :] + 1) \
-                            & batch.valid[:, None]
-                        killed = (nxt & ~q).any(axis=0)
-                        pend = pend._replace(valid=pend.valid & ~killed)
+                    with stage("match"):
+                        q = self._leg_cond(leg, leg_b, pend, now)  # [B,P]
                         q = q & pend.valid[None, :]
-
-                    # first qualifying arrival per entry
-                    qseq = jnp.where(q, arr_seq[:, None], BIGSEQ)
-                    b_star = jnp.argmin(qseq, axis=0)  # [P]
-                    matched = q.any(axis=0)
-
-                    cap = {n: v[b_star] for n, v in leg_b.cols.items()}
-                    cap_ts = batch.ts[b_star]
-
-                    if pos.kind == "logical":
-                        other = 1 - li
-                        # logical positions persist their legs in their own
-                        # pending table (both legs are captured refs)
-                        new_frames = dict(pend.frames)
-                        new_fvalid = dict(pend.frame_valid)
-                        new_fts = dict(pend.frame_ts)
-                        new_frames[leg.ref] = {
-                            n: jnp.where(matched, cap[n],
-                                         pend.frames[leg.ref][n])
-                            for n in cap}
-                        new_fvalid[leg.ref] = pend.frame_valid[leg.ref] | matched
-                        new_fts[leg.ref] = jnp.where(
-                            matched, cap_ts, pend.frame_ts[leg.ref])
-                        complete = (
-                            matched if pos.logical_op == "or"
-                            else (matched & pend.leg_done[:, other]))
-                        pend = pend._replace(
-                            frames=new_frames, frame_valid=new_fvalid,
-                            frame_ts=new_fts,
-                            leg_done=pend.leg_done.at[:, li].set(
-                                pend.leg_done[:, li] | matched),
-                            last_seq=jnp.where(
-                                matched,
-                                jnp.maximum(arr_seq[b_star], pend.last_seq),
-                                pend.last_seq))
-                        adv_valid = complete
-                        ins_frames = pend.frames
-                        ins_fvalid = pend.frame_valid
-                        ins_fts = pend.frame_ts
-                        consumed = complete
-                        comp_ts = jnp.where(matched, cap_ts, pend.armed_ts)
-                        pending[pi - 1] = pend._replace(
-                            valid=pend.valid & ~consumed)
-                    else:
-                        # carry captured frames + the new arrival frame into
-                        # the advance; pend's own structure is untouched
-                        ins_frames = dict(pend.frames)
-                        ins_fvalid = dict(pend.frame_valid)
-                        ins_fts = dict(pend.frame_ts)
-                        ins_frames[leg.ref] = cap
-                        ins_fvalid[leg.ref] = matched
-                        ins_fts[leg.ref] = cap_ts
-                        adv_valid = matched
-                        comp_ts = cap_ts
-                        if pos.sticky:
-                            # the entry stays armed; bumping last_seq lets
-                            # the next pass advance the NEXT arrival
-                            pending[pi - 1] = pend._replace(
-                                last_seq=jnp.where(
-                                    matched,
-                                    jnp.maximum(arr_seq[b_star],
-                                                pend.last_seq),
-                                    pend.last_seq))
-                        elif mid_g is not None:
-                            # group-head context entry stays armed but
-                            # busy-latched until this iteration completes
-                            pending[pi - 1] = pend._replace(
-                                leg_done=pend.leg_done.at[:, 0].set(
-                                    pend.leg_done[:, 0] | matched),
-                                last_seq=jnp.where(
-                                    matched,
-                                    jnp.maximum(arr_seq[b_star],
-                                                pend.last_seq),
-                                    pend.last_seq))
+                        if mid_g is not None:
+                            # mid-every group head: an entry with an iteration
+                            # in flight (busy latch) does not start another —
+                            # re-armed when the iteration completes past the
+                            # group end (_advance gate hook)
+                            q = q & ~pend.leg_done[:, 0][None, :]
+                        if is_seq:
+                            q = q & (arr_seq[:, None] == pend.last_seq[None, :] + 1)
+                        elif pos.kind == "logical":
+                            q = q & (arr_seq[:, None] > pend0.last_seq[None, :])
                         else:
-                            pending[pi - 1] = pend._replace(
-                                valid=pend.valid & ~matched)
+                            q = q & (arr_seq[:, None] > pend.last_seq[None, :])
+                        if within is not None:
+                            q = q & (batch.ts[:, None] - pend.start_ts[None, :]
+                                     <= jnp.int64(within))
 
-                    adv_origin = (
-                        jnp.arange(pend.valid.shape[0], dtype=jnp.int32)
-                        if mid_g is not None else pend.origin)
+                        if is_seq and pos.kind != "logical":
+                            # strict: an arrival at seq == last_seq+1 that does NOT
+                            # match kills the entry
+                            nxt = (arr_seq[:, None] == pend.last_seq[None, :] + 1) \
+                                & batch.valid[:, None]
+                            killed = (nxt & ~q).any(axis=0)
+                            pend = pend._replace(valid=pend.valid & ~killed)
+                            q = q & pend.valid[None, :]
+
+                        # first qualifying arrival per entry
+                        qseq = jnp.where(q, arr_seq[:, None], BIGSEQ)
+                        b_star = jnp.argmin(qseq, axis=0)  # [P]
+                        matched = q.any(axis=0)
+
+                    with stage("frames"):
+                        cap = {n: v[b_star] for n, v in leg_b.cols.items()}
+                        cap_ts = batch.ts[b_star]
+
+                    with stage("match"):
+                        if pos.kind == "logical":
+                            other = 1 - li
+                            # logical positions persist their legs in their own
+                            # pending table (both legs are captured refs)
+                            new_frames = dict(pend.frames)
+                            new_fvalid = dict(pend.frame_valid)
+                            new_fts = dict(pend.frame_ts)
+                            new_frames[leg.ref] = {
+                                n: jnp.where(matched, cap[n],
+                                             pend.frames[leg.ref][n])
+                                for n in cap}
+                            new_fvalid[leg.ref] = pend.frame_valid[leg.ref] | matched
+                            new_fts[leg.ref] = jnp.where(
+                                matched, cap_ts, pend.frame_ts[leg.ref])
+                            complete = (
+                                matched if pos.logical_op == "or"
+                                else (matched & pend.leg_done[:, other]))
+                            pend = pend._replace(
+                                frames=new_frames, frame_valid=new_fvalid,
+                                frame_ts=new_fts,
+                                leg_done=pend.leg_done.at[:, li].set(
+                                    pend.leg_done[:, li] | matched),
+                                last_seq=jnp.where(
+                                    matched,
+                                    jnp.maximum(arr_seq[b_star], pend.last_seq),
+                                    pend.last_seq))
+                            adv_valid = complete
+                            ins_frames = pend.frames
+                            ins_fvalid = pend.frame_valid
+                            ins_fts = pend.frame_ts
+                            consumed = complete
+                            comp_ts = jnp.where(matched, cap_ts, pend.armed_ts)
+                            pending[pi - 1] = pend._replace(
+                                valid=pend.valid & ~consumed)
+                        else:
+                            # carry captured frames + the new arrival frame into
+                            # the advance; pend's own structure is untouched
+                            ins_frames = dict(pend.frames)
+                            ins_fvalid = dict(pend.frame_valid)
+                            ins_fts = dict(pend.frame_ts)
+                            ins_frames[leg.ref] = cap
+                            ins_fvalid[leg.ref] = matched
+                            ins_fts[leg.ref] = cap_ts
+                            adv_valid = matched
+                            comp_ts = cap_ts
+                            if pos.sticky:
+                                # the entry stays armed; bumping last_seq lets
+                                # the next pass advance the NEXT arrival
+                                pending[pi - 1] = pend._replace(
+                                    last_seq=jnp.where(
+                                        matched,
+                                        jnp.maximum(arr_seq[b_star],
+                                                    pend.last_seq),
+                                        pend.last_seq))
+                            elif mid_g is not None:
+                                # group-head context entry stays armed but
+                                # busy-latched until this iteration completes
+                                pending[pi - 1] = pend._replace(
+                                    leg_done=pend.leg_done.at[:, 0].set(
+                                        pend.leg_done[:, 0] | matched),
+                                    last_seq=jnp.where(
+                                        matched,
+                                        jnp.maximum(arr_seq[b_star],
+                                                    pend.last_seq),
+                                        pend.last_seq))
+                            else:
+                                pending[pi - 1] = pend._replace(
+                                    valid=pend.valid & ~matched)
+
+                    with stage("match"):
+                        adv_origin = (
+                            jnp.arange(pend.valid.shape[0], dtype=jnp.int32)
+                            if mid_g is not None else pend.origin)
                     self._advance(
                         pending, out_blocks, pi + 1,
                         ins_frames, ins_fvalid, ins_fts,
@@ -1579,20 +1608,21 @@ class PatternQueryRuntime:
                     # qualifying arrivals beyond the per-batch pass bound:
                     # counted as dropped (monitored truncation; raise
                     # config.pattern_sticky_passes or shrink batches)
-                    pend = pending[pi - 1]
-                    leg0 = pos.legs[0]
-                    q_left = self._leg_cond(
-                        leg0, self._leg_batch(batch, leg0), pend, now)
-                    q_left = q_left & pend.valid[None, :] & (
-                        arr_seq[:, None] > pend.last_seq[None, :])
-                    if within is not None:
-                        # arrivals outside the within window could never
-                        # match — they are not truncation
-                        q_left = q_left & (
-                            batch.ts[:, None] - pend.start_ts[None, :]
-                            <= jnp.int64(within))
-                    drop_acc[0] = drop_acc[0] + jnp.sum(
-                        q_left, dtype=jnp.int64)
+                    with stage("match"):
+                        pend = pending[pi - 1]
+                        leg0 = pos.legs[0]
+                        q_left = self._leg_cond(
+                            leg0, self._leg_batch(batch, leg0), pend, now)
+                        q_left = q_left & pend.valid[None, :] & (
+                            arr_seq[:, None] > pend.last_seq[None, :])
+                        if within is not None:
+                            # arrivals outside the within window could never
+                            # match — they are not truncation
+                            q_left = q_left & (
+                                batch.ts[:, None] - pend.start_ts[None, :]
+                                <= jnp.int64(within))
+                        drop_acc[0] = drop_acc[0] + jnp.sum(
+                            q_left, dtype=jnp.int64)
 
             pi = 0
             while pi < S:
@@ -1614,34 +1644,35 @@ class PatternQueryRuntime:
                     leg0 = head_pos.legs[0]
                     if junction_sid is not None and (
                             merged or leg0.stream_id == junction_sid):
-                        if g is hg:
-                            m_left = self._leg_cond(
-                                leg0, self._leg_batch(batch, leg0), None,
-                                now)[:, 0]
-                            m_left = m_left & (arr_seq >= gate0_box[0]) \
-                                & batch.valid
-                            cnt = jnp.sum(m_left, dtype=jnp.int64)
-                            # the in-flight iteration's own start event is
-                            # not a leftover (gate closed => one started)
-                            cnt = jnp.maximum(
-                                cnt - jnp.where(active0_box[0],
-                                                jnp.int64(0), jnp.int64(1)),
-                                0)
-                            drop_acc[0] = drop_acc[0] + cnt
-                        else:
-                            pend_h = pending[g.head - 1]
-                            ql = self._leg_cond(
-                                leg0, self._leg_batch(batch, leg0), pend_h,
-                                now)
-                            ql = ql & pend_h.valid[None, :] & (
-                                arr_seq[:, None] > pend_h.last_seq[None, :])
-                            if within is not None:
-                                ql = ql & (
-                                    batch.ts[:, None]
-                                    - pend_h.start_ts[None, :]
-                                    <= jnp.int64(within))
-                            drop_acc[0] = drop_acc[0] + jnp.sum(
-                                ql, dtype=jnp.int64)
+                        with stage("match"):
+                            if g is hg:
+                                m_left = self._leg_cond(
+                                    leg0, self._leg_batch(batch, leg0), None,
+                                    now)[:, 0]
+                                m_left = m_left & (arr_seq >= gate0_box[0]) \
+                                    & batch.valid
+                                cnt = jnp.sum(m_left, dtype=jnp.int64)
+                                # the in-flight iteration's own start event is
+                                # not a leftover (gate closed => one started)
+                                cnt = jnp.maximum(
+                                    cnt - jnp.where(active0_box[0],
+                                                    jnp.int64(0), jnp.int64(1)),
+                                    0)
+                                drop_acc[0] = drop_acc[0] + cnt
+                            else:
+                                pend_h = pending[g.head - 1]
+                                ql = self._leg_cond(
+                                    leg0, self._leg_batch(batch, leg0), pend_h,
+                                    now)
+                                ql = ql & pend_h.valid[None, :] & (
+                                    arr_seq[:, None] > pend_h.last_seq[None, :])
+                                if within is not None:
+                                    ql = ql & (
+                                        batch.ts[:, None]
+                                        - pend_h.start_ts[None, :]
+                                        <= jnp.int64(within))
+                                drop_acc[0] = drop_acc[0] + jnp.sum(
+                                    ql, dtype=jnp.int64)
                     pi = g.end + 1
                 else:
                     process_position(pi)
@@ -1649,21 +1680,22 @@ class PatternQueryRuntime:
 
             # ---- merge output blocks through the selector ----
             new_sel, out = self._emit(state.sel_state, out_blocks, now)
-            live = sum((jnp.sum(t.valid, dtype=jnp.int32) for t in pending),
-                       jnp.int32(0))
-            new_state = PatternState(
-                pending=tuple(pending),
-                active0=active0_box[0],
-                seq=state.seq + n_valid,
-                sel_state=new_sel,
-                dropped=state.dropped + drop_acc[0],
-                armed0_ts=armed0_out[0],
-                gate0_seq=gate0_box[0],
-                expired=expired_acc[0] + (
-                    0 if state.expired is None else state.expired),
-                live_hwm=live if state.live_hwm is None else jnp.maximum(
-                    state.live_hwm, live),
-            )
+            with stage("append"):
+                live = sum((jnp.sum(t.valid, dtype=jnp.int32) for t in pending),
+                           jnp.int32(0))
+                new_state = PatternState(
+                    pending=tuple(pending),
+                    active0=active0_box[0],
+                    seq=state.seq + n_valid,
+                    sel_state=new_sel,
+                    dropped=state.dropped + drop_acc[0],
+                    armed0_ts=armed0_out[0],
+                    gate0_seq=gate0_box[0],
+                    expired=expired_acc[0] + (
+                        0 if state.expired is None else state.expired),
+                    live_hwm=live if state.live_hwm is None else jnp.maximum(
+                        state.live_hwm, live),
+                )
             return new_state, out
 
         return step
@@ -1697,32 +1729,33 @@ class PatternQueryRuntime:
         if origin is None:
             origin = jnp.full(valid.shape, -1, jnp.int32)
         while True:
-            if gate_ctx is not None:
-                hg = self.plan.head_group
-                if hg is not None and target_pos == hg.end + 1:
-                    # head every-group completion: re-open the gate for
-                    # arrivals past the completing event
-                    # (EveryPatternTestCase testQuery4/5)
-                    any_c = valid.any()
-                    mx = jnp.max(jnp.where(valid, last_seq,
-                                           jnp.int64(-BIGSEQ))) + 1
-                    gate_ctx["active0"][0] = gate_ctx["active0"][0] | any_c
-                    gate_ctx["gate0"][0] = jnp.where(
-                        any_c, jnp.maximum(gate_ctx["gate0"][0], mx),
-                        gate_ctx["gate0"][0])
-                for g in self.plan.mid_groups:
-                    if target_pos == g.end + 1:
-                        # mid every-group completion: clear the origin
-                        # context entry's busy latch and advance its seq
-                        # watermark (testQuery6 sequential iterations)
-                        head_tbl = pending[g.head - 1]
-                        o = jnp.where(valid & (origin >= 0), origin, P)
-                        pending[g.head - 1] = head_tbl._replace(
-                            leg_done=head_tbl.leg_done.at[o, 0].set(
-                                False, mode="drop"),
-                            last_seq=head_tbl.last_seq.at[o].max(
-                                last_seq, mode="drop"))
-                        origin = jnp.full(valid.shape, -1, jnp.int32)
+            with stage("match"):
+                if gate_ctx is not None:
+                    hg = self.plan.head_group
+                    if hg is not None and target_pos == hg.end + 1:
+                        # head every-group completion: re-open the gate for
+                        # arrivals past the completing event
+                        # (EveryPatternTestCase testQuery4/5)
+                        any_c = valid.any()
+                        mx = jnp.max(jnp.where(valid, last_seq,
+                                               jnp.int64(-BIGSEQ))) + 1
+                        gate_ctx["active0"][0] = gate_ctx["active0"][0] | any_c
+                        gate_ctx["gate0"][0] = jnp.where(
+                            any_c, jnp.maximum(gate_ctx["gate0"][0], mx),
+                            gate_ctx["gate0"][0])
+                    for g in self.plan.mid_groups:
+                        if target_pos == g.end + 1:
+                            # mid every-group completion: clear the origin
+                            # context entry's busy latch and advance its seq
+                            # watermark (testQuery6 sequential iterations)
+                            head_tbl = pending[g.head - 1]
+                            o = jnp.where(valid & (origin >= 0), origin, P)
+                            pending[g.head - 1] = head_tbl._replace(
+                                leg_done=head_tbl.leg_done.at[o, 0].set(
+                                    False, mode="drop"),
+                                last_seq=head_tbl.last_seq.at[o].max(
+                                    last_seq, mode="drop"))
+                            origin = jnp.full(valid.shape, -1, jnp.int32)
             if target_pos >= S:
                 out_blocks.append((frames, fvalid, fts, armed_ts, valid,
                                    order))
@@ -1750,57 +1783,58 @@ class PatternQueryRuntime:
                         uses_legs: bool = True) -> PendingTable:
         """Insert candidate entries into dst's free slots, lowest slot
         first, in `order` (see _advance; lane order without it)."""
-        P = self.P
-        free_order = stable_partition_order(~dst.valid)
-        n_free = jnp.sum((~dst.valid).astype(jnp.int32))
-        if order is None:
-            rank = jnp.cumsum(valid.astype(jnp.int32)) - 1
-        else:
-            lanes = jnp.arange(valid.shape[0], dtype=jnp.int32)
-            perm = _upstream_order(valid, order)
-            rank = jnp.zeros_like(lanes).at[perm].set(
-                lanes, unique_indices=True)
-        fits = valid & (rank < n_free)
-        n_drop = jnp.sum(valid & ~fits, dtype=jnp.int64)
-        # a free slot of its own per fitting lane, P (dropped) for the rest:
-        # no two in-bounds slots are equal, as scatter_lanes asks
-        slot = jnp.where(fits, free_order[jnp.clip(rank, 0, P - 1)], P)
+        with stage("append"):
+            P = self.P
+            free_order = stable_partition_order(~dst.valid)
+            n_free = jnp.sum((~dst.valid).astype(jnp.int32))
+            if order is None:
+                rank = jnp.cumsum(valid.astype(jnp.int32)) - 1
+            else:
+                lanes = jnp.arange(valid.shape[0], dtype=jnp.int32)
+                perm = _upstream_order(valid, order)
+                rank = jnp.zeros_like(lanes).at[perm].set(
+                    lanes, unique_indices=True)
+            fits = valid & (rank < n_free)
+            n_drop = jnp.sum(valid & ~fits, dtype=jnp.int64)
+            # a free slot of its own per fitting lane, P (dropped) for the rest:
+            # no two in-bounds slots are equal, as scatter_lanes asks
+            slot = jnp.where(fits, free_order[jnp.clip(rank, 0, P - 1)], P)
 
-        new_frames = {}
-        new_fvalid = {}
-        new_fts = {}
-        for ref in dst.frames:
-            src_cols = frames.get(ref)
-            if src_cols is None:
-                new_frames[ref] = dst.frames[ref]
-                new_fvalid[ref] = dst.frame_valid[ref]
-                new_fts[ref] = dst.frame_ts[ref]
-                continue
-            new_frames[ref] = {
-                n: scatter_lanes(dst.frames[ref][n], slot, src_cols[n])
-                for n in dst.frames[ref]}
-            new_fvalid[ref] = dst.frame_valid[ref].at[slot].set(
-                fvalid.get(ref, valid), mode="drop")
-            new_fts[ref] = scatter_lanes(
-                dst.frame_ts[ref], slot,
-                fts.get(ref, 0))
-        if origin is None:
-            origin = jnp.full(valid.shape, -1, jnp.int32)
-        return PendingTable(
-            frames=new_frames, frame_valid=new_fvalid, frame_ts=new_fts,
-            start_ts=scatter_lanes(dst.start_ts, slot, start_ts),
-            last_seq=scatter_lanes(dst.last_seq, slot, last_seq),
-            armed_ts=scatter_lanes(dst.armed_ts, slot, armed_ts),
-            valid=dst.valid.at[slot].set(valid, mode="drop"),
-            # only a table whose position fills legs or latches (logical,
-            # timed not-and, a mid-every group's head) ever sets a flag: the
-            # others' stay all False, and a [P, 2] scatter is not free
-            leg_done=dst.leg_done.at[slot].set(
-                jnp.zeros((slot.shape[0], 2), bool), mode="drop")
-            if uses_legs else dst.leg_done,
-            origin=dst.origin.at[slot].set(origin.astype(jnp.int32),
-                                           mode="drop"),
-        ), n_drop
+            new_frames = {}
+            new_fvalid = {}
+            new_fts = {}
+            for ref in dst.frames:
+                src_cols = frames.get(ref)
+                if src_cols is None:
+                    new_frames[ref] = dst.frames[ref]
+                    new_fvalid[ref] = dst.frame_valid[ref]
+                    new_fts[ref] = dst.frame_ts[ref]
+                    continue
+                new_frames[ref] = {
+                    n: scatter_lanes(dst.frames[ref][n], slot, src_cols[n])
+                    for n in dst.frames[ref]}
+                new_fvalid[ref] = dst.frame_valid[ref].at[slot].set(
+                    fvalid.get(ref, valid), mode="drop")
+                new_fts[ref] = scatter_lanes(
+                    dst.frame_ts[ref], slot,
+                    fts.get(ref, 0))
+            if origin is None:
+                origin = jnp.full(valid.shape, -1, jnp.int32)
+            return PendingTable(
+                frames=new_frames, frame_valid=new_fvalid, frame_ts=new_fts,
+                start_ts=scatter_lanes(dst.start_ts, slot, start_ts),
+                last_seq=scatter_lanes(dst.last_seq, slot, last_seq),
+                armed_ts=scatter_lanes(dst.armed_ts, slot, armed_ts),
+                valid=dst.valid.at[slot].set(valid, mode="drop"),
+                # only a table whose position fills legs or latches (logical,
+                # timed not-and, a mid-every group's head) ever sets a flag: the
+                # others' stay all False, and a [P, 2] scatter is not free
+                leg_done=dst.leg_done.at[slot].set(
+                    jnp.zeros((slot.shape[0], 2), bool), mode="drop")
+                if uses_legs else dst.leg_done,
+                origin=dst.origin.at[slot].set(origin.astype(jnp.int32),
+                                               mode="drop"),
+            ), n_drop
 
     # ------------------------------------------------------------------ emit
 
@@ -1813,39 +1847,43 @@ class PatternQueryRuntime:
 
         if not out_blocks:
             # empty output
-            W = 1
-            scope = Scope()
-            for ref in all_refs:
-                cols = {n: jnp.zeros((W,), dtypes.device_dtype(t))
-                        for n, t in self.ref_types[ref].items()}
-                scope.add_frame(ref, cols, jnp.zeros((W,), dtypes.TS_DTYPE),
-                                jnp.zeros((W,), bool),
-                                default=(ref == all_refs[0]))
-            self._alias_bare_streams(scope)
-            scope.extras["now"] = now
-            chunk = EventBatch(ts=jnp.zeros((W,), dtypes.TS_DTYPE), cols={},
-                               valid=jnp.zeros((W,), bool),
-                               types=jnp.zeros((W,), jnp.int8))
-            return selector.step(sel_state, chunk, scope)
+            with stage("frames"):
+                W = 1
+                scope = Scope()
+                for ref in all_refs:
+                    cols = {n: jnp.zeros((W,), dtypes.device_dtype(t))
+                            for n, t in self.ref_types[ref].items()}
+                    scope.add_frame(ref, cols, jnp.zeros((W,), dtypes.TS_DTYPE),
+                                    jnp.zeros((W,), bool),
+                                    default=(ref == all_refs[0]))
+                self._alias_bare_streams(scope)
+                scope.extras["now"] = now
+                chunk = EventBatch(ts=jnp.zeros((W,), dtypes.TS_DTYPE), cols={},
+                                   valid=jnp.zeros((W,), bool),
+                                   types=jnp.zeros((W,), jnp.int8))
+            with stage("selector"):
+                return selector.step(sel_state, chunk, scope)
 
         # concatenate blocks lane-wise
-        scope = Scope()
-        tss = jnp.concatenate([b[3] for b in out_blocks])
-        valids = jnp.concatenate([b[4] for b in out_blocks])
-        # upstream's order (see _advance). Blocks that carry none (arrivals
-        # completing a one-position pattern, timer completions) come first,
-        # in lane order; a step of such blocks alone is in order as it is
+        with stage("frames"):
+            scope = Scope()
+            tss = jnp.concatenate([b[3] for b in out_blocks])
+            valids = jnp.concatenate([b[4] for b in out_blocks])
+            # upstream's order (see _advance). Blocks that carry none (arrivals
+            # completing a one-position pattern, timer completions) come first,
+            # in lane order; a step of such blocks alone is in order as it is
         perm = None
-        if any(b[5] is not None for b in out_blocks):
-            comps, prevs = [], []
-            for b in out_blocks:
-                W = b[4].shape[0]
-                comp, prev = b[5] if b[5] is not None else (
-                    jnp.full((W,), -1, jnp.int32), jnp.zeros((W,), jnp.int64))
-                comps.append(comp)
-                prevs.append(prev)
-            perm = _upstream_order(valids, (jnp.concatenate(comps),
-                                            jnp.concatenate(prevs)))
+        with stage("match"):
+            if any(b[5] is not None for b in out_blocks):
+                comps, prevs = [], []
+                for b in out_blocks:
+                    W = b[4].shape[0]
+                    comp, prev = b[5] if b[5] is not None else (
+                        jnp.full((W,), -1, jnp.int32), jnp.zeros((W,), jnp.int64))
+                    comps.append(comp)
+                    prevs.append(prev)
+                perm = _upstream_order(valids, (jnp.concatenate(comps),
+                                                jnp.concatenate(prevs)))
         # a select list that reads each lane alone (no aggregate, order by
         # or limit) commutes with the permutation: permute its few output
         # columns instead of every captured frame
@@ -1853,43 +1891,46 @@ class PatternQueryRuntime:
             selector.has_aggregators or selector.group_vars
             or selector.emit_final_per_group or selector.order_by
             or selector.limit is not None or selector.offset is not None)
-        for ref in all_refs:
-            cols_parts = []
-            valid_parts = []
-            ts_parts = []
-            for frames, fvalid, fts, ts, v, _ in out_blocks:
-                W = ts.shape[0]
-                if ref in frames:
-                    cols_parts.append(frames[ref])
-                    valid_parts.append(fvalid[ref] & v)
-                    ts_parts.append(fts[ref])
-                else:
-                    cols_parts.append({
-                        n: jnp.zeros((W,), dtypes.device_dtype(t))
-                        for n, t in self.ref_types[ref].items()})
-                    valid_parts.append(jnp.zeros((W,), bool))
-                    ts_parts.append(jnp.zeros((W,), dtypes.TS_DTYPE))
-            cols = {n: jnp.concatenate([c[n] for c in cols_parts])
-                    for n in self.ref_types[ref]}
-            fv = jnp.concatenate(valid_parts)
-            # zero missing frames so projections emit nulls
-            cols = {n: jnp.where(fv, v, jnp.zeros((), v.dtype))
-                    for n, v in cols.items()}
-            fts_all = jnp.concatenate(ts_parts)
+        with stage("frames"):
+            for ref in all_refs:
+                cols_parts = []
+                valid_parts = []
+                ts_parts = []
+                for frames, fvalid, fts, ts, v, _ in out_blocks:
+                    W = ts.shape[0]
+                    if ref in frames:
+                        cols_parts.append(frames[ref])
+                        valid_parts.append(fvalid[ref] & v)
+                        ts_parts.append(fts[ref])
+                    else:
+                        cols_parts.append({
+                            n: jnp.zeros((W,), dtypes.device_dtype(t))
+                            for n, t in self.ref_types[ref].items()})
+                        valid_parts.append(jnp.zeros((W,), bool))
+                        ts_parts.append(jnp.zeros((W,), dtypes.TS_DTYPE))
+                cols = {n: jnp.concatenate([c[n] for c in cols_parts])
+                        for n in self.ref_types[ref]}
+                fv = jnp.concatenate(valid_parts)
+                # zero missing frames so projections emit nulls
+                cols = {n: jnp.where(fv, v, jnp.zeros((), v.dtype))
+                        for n, v in cols.items()}
+                fts_all = jnp.concatenate(ts_parts)
+                if perm is not None and not after:
+                    cols, fts_all, fv = jax.tree_util.tree_map(
+                        lambda a: a[perm], (cols, fts_all, fv))
+                scope.add_frame(ref, cols, fts_all, fv,
+                                default=(ref == all_refs[0]))
+            self._alias_bare_streams(scope)
+            scope.extras["now"] = now
             if perm is not None and not after:
-                cols, fts_all, fv = jax.tree_util.tree_map(
-                    lambda a: a[perm], (cols, fts_all, fv))
-            scope.add_frame(ref, cols, fts_all, fv,
-                            default=(ref == all_refs[0]))
-        self._alias_bare_streams(scope)
-        scope.extras["now"] = now
-        if perm is not None and not after:
-            tss, valids = tss[perm], valids[perm]
-        chunk = EventBatch(ts=tss, cols={}, valid=valids,
-                           types=jnp.zeros((tss.shape[0],), jnp.int8))
-        new_sel, out = selector.step(sel_state, chunk, scope)
-        if after:
-            out = gather_lanes(out, perm)
+                tss, valids = tss[perm], valids[perm]
+            chunk = EventBatch(ts=tss, cols={}, valid=valids,
+                               types=jnp.zeros((tss.shape[0],), jnp.int8))
+        with stage("selector"):
+            new_sel, out = selector.step(sel_state, chunk, scope)
+        with stage("emit"):
+            if after:
+                out = gather_lanes(out, perm)
         return new_sel, out
 
     def _alias_bare_streams(self, scope: Scope) -> None:

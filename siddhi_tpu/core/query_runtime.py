@@ -29,7 +29,7 @@ from ..extension.registry import ExtensionKind, Registry
 from ..ops.expr_compile import Scope, TypeResolver, compile_expression
 from ..ops.selector import CompiledSelector
 from ..ops.window_factories import make_window
-from ..telemetry.tracing import StageCells
+from ..telemetry.tracing import StageCells, stage
 from ..ops.windows import PassThroughWindow, WindowOp
 from ..query_api.definition import AttributeType, StreamDefinition, Attribute
 from ..query_api.execution import (
@@ -452,78 +452,82 @@ class QueryRuntime(Receiver):
                     scope.extras[f"table:{tid}"] = tstate
                     scope.extras[f"tableidx:{tid}"] = tidx
                     scope.extras[f"in:{tid}"] = probes[tid]
-            mask = batch.valid
-            for f in filters:
-                mask = mask & f(scope)
-            batch = batch.where_valid(mask)
-            scope.add_frame(frame_ref, batch.cols, batch.ts, batch.valid,
-                            default=True)
-            batch = apply_fns(pre_fns, batch, scope)
+            with stage("filter"):
+                mask = batch.valid
+                for f in filters:
+                    mask = mask & f(scope)
+                batch = batch.where_valid(mask)
+                scope.add_frame(frame_ref, batch.cols, batch.ts, batch.valid,
+                                default=True)
+                batch = apply_fns(pre_fns, batch, scope)
 
             wstate_pre = wstate
-            wstate, chunk = window.step(wstate, batch, now)
+            with stage("window"):
+                wstate, chunk = window.step(wstate, batch, now)
 
-            cscope = Scope()
-            cscope.add_frame(frame_ref, chunk.cols, chunk.ts, chunk.valid, default=True)
-            cscope.extras = dict(scope.extras)
-            chunk = apply_fns(post_fns, chunk, cscope)
-            for f in post_filters:
-                chunk = chunk.where_valid(
-                    f(cscope) | (chunk.types != EventType.CURRENT))
-            if selector.extrema_plan:
-                # removal-capable sliding min/max: range queries over the
-                # window's arrival-order sequence (ops/extrema.py)
-                from ..ops.extrema import (grouped_sliding_extrema_lanes,
-                                           sliding_extrema_lanes)
-                from ..ops.windows import _unpack_rows
-                ring_cols, ring_ts = _unpack_rows(wstate_pre.ring,
-                                                  window.layout)
-                rscope = Scope()
-                rscope.add_frame(
-                    frame_ref, ring_cols, ring_ts,
-                    jnp.ones(ring_ts.shape, bool), default=True)
-                rscope.extras = dict(scope.extras)
-                ghash = selector.extrema_group_hash
-                for slot, eop, args in selector.extrema_plan:
-                    if ghash is not None:
-                        cscope.extras[f"extrema:{slot}"] = \
-                            grouped_sliding_extrema_lanes(
-                                eop, args[0](rscope), ghash(rscope),
-                                wstate_pre.expired, wstate_pre.appended,
-                                chunk, args[0](cscope), ghash(cscope))
-                    else:
-                        cscope.extras[f"extrema:{slot}"] = \
-                            sliding_extrema_lanes(
-                                eop, args[0](rscope), wstate_pre.expired,
-                                wstate_pre.appended, chunk, args[0](cscope))
-            sstate, out = selector.step(sstate, chunk, cscope)
-            if getattr(limiter, "needs_window_contents", False):
-                # non-FIFO snapshot: per-arrival output is suppressed; ticks
-                # re-project the window's live contents. POST-step state so
-                # time-driven evictions (session close on this watermark)
-                # apply; the limiter then drops rows whose arrival ts is
-                # PAST the fired boundary, so the batch revealing a crossing
-                # cannot leak its later arrivals into that snapshot
-                w_cols, w_ts, w_live = window.contents(wstate, now)
-                s2 = Scope()
-                s2.add_frame(frame_ref, w_cols, w_ts, w_live, default=True)
-                s2.extras["now"] = now
-                proj = {
-                    name: jnp.broadcast_to(
-                        jnp.asarray(ce(s2)), w_ts.shape)
-                    for name, ce in selector.out_exprs}
-                if selector.having is not None:
-                    h2 = Scope()
-                    h2.add_frame(frame_ref, w_cols, w_ts, w_live)
-                    h2.add_frame("__out__", proj, w_ts, w_live, default=True)
-                    h2.extras["now"] = now
-                    w_live = w_live & selector.having(h2)
-                cb = EventBatch(  # ts = ARRIVAL instants (boundary filter)
-                    ts=w_ts, cols=proj, valid=w_live,
-                    types=jnp.zeros(w_ts.shape, jnp.int8))
-                rstate, out = limiter.step_contents(rstate, cb, now)
-            else:
-                rstate, out = limiter.step(rstate, out, now)
+            with stage("selector"):
+                cscope = Scope()
+                cscope.add_frame(frame_ref, chunk.cols, chunk.ts, chunk.valid, default=True)
+                cscope.extras = dict(scope.extras)
+                chunk = apply_fns(post_fns, chunk, cscope)
+                for f in post_filters:
+                    chunk = chunk.where_valid(
+                        f(cscope) | (chunk.types != EventType.CURRENT))
+                if selector.extrema_plan:
+                    # removal-capable sliding min/max: range queries over the
+                    # window's arrival-order sequence (ops/extrema.py)
+                    from ..ops.extrema import (grouped_sliding_extrema_lanes,
+                                               sliding_extrema_lanes)
+                    from ..ops.windows import _unpack_rows
+                    ring_cols, ring_ts = _unpack_rows(wstate_pre.ring,
+                                                      window.layout)
+                    rscope = Scope()
+                    rscope.add_frame(
+                        frame_ref, ring_cols, ring_ts,
+                        jnp.ones(ring_ts.shape, bool), default=True)
+                    rscope.extras = dict(scope.extras)
+                    ghash = selector.extrema_group_hash
+                    for slot, eop, args in selector.extrema_plan:
+                        if ghash is not None:
+                            cscope.extras[f"extrema:{slot}"] = \
+                                grouped_sliding_extrema_lanes(
+                                    eop, args[0](rscope), ghash(rscope),
+                                    wstate_pre.expired, wstate_pre.appended,
+                                    chunk, args[0](cscope), ghash(cscope))
+                        else:
+                            cscope.extras[f"extrema:{slot}"] = \
+                                sliding_extrema_lanes(
+                                    eop, args[0](rscope), wstate_pre.expired,
+                                    wstate_pre.appended, chunk, args[0](cscope))
+                sstate, out = selector.step(sstate, chunk, cscope)
+            with stage("emit"):
+                if getattr(limiter, "needs_window_contents", False):
+                    # non-FIFO snapshot: per-arrival output is suppressed; ticks
+                    # re-project the window's live contents. POST-step state so
+                    # time-driven evictions (session close on this watermark)
+                    # apply; the limiter then drops rows whose arrival ts is
+                    # PAST the fired boundary, so the batch revealing a crossing
+                    # cannot leak its later arrivals into that snapshot
+                    w_cols, w_ts, w_live = window.contents(wstate, now)
+                    s2 = Scope()
+                    s2.add_frame(frame_ref, w_cols, w_ts, w_live, default=True)
+                    s2.extras["now"] = now
+                    proj = {
+                        name: jnp.broadcast_to(
+                            jnp.asarray(ce(s2)), w_ts.shape)
+                        for name, ce in selector.out_exprs}
+                    if selector.having is not None:
+                        h2 = Scope()
+                        h2.add_frame(frame_ref, w_cols, w_ts, w_live)
+                        h2.add_frame("__out__", proj, w_ts, w_live, default=True)
+                        h2.extras["now"] = now
+                        w_live = w_live & selector.having(h2)
+                    cb = EventBatch(  # ts = ARRIVAL instants (boundary filter)
+                        ts=w_ts, cols=proj, valid=w_live,
+                        types=jnp.zeros(w_ts.shape, jnp.int8))
+                    rstate, out = limiter.step_contents(rstate, cb, now)
+                else:
+                    rstate, out = limiter.step(rstate, out, now)
 
             return (wstate, sstate, rstate), out
 

@@ -23,7 +23,7 @@ from typing import Callable, Optional, Sequence
 import numpy as np
 
 from ..errors import SiddhiAppCreationError, SiddhiAppRuntimeError
-from ..telemetry.tracing import Span, StageCells
+from ..telemetry.tracing import Span, StageCells, stage
 from ..util.locks import named_condition, named_lock, note_blocking
 from ..query_api.definition import AttributeType, StreamDefinition
 from . import dtypes
@@ -183,13 +183,15 @@ def _wire_pack(batch: EventBatch):
     directly attached chip.) `over` flags a >49-day timestamp span (then
     the fetch worker re-reads the raw batch instead)."""
     import jax.numpy as jnp
-    big = jnp.int64(1) << jnp.int64(62)
-    ts0 = jnp.min(jnp.where(batch.valid, batch.ts, big))
-    ts0 = jnp.where(ts0 == big, jnp.int64(0), ts0)
-    dts = jnp.where(batch.valid, batch.ts - ts0, 0)
-    over = jnp.any(dts > jnp.int64(0xFFFFFFFF)) | jnp.any(dts < 0)
-    flags = (batch.types.astype(jnp.uint8) << 1) | batch.valid.astype(jnp.uint8)
-    return ts0, dts.astype(jnp.uint32), flags, batch.cols, over
+    with stage("emit"):
+        big = jnp.int64(1) << jnp.int64(62)
+        ts0 = jnp.min(jnp.where(batch.valid, batch.ts, big))
+        ts0 = jnp.where(ts0 == big, jnp.int64(0), ts0)
+        dts = jnp.where(batch.valid, batch.ts - ts0, 0)
+        over = jnp.any(dts > jnp.int64(0xFFFFFFFF)) | jnp.any(dts < 0)
+        flags = (batch.types.astype(jnp.uint8) << 1) \
+            | batch.valid.astype(jnp.uint8)
+        return ts0, dts.astype(jnp.uint32), flags, batch.cols, over
 
 
 _wire_pack_jit = None

@@ -33,6 +33,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from ..telemetry.tracing import stage
 from .lanes import gather_lanes, scatter_lanes
 from .search import searchsorted32, stable_argsort_bounded
 
@@ -88,27 +89,32 @@ def grouped_scan(
     plan = _segment_plan(
         slots, valid, resets, current_epoch, K,
         (jnp.where(valid, deltas, jnp.full_like(deltas, identity)),))
-    within = _segmented_scan(plan.s_cols[0], plan.seg_start, combine,
-                             identity)
+    with stage("selector/scan"):
+        within = _segmented_scan(plan.s_cols[0], plan.seg_start, combine,
+                                 identity)
 
     # carry-in: only the segment whose epoch matches the state's stored epoch
     # for that slot gets the stored value; stale epochs read the identity.
-    stored_vals, stored_epoch = gather_lanes(
-        (state.values, state.epoch), plan.safe_slots)
-    carry = jnp.where(
-        plan.epoch_ok_slots & (stored_epoch == plan.s_epochs), stored_vals,
-        jnp.full_like(stored_vals, identity))
-    carry_seg, = gather_lanes((carry,), plan.start_idx)
+    with stage("selector/gather"):
+        stored_vals, stored_epoch = gather_lanes(
+            (state.values, state.epoch), plan.safe_slots)
+        carry = jnp.where(
+            plan.epoch_ok_slots & (stored_epoch == plan.s_epochs),
+            stored_vals, jnp.full_like(stored_vals, identity))
+        carry_seg, = gather_lanes((carry,), plan.start_idx)
 
-    s_out = combine(carry_seg, within)
-    new_values = scatter_lanes(state.values, plan.write_slot,
-                               s_out.astype(state.values.dtype))
-    new_epoch = state.epoch.at[plan.write_slot].set(
-        plan.s_epochs.astype(state.epoch.dtype), mode="drop")
-    # scatter back to lane order (an inverse permutation and a packed gather
-    # cost the same on the chip: PERF.md, PR 33). `order` is a permutation:
-    # no two lanes write one place, so an 8-byte column's words may go apart
-    out = scatter_lanes(jnp.zeros_like(s_out), plan.order, s_out)
+    with stage("selector/scan"):
+        s_out = combine(carry_seg, within)
+    with stage("selector/scatter"):
+        new_values = scatter_lanes(state.values, plan.write_slot,
+                                   s_out.astype(state.values.dtype))
+        new_epoch = state.epoch.at[plan.write_slot].set(
+            plan.s_epochs.astype(state.epoch.dtype), mode="drop")
+        # scatter back to lane order (an inverse permutation and a packed
+        # gather cost the same on the chip: PERF.md, PR 33). `order` is a
+        # permutation: no two lanes write one place, so an 8-byte column's
+        # words may go apart
+        out = scatter_lanes(jnp.zeros_like(s_out), plan.order, s_out)
     return GroupState(new_values, new_epoch), out
 
 
@@ -134,39 +140,43 @@ class _SegmentPlan(NamedTuple):
 def _segment_plan(slots, valid, resets, current_epoch, K,
                   cols: tuple = ()) -> _SegmentPlan:
     sentinel = jnp.int32(K)
-    slots_v = jnp.where(valid, slots, sentinel)
+    with stage("selector/sort"):
+        slots_v = jnp.where(valid, slots, sentinel)
 
-    # epoch id per lane: lanes after the r-th reset belong to epoch
-    # current_epoch + r. cumsum of resets gives r per lane (reset lane itself
-    # starts the new epoch).
-    reset_rank = jnp.cumsum(resets.astype(jnp.int32))
-    lane_epoch = current_epoch + reset_rank
+        # epoch id per lane: lanes after the r-th reset belong to epoch
+        # current_epoch + r. cumsum of resets gives r per lane (reset lane
+        # itself starts the new epoch).
+        reset_rank = jnp.cumsum(resets.astype(jnp.int32))
+        lane_epoch = current_epoch + reset_rank
 
-    # stable sort by (slot, lane) — lane order inside a slot is preserved.
-    # slots_v is non-negative (< K+1), as stable_argsort_bounded requires
-    order = stable_argsort_bounded(slots_v)
-    s_slots, s_epochs, *s_cols = gather_lanes(
-        (slots_v, lane_epoch, *cols), order)
+        # stable sort by (slot, lane) — lane order inside a slot is preserved.
+        # slots_v is non-negative (< K+1), as stable_argsort_bounded requires
+        order = stable_argsort_bounded(slots_v)
+    with stage("selector/gather"):
+        s_slots, s_epochs, *s_cols = gather_lanes(
+            (slots_v, lane_epoch, *cols), order)
 
-    # a new segment starts when slot changes OR lane epoch changes
-    prev_slot = jnp.concatenate([jnp.full((1,), -1, s_slots.dtype), s_slots[:-1]])
-    prev_epoch = jnp.concatenate([jnp.full((1,), -1, s_epochs.dtype), s_epochs[:-1]])
-    seg_start = (s_slots != prev_slot) | (s_epochs != prev_epoch)
+    with stage("selector/scan"):
+        # a new segment starts when slot changes OR lane epoch changes
+        prev_slot = jnp.concatenate([jnp.full((1,), -1, s_slots.dtype), s_slots[:-1]])
+        prev_epoch = jnp.concatenate([jnp.full((1,), -1, s_epochs.dtype), s_epochs[:-1]])
+        seg_start = (s_slots != prev_slot) | (s_epochs != prev_epoch)
 
-    safe_slots = jnp.minimum(s_slots, K - 1)
+        safe_slots = jnp.minimum(s_slots, K - 1)
 
-    # state writes come from the last lane of each *slot* run, every other
-    # lane writes the out-of-bounds sentinel and is dropped: NO TWO IN-BOUNDS
-    # ENTRIES OF write_slot ARE EQUAL (last epoch's value wins), which is
-    # what lets scatter_lanes send an 8-byte table's words apart
-    next_slot = jnp.concatenate([s_slots[1:], jnp.full((1,), -1, s_slots.dtype)])
-    is_slot_end = s_slots != next_slot
-    write_slot = jnp.where((s_slots < K) & is_slot_end, s_slots, sentinel)
+        # state writes come from the last lane of each *slot* run, every
+        # other lane writes the out-of-bounds sentinel and is dropped: NO TWO
+        # IN-BOUNDS ENTRIES OF write_slot ARE EQUAL (last epoch's value
+        # wins), which is what lets scatter_lanes send an 8-byte table's
+        # words apart
+        next_slot = jnp.concatenate([s_slots[1:], jnp.full((1,), -1, s_slots.dtype)])
+        is_slot_end = s_slots != next_slot
+        write_slot = jnp.where((s_slots < K) & is_slot_end, s_slots, sentinel)
 
-    L = s_slots.shape[0]
-    idx = jnp.arange(L, dtype=jnp.int32)
-    start_idx = jax.lax.associative_scan(
-        jnp.maximum, jnp.where(seg_start, idx, 0))
+        L = s_slots.shape[0]
+        idx = jnp.arange(L, dtype=jnp.int32)
+        start_idx = jax.lax.associative_scan(
+            jnp.maximum, jnp.where(seg_start, idx, 0))
 
     return _SegmentPlan(order, s_slots, s_epochs, tuple(s_cols), seg_start,
                         safe_slots, s_slots < K, write_slot, start_idx)
@@ -193,24 +203,29 @@ def grouped_scan_fused(
         slots, valid, resets, current_epoch, K,
         tuple(jnp.where(valid, d, jnp.zeros((), d.dtype))
               for d in deltas_list))
-    stored_epoch, *stored_vals = gather_lanes(
-        (shared_epoch, *values_list), plan.safe_slots)
-    epoch_live = plan.epoch_ok_slots & (stored_epoch == plan.s_epochs)
-    carries = gather_lanes(
-        [jnp.where(epoch_live, sv, jnp.zeros_like(sv)) for sv in stored_vals],
-        plan.start_idx)
+    with stage("selector/gather"):
+        stored_epoch, *stored_vals = gather_lanes(
+            (shared_epoch, *values_list), plan.safe_slots)
+        epoch_live = plan.epoch_ok_slots & (stored_epoch == plan.s_epochs)
+        carries = gather_lanes(
+            [jnp.where(epoch_live, sv, jnp.zeros_like(sv))
+             for sv in stored_vals], plan.start_idx)
     s_outs = []
-    for values, sd, carry_seg in zip(values_list, plan.s_cols, carries):
-        within = _segmented_scan(sd, plan.seg_start, lambda a, b: a + b,
-                                 jnp.zeros((), sd.dtype))
-        s_outs.append(carry_seg + within.astype(values.dtype))
-    new_values = [scatter_lanes(values, plan.write_slot, s_out)
-                  for values, s_out in zip(values_list, s_outs)]
-    new_epoch = shared_epoch.at[plan.write_slot].set(
-        plan.s_epochs.astype(shared_epoch.dtype), mode="drop")
-    # ONE scatter builds the inverse, one row gather brings every
-    # component back to lane order
-    outs = gather_lanes(s_outs, invert_permutation(plan.order))
+    with stage("selector/scan"):
+        for values, sd, carry_seg in zip(values_list, plan.s_cols, carries):
+            within = _segmented_scan(sd, plan.seg_start, lambda a, b: a + b,
+                                     jnp.zeros((), sd.dtype))
+            s_outs.append(carry_seg + within.astype(values.dtype))
+    with stage("selector/scatter"):
+        new_values = [scatter_lanes(values, plan.write_slot, s_out)
+                      for values, s_out in zip(values_list, s_outs)]
+        new_epoch = shared_epoch.at[plan.write_slot].set(
+            plan.s_epochs.astype(shared_epoch.dtype), mode="drop")
+        # ONE scatter builds the inverse, one row gather brings every
+        # component back to lane order
+        back = invert_permutation(plan.order)
+    with stage("selector/gather"):
+        outs = gather_lanes(s_outs, back)
     return new_values, new_epoch, outs
 
 
@@ -228,22 +243,27 @@ def ungrouped_scan(
     boundaries plus one scalar state cell. Semantics identical to
     grouped_scan with all-zero slots."""
     combine, identity = _OPS[op](deltas.dtype)
-    reset_rank = jnp.cumsum(resets.astype(jnp.int32))
-    lane_epoch = current_epoch + reset_rank
-    seg_start = jnp.concatenate(
-        [jnp.ones((1,), bool), lane_epoch[1:] != lane_epoch[:-1]])
-    s_deltas = jnp.where(valid, deltas, jnp.full_like(deltas, identity))
-    within = _segmented_scan(s_deltas, seg_start, combine, identity)
-    stored = state.values[0]
-    # a segment is a run of one lane epoch and the carry depends on nothing
-    # else, so every lane already holds its segment's carry: no broadcast
-    # from the segment's start (an emulated-int64 gather a step on the TPU)
-    carry_seg = jnp.where(state.epoch[0] == lane_epoch, stored,
-                          jnp.full_like(stored, identity))
-    s_out = combine(carry_seg, within)
-    new_state = GroupState(
-        values=state.values.at[0].set(s_out[-1].astype(state.values.dtype)),
-        epoch=state.epoch.at[0].set(lane_epoch[-1].astype(state.epoch.dtype)))
+    with stage("selector/scan"):
+        reset_rank = jnp.cumsum(resets.astype(jnp.int32))
+        lane_epoch = current_epoch + reset_rank
+        seg_start = jnp.concatenate(
+            [jnp.ones((1,), bool), lane_epoch[1:] != lane_epoch[:-1]])
+        s_deltas = jnp.where(valid, deltas, jnp.full_like(deltas, identity))
+        within = _segmented_scan(s_deltas, seg_start, combine, identity)
+        stored = state.values[0]
+        # a segment is a run of one lane epoch and the carry depends on
+        # nothing else, so every lane already holds its segment's carry: no
+        # broadcast from the segment's start (an emulated-int64 gather a step
+        # on the TPU)
+        carry_seg = jnp.where(state.epoch[0] == lane_epoch, stored,
+                              jnp.full_like(stored, identity))
+        s_out = combine(carry_seg, within)
+    with stage("selector/scatter"):
+        new_state = GroupState(
+            values=state.values.at[0].set(
+                s_out[-1].astype(state.values.dtype)),
+            epoch=state.epoch.at[0].set(
+                lane_epoch[-1].astype(state.epoch.dtype)))
     return new_state, s_out
 
 
@@ -257,24 +277,30 @@ def ungrouped_scan_fused(
 ) -> tuple[list, jax.Array, list]:
     """`grouped_scan_fused` without GROUP BY: shared reset segmentation, no
     sort, scalar state cells."""
-    reset_rank = jnp.cumsum(resets.astype(jnp.int32))
-    lane_epoch = current_epoch + reset_rank
-    seg_start = jnp.concatenate(
-        [jnp.ones((1,), bool), lane_epoch[1:] != lane_epoch[:-1]])
-    epoch_ok = shared_epoch[0] == lane_epoch
+    with stage("selector/scan"):
+        reset_rank = jnp.cumsum(resets.astype(jnp.int32))
+        lane_epoch = current_epoch + reset_rank
+        seg_start = jnp.concatenate(
+            [jnp.ones((1,), bool), lane_epoch[1:] != lane_epoch[:-1]])
+        epoch_ok = shared_epoch[0] == lane_epoch
     new_values, outs = [], []
     for values, deltas in zip(values_list, deltas_list):
         combine, identity = _OPS["sum"](deltas.dtype)
-        s_deltas = jnp.where(valid, deltas, jnp.full_like(deltas, identity))
-        within = _segmented_scan(s_deltas, seg_start, combine, identity)
-        # per lane, as in ungrouped_scan: the carry follows the lane epoch
-        carry_seg = jnp.where(epoch_ok, values[0],
-                              jnp.full_like(values[0], identity))
-        s_out = combine(carry_seg, within)
-        new_values.append(values.at[0].set(s_out[-1].astype(values.dtype)))
+        with stage("selector/scan"):
+            s_deltas = jnp.where(valid, deltas,
+                                 jnp.full_like(deltas, identity))
+            within = _segmented_scan(s_deltas, seg_start, combine, identity)
+            # per lane, as in ungrouped_scan: the carry follows the lane epoch
+            carry_seg = jnp.where(epoch_ok, values[0],
+                                  jnp.full_like(values[0], identity))
+            s_out = combine(carry_seg, within)
+        with stage("selector/scatter"):
+            new_values.append(
+                values.at[0].set(s_out[-1].astype(values.dtype)))
         outs.append(s_out)
-    new_epoch = shared_epoch.at[0].set(lane_epoch[-1].astype(
-        shared_epoch.dtype))
+    with stage("selector/scatter"):
+        new_epoch = shared_epoch.at[0].set(lane_epoch[-1].astype(
+            shared_epoch.dtype))
     return new_values, new_epoch, outs
 
 

@@ -36,6 +36,7 @@ from ..core import dtypes
 from .search import searchsorted32, stable_partition_order
 from ..core.event import EventBatch, EventType
 from ..errors import SiddhiAppCreationError
+from ..telemetry.tracing import stage
 
 # emission-key kinds: expired lanes sort before reset before current at the
 # same trigger position (matches reference chunk insertion order).
@@ -658,7 +659,8 @@ class SlidingWindow(WindowOp):
         # dispatch): every lane-count shape below derives from it, so one
         # window instance serves the whole bucket ladder (one trace per rung)
         B, E = batch.capacity, self.E
-        comp_mat, n_valid32 = compact_packed(batch, self.layout)
+        with stage("window/append"):
+            comp_mat, n_valid32 = compact_packed(batch, self.layout)
         n_valid = n_valid32.astype(jnp.int64)
 
         if self.ts_attr is not None:
@@ -682,121 +684,125 @@ class SlidingWindow(WindowOp):
         pe = jnp.arange(E, dtype=jnp.int32)
         win_len0 = (state.appended - state.expired).astype(jnp.int32)
         win_len1 = win_len0 + n_valid32
-        cand_mat = _fetch_rel_packed(
-            state.ring, comp_mat, state.expired, state.appended, E)
+        with stage("window/fetch"):
+            cand_mat = _fetch_rel_packed(
+                state.ring, comp_mat, state.expired, state.appended, E)
 
-        # Every rule below says how many candidates have left, in FIFO
-        # order, by the time arrival i is appended (`pops`, nondecreasing in
-        # i) and by the end of the step (`n_exp`); the rest is shared.
-        wm, deferred = state.wm, state.deferred
-        if self.time_ms is not None:
-            # a candidate leaves at the first arrival whose clock reaches the
-            # running maximum of the deadlines up to it, before that arrival
-            # is counted (ties: expire first); never before it has arrived
-            # itself; at the step's end if only the final clock covers it.
-            own = _packed_ts(cand_mat) + jnp.int64(self.time_ms)
-            deadline = _cummax(jnp.where(pe < win_len1, own, BIG))
-            lane_clock = _cummax(jnp.where(cur_valid, comp_ts, -BIG))
-            tracked = self.ts_attr is not None or self.playback
-            if tracked:
-                lane_clock = jnp.maximum(lane_clock, state.wm)
-                # the allowed lateness holds the step's last clock back, so
-                # that panes close only once the ingress gate can release no
-                # more rows into them; a timer batch brings its own clock
-                end = lane_clock[-1] - jnp.int64(self.lateness_ms)
-                if self.ts_attr is None:
-                    end = jnp.where(n_valid32 > 0, end, now)
-                end = jnp.maximum(state.wm, end)
+        with stage("window/expire"):
+            # Every rule below says how many candidates have left, in FIFO
+            # order, by the time arrival i is appended (`pops`, nondecreasing in
+            # i) and by the end of the step (`n_exp`); the rest is shared.
+            wm, deferred = state.wm, state.deferred
+            if self.time_ms is not None:
+                # a candidate leaves at the first arrival whose clock reaches the
+                # running maximum of the deadlines up to it, before that arrival
+                # is counted (ties: expire first); never before it has arrived
+                # itself; at the step's end if only the final clock covers it.
+                own = _packed_ts(cand_mat) + jnp.int64(self.time_ms)
+                deadline = _cummax(jnp.where(pe < win_len1, own, BIG))
+                lane_clock = _cummax(jnp.where(cur_valid, comp_ts, -BIG))
+                tracked = self.ts_attr is not None or self.playback
+                if tracked:
+                    lane_clock = jnp.maximum(lane_clock, state.wm)
+                    # the allowed lateness holds the step's last clock back, so
+                    # that panes close only once the ingress gate can release no
+                    # more rows into them; a timer batch brings its own clock
+                    end = lane_clock[-1] - jnp.int64(self.lateness_ms)
+                    if self.ts_attr is None:
+                        end = jnp.where(n_valid32 > 0, end, now)
+                    end = jnp.maximum(state.wm, end)
+                else:
+                    end = now
+                n_time = jnp.minimum(jnp.sum(deadline <= end, dtype=jnp.int32),
+                                     win_len1)
+                if tracked and self.ts_attr is None:
+                    # a data batch under playback ends with its last arrival's
+                    # own pops, and that arrival stays: upstream looks at the
+                    # head again only at the next event
+                    n_time = jnp.minimum(
+                        n_time, win_len1 - (n_valid32 > 0).astype(jnp.int32))
+                pops_time = jnp.minimum(
+                    searchsorted32(deadline, lane_clock, side="right"),
+                    jnp.minimum(win_len0 + p, n_time))
+                # rows the step before should have let go and could not: E ran
+                # out (its newest row may stand, as above)
+                deferred = deferred + jnp.sum(
+                    (deadline <= state.wm) & (pe < win_len0 - 1), dtype=jnp.int64)
+                wm = jnp.maximum(state.wm, end)
+            if self.length is not None:
+                # length(N): candidate o is evicted by the arrival with overall
+                # index o + N (the N+1'th event), all relative -> int32
+                rel = (state.expired + jnp.int64(self.length)
+                       - state.appended).astype(jnp.int32)
+                pops_len = jnp.clip(p + 1 - rel, 0, jnp.minimum(E, win_len1))
+                n_len = jnp.clip(n_valid32 - rel, 0, jnp.minimum(E, win_len1))
+            if self.time_ms is None:
+                pops, n_exp = pops_len, n_len
+            elif self.length is None:
+                pops, n_exp = pops_time, n_time
             else:
-                end = now
-            n_time = jnp.minimum(jnp.sum(deadline <= end, dtype=jnp.int32),
-                                 win_len1)
-            if tracked and self.ts_attr is None:
-                # a data batch under playback ends with its last arrival's
-                # own pops, and that arrival stays: upstream looks at the
-                # head again only at the next event
-                n_time = jnp.minimum(
-                    n_time, win_len1 - (n_valid32 > 0).astype(jnp.int32))
-            pops_time = jnp.minimum(
-                searchsorted32(deadline, lane_clock, side="right"),
-                jnp.minimum(win_len0 + p, n_time))
-            # rows the step before should have let go and could not: E ran
-            # out (its newest row may stand, as above)
-            deferred = deferred + jnp.sum(
-                (deadline <= state.wm) & (pe < win_len0 - 1), dtype=jnp.int64)
-            wm = jnp.maximum(state.wm, end)
-        if self.length is not None:
-            # length(N): candidate o is evicted by the arrival with overall
-            # index o + N (the N+1'th event), all relative -> int32
-            rel = (state.expired + jnp.int64(self.length)
-                   - state.appended).astype(jnp.int32)
-            pops_len = jnp.clip(p + 1 - rel, 0, jnp.minimum(E, win_len1))
-            n_len = jnp.clip(n_valid32 - rel, 0, jnp.minimum(E, win_len1))
-        if self.time_ms is None:
-            pops, n_exp = pops_len, n_len
-        elif self.length is None:
-            pops, n_exp = pops_time, n_time
-        else:
-            # timeLength(W, N): whichever rule fires first
-            pops = jnp.maximum(pops_time, pops_len)
-            n_exp = jnp.maximum(n_time, n_len)
-        pops = jnp.where(cur_valid, jnp.minimum(pops, n_exp), n_exp)
-        expires = pe < n_exp
-        # trigger position of candidate j: the arrivals that did not see it
-        # leave, counted by a histogram of `pops` (sorted indices) and a
-        # prefix sum; n_valid where only the step's end lets it go
-        trig = jnp.cumsum(jnp.zeros((E + 1,), jnp.int32).at[pops].add(
-            1, indices_are_sorted=True))[:E]
-        trig = jnp.minimum(trig, n_valid32)
+                # timeLength(W, N): whichever rule fires first
+                pops = jnp.maximum(pops_time, pops_len)
+                n_exp = jnp.maximum(n_time, n_len)
+            pops = jnp.where(cur_valid, jnp.minimum(pops, n_exp), n_exp)
+            expires = pe < n_exp
+            # trigger position of candidate j: the arrivals that did not see it
+            # leave, counted by a histogram of `pops` (sorted indices) and a
+            # prefix sum; n_valid where only the step's end lets it go
+            trig = jnp.cumsum(jnp.zeros((E + 1,), jnp.int32).at[pops].add(
+                1, indices_are_sorted=True))[:E]
+            trig = jnp.minimum(trig, n_valid32)
 
-        # stamps of the expired lanes
-        safe_trig = jnp.clip(trig, 0, B - 1)
-        if self.time_ms is None:
-            # reference stamps evicted events with current time
-            # (LengthWindowProcessor.java:121)
-            emit_ts = comp_ts[safe_trig]
-        elif self.length is None:
-            emit_ts = own
-        else:
-            by_time = (trig >= n_valid32) | (pops_time[safe_trig] > pe)
-            emit_ts = jnp.where(by_time, own, comp_ts[safe_trig])
+            # stamps of the expired lanes
+            safe_trig = jnp.clip(trig, 0, B - 1)
+            if self.time_ms is None:
+                # reference stamps evicted events with current time
+                # (LengthWindowProcessor.java:121)
+                emit_ts = comp_ts[safe_trig]
+            elif self.length is None:
+                emit_ts = own
+            else:
+                by_time = (trig >= n_valid32) | (pops_time[safe_trig] > pe)
+                emit_ts = jnp.where(by_time, own, comp_ts[safe_trig])
 
-        # ---- assemble chunk: E expired lanes + B current lanes ----
-        # emission order: candidate j goes out before the arrival that sees
-        # it leave, so its rank is j + trig[j] and arrival i's is i + pops[i];
-        # lanes that emit nothing follow in concatenation order
-        all_mat = jnp.concatenate([cand_mat, comp_mat], axis=1)
-        all_emit = jnp.concatenate([emit_ts, comp_ts])
-        all_types = jnp.concatenate([
-            jnp.full((E,), EventType.EXPIRED, jnp.int8),
-            jnp.full((B,), EventType.CURRENT, jnp.int8),
-        ])
-        if self.is_delay:
-            # delay(W): expired lanes are re-emitted as CURRENT after the
-            # delay; arrivals are swallowed (reference DelayWindowProcessor).
-            all_types = jnp.full((E + B,), EventType.CURRENT, jnp.int8)
-            cur_out = jnp.zeros((B,), bool)
-            n_cur, lead = jnp.int32(0), jnp.zeros((E,), jnp.int32)
-        else:
-            cur_out, n_cur, lead = cur_valid, n_valid32, trig
-        total = n_exp + n_cur
-        rank_exp = jnp.where(expires, pe + lead, total + pe - n_exp)
-        rank_cur = jnp.where(cur_out, p + pops,
-                             total + (E - n_exp) + p - n_cur)
-        order = jnp.zeros((E + B,), jnp.int32).at[rank_exp].set(pe) \
-            .at[rank_cur].set(E + p)
-        chunk = _gather_chunk_packed(
-            order, all_mat, all_emit, jnp.concatenate([expires, cur_out]),
-            all_types, self.layout)
+            # ---- assemble chunk: E expired lanes + B current lanes ----
+            # emission order: candidate j goes out before the arrival that sees
+            # it leave, so its rank is j + trig[j] and arrival i's is i + pops[i];
+            # lanes that emit nothing follow in concatenation order
+            all_mat = jnp.concatenate([cand_mat, comp_mat], axis=1)
+            all_emit = jnp.concatenate([emit_ts, comp_ts])
+            all_types = jnp.concatenate([
+                jnp.full((E,), EventType.EXPIRED, jnp.int8),
+                jnp.full((B,), EventType.CURRENT, jnp.int8),
+            ])
+            if self.is_delay:
+                # delay(W): expired lanes are re-emitted as CURRENT after the
+                # delay; arrivals are swallowed (reference DelayWindowProcessor).
+                all_types = jnp.full((E + B,), EventType.CURRENT, jnp.int8)
+                cur_out = jnp.zeros((B,), bool)
+                n_cur, lead = jnp.int32(0), jnp.zeros((E,), jnp.int32)
+            else:
+                cur_out, n_cur, lead = cur_valid, n_valid32, trig
+            total = n_exp + n_cur
+            rank_exp = jnp.where(expires, pe + lead, total + pe - n_exp)
+            rank_cur = jnp.where(cur_out, p + pops,
+                                 total + (E - n_exp) + p - n_cur)
+            order = jnp.zeros((E + B,), jnp.int32).at[rank_exp].set(pe) \
+                .at[rank_cur].set(E + p)
+        with stage("window/fetch"):
+            chunk = _gather_chunk_packed(
+                order, all_mat, all_emit, jnp.concatenate([expires, cur_out]),
+                all_types, self.layout)
 
         # ---- ring update ----
         # in place, after every read of the old ring. XLA's TPU pipeline
         # orders the two itself; its CPU pipeline copies the whole ring
         # unless the write DEPENDS on the read, so it is made to, by a
         # no-op: n_valid32 <= B always, and `held` is 0 or 1
-        held = (cand_mat[0, 0] >> 31).astype(jnp.int32)
-        new_ring = _append_packed(state.ring, comp_mat, state.appended,
-                                  jnp.minimum(n_valid32, B + held))
+        with stage("window/append"):
+            held = (cand_mat[0, 0] >> 31).astype(jnp.int32)
+            new_ring = _append_packed(state.ring, comp_mat, state.appended,
+                                      jnp.minimum(n_valid32, B + held))
 
         # live rows overwritten by ring wrap (a time window holding more
         # than C un-expired rows): new excess this step, monotone
@@ -885,7 +891,8 @@ class LengthBatchWindow(WindowOp):
     def step(self, state: LengthBatchState, batch: EventBatch,
              now: jax.Array):
         B, N, L = self.B, self.N, self.chunk_width
-        comp_mat, n_valid32 = compact_packed(batch, self.layout)
+        with stage("window/append"):
+            comp_mat, n_valid32 = compact_packed(batch, self.layout)
         appended1 = state.appended + n_valid32.astype(jnp.int64)
 
         # Invariant: state.flushed is a multiple of N, so everything per lane
@@ -898,36 +905,40 @@ class LengthBatchWindow(WindowOp):
         # expired lanes are emitted) by the previous flush. B + N + lead <= C.
         lead = N if self.expired_on else 0
         E = B + N + lead
-        rows = _fetch_rel_packed(state.ring, comp_mat, state.flushed - lead,
-                                 state.appended, E)
+        with stage("window/fetch"):
+            rows = _fetch_rel_packed(state.ring, comp_mat, state.flushed - lead,
+                                     state.appended, E)
 
-        # output lane j -> (flush blk, position r within its block). A block
-        # is [lead expired lanes, RESET, N currents]; the first flush ever
-        # has no previous flush to expire, so its block starts at the RESET.
-        blk_w = lead + 1 + N
-        skip = jnp.where(state.flushed == 0, jnp.int32(lead), jnp.int32(0))
-        j = jnp.arange(L, dtype=jnp.int32) + skip
-        blk, r = j // blk_w, j % blk_w
-        is_reset = r == lead
-        is_cur = r > lead
-        # row of `rows`: expired lane r of flush blk is event r of flush
-        # blk - 1; current lane is event r - lead - 1 of flush blk; a RESET
-        # reads the zero row appended at E
-        src = jnp.where(is_reset, E,
-                        jnp.clip(blk * N + r - is_cur.astype(jnp.int32),
-                                 0, E - 1))
-        out = jnp.concatenate(
-            [rows, jnp.zeros((self.W, 1), jnp.uint32)], axis=1)[:, src]
-        cols, own_ts = _unpack_rows(out, self.layout)
+        with stage("window/expire"):
+            # output lane j -> (flush blk, position r within its block). A block
+            # is [lead expired lanes, RESET, N currents]; the first flush ever
+            # has no previous flush to expire, so its block starts at the RESET.
+            blk_w = lead + 1 + N
+            skip = jnp.where(state.flushed == 0, jnp.int32(lead), jnp.int32(0))
+            j = jnp.arange(L, dtype=jnp.int32) + skip
+            blk, r = j // blk_w, j % blk_w
+            is_reset = r == lead
+            is_cur = r > lead
+            # row of `rows`: expired lane r of flush blk is event r of flush
+            # blk - 1; current lane is event r - lead - 1 of flush blk; a RESET
+            # reads the zero row appended at E
+            src = jnp.where(is_reset, E,
+                            jnp.clip(blk * N + r - is_cur.astype(jnp.int32),
+                                     0, E - 1))
+        with stage("window/fetch"):
+            out = jnp.concatenate(
+                [rows, jnp.zeros((self.W, 1), jnp.uint32)], axis=1)[:, src]
+            cols, own_ts = _unpack_rows(out, self.layout)
 
-        # RESET and expired lanes are stamped with the arrival completing
-        # their flush (the reference re-stamps with current time): the last
-        # current of each block — a strided slice of B//N + 1 stamps (no
-        # more flushes can complete), each repeated over its block
-        flush_ts = jnp.repeat(_packed_ts(rows[:, lead + N - 1::N]), blk_w)
-        flush_ts = jax.lax.dynamic_slice(
-            jnp.pad(flush_ts, (0, max(L + lead - flush_ts.shape[0], 0))),
-            (skip,), (L,))
+        with stage("window/expire"):
+            # RESET and expired lanes are stamped with the arrival completing
+            # their flush (the reference re-stamps with current time): the last
+            # current of each block — a strided slice of B//N + 1 stamps (no
+            # more flushes can complete), each repeated over its block
+            flush_ts = jnp.repeat(_packed_ts(rows[:, lead + N - 1::N]), blk_w)
+            flush_ts = jax.lax.dynamic_slice(
+                jnp.pad(flush_ts, (0, max(L + lead - flush_ts.shape[0], 0))),
+                (skip,), (L,))
 
         chunk = EventBatch(
             ts=jnp.where(is_cur, own_ts, flush_ts),
@@ -939,12 +950,13 @@ class LengthBatchWindow(WindowOp):
                           jnp.int8(EventType.EXPIRED))),
         )
 
-        new_state = LengthBatchState(
-            ring=_append_packed(state.ring, comp_mat, state.appended,
-                                n_valid32),
-            appended=appended1,
-            flushed=state.flushed + (nf * N).astype(jnp.int64),
-        )
+        with stage("window/append"):
+            new_state = LengthBatchState(
+                ring=_append_packed(state.ring, comp_mat, state.appended,
+                                    n_valid32),
+                appended=appended1,
+                flushed=state.flushed + (nf * N).astype(jnp.int64),
+            )
         return new_state, chunk
 
     def contents(self, state: LengthBatchState, now: jax.Array):

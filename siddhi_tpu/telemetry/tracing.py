@@ -48,6 +48,19 @@ any profiler session (SIDDHI_PROFILE, a benchmark's slice) it lies in
 entered per frame, per worker run or per batch, never per row. The cells
 are what `statistics_report()` shows as `ingress_pipeline.<stream>.stage_ms`
 and `readback.stage_ms`.
+
+Step stages (`STEP_STAGES`, `stage`): the device half. A step program's
+body is cut into named stages with `with stage("selector"):`, which is
+`jax.named_scope("siddhi.selector")` and nothing else: the name goes into
+the `op_name` metadata of every HLO operation traced inside it
+(`jit(step)/siddhi.selector/siddhi.selector/sort/sort`), so a profiler
+session shows it on each device op and `benchmarks/stages.py` sums device
+time by it. It exists at trace time only: nothing runs per batch, the
+lowered module is the same module (a scope is debug location, and jax
+strips that from the compile cache's key), and an executable loaded from a
+cache that an unscoped build wrote carries no scope until that cache is
+rebuilt (`docs/OBSERVABILITY.md`). One vocabulary for every step program,
+two levels at most; an undeclared name raises where the step is traced.
 """
 
 from __future__ import annotations
@@ -59,6 +72,7 @@ import time
 from collections import deque
 from typing import Optional
 
+import jax
 from jax.profiler import TraceAnnotation
 
 from ..util.locks import named_lock
@@ -68,6 +82,36 @@ from .metrics import Histogram, MetricsRegistry, bucket_index
 SLOW_RING = 8
 #: recent-completion ring size (test/debug surface)
 RECENT_RING = 64
+
+#: every stage a step program may name; `a/b` is a part of `a`
+STEP_STAGES = (
+    "filter",
+    "window", "window/append", "window/expire", "window/fetch",
+    "selector", "selector/sort", "selector/gather", "selector/scan",
+    "selector/scatter",
+    "emit",
+    # the join's probe programs
+    "probe", "compact", "frames",
+    # the pattern's step programs
+    "append", "match", "match/expire",
+)
+#: the top-level stages of each family of step program, in program order
+STEP_FAMILIES = {
+    "query": ("filter", "window", "selector", "emit"),
+    "join": ("filter", "window", "probe", "compact", "frames", "selector",
+             "emit"),
+    "pattern": ("filter", "append", "match", "frames", "selector", "emit"),
+}
+STAGE_PREFIX = "siddhi."
+
+
+def stage(name: str):
+    """The scope of one declared stage of a step program: a context manager
+    for trace time (`jax.named_scope`), no computation."""
+    if name not in STEP_STAGES:
+        raise ValueError(f"undeclared step stage {name!r}: STEP_STAGES in "
+                         "telemetry/tracing.py names them all")
+    return jax.named_scope(STAGE_PREFIX + name)
 
 
 class Span:

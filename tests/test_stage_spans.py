@@ -293,13 +293,24 @@ def test_spans_lie_on_the_profilers_clock(tmp_path):
                         (ev.start_ns, ev.start_ns + ev.duration_ns,
                          dict(ev.stats)))
     for name in ("siddhi.front.wire", "siddhi.ingress.claim_wait",
-                 "siddhi.ingress.worker_run", "siddhi.ingress.ticket_wait",
+                 "siddhi.ingress.ticket_wait",
                  "siddhi.ingress.intern_lock_wait", "siddhi.ingress.intern",
                  "siddhi.feeder.fill",
                  "siddhi.feeder.h2d", "siddhi.feeder.lock_wait",
                  "siddhi.feeder.dispatch", "siddhi.readback.submit",
                  "siddhi.readback.fetch", "siddhi.readback.callback"):
         assert events.get(name), name
+    # and no label beyond the inventory (PERF.md section 3): each of these
+    # has a reader under benchmarks/ or a row in docs/OBSERVABILITY.md
+    assert set(events) <= {
+        "siddhi.front.wire", "siddhi.ingress.claim_wait",
+        "siddhi.ingress.ticket_wait", "siddhi.ingress.intern_lock_wait",
+        "siddhi.ingress.intern", "siddhi.feeder.fill", "siddhi.feeder.h2d",
+        "siddhi.feeder.lock_wait", "siddhi.feeder.dispatch",
+        "siddhi.readback.submit", "siddhi.readback.fetch",
+        "siddhi.readback.callback", "siddhi.join.step",
+        "siddhi.join.drop_sync", "siddhi.pattern.step",
+        "siddhi.pattern.drop_sync", "siddhi.window.drop_sync"}, sorted(events)
     dispatches = events["siddhi.feeder.dispatch"]
     chunks = [int(stats["chunk"]) for _, _, stats in dispatches]
     assert chunks == sorted(chunks) and len(set(chunks)) == len(chunks)
