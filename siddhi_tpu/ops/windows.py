@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core import dtypes
-from .search import searchsorted32, stable_partition_order
+from .search import rank_sorted32, searchsorted32, stable_partition_order
 from ..core.event import EventBatch, EventType
 from ..errors import SiddhiAppCreationError
 from ..telemetry.tracing import stage
@@ -721,8 +721,9 @@ class SlidingWindow(WindowOp):
                     # head again only at the next event
                     n_time = jnp.minimum(
                         n_time, win_len1 - (n_valid32 > 0).astype(jnp.int32))
+                # both are running maxima, so sorted: a merge, not a search
                 pops_time = jnp.minimum(
-                    searchsorted32(deadline, lane_clock, side="right"),
+                    rank_sorted32(deadline, lane_clock, side="right"),
                     jnp.minimum(win_len0 + p, n_time))
                 # rows the step before should have let go and could not: E ran
                 # out (its newest row may stand, as above)

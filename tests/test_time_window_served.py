@@ -155,6 +155,76 @@ def test_a_symbol_that_leaves_and_returns_inside_one_batch(deploy, frames):
     assert got == distinct_counts(d.symbols, d.stamps, 1000)
 
 
+class BudgetedWindow(DistinctTimeWindow):
+    """The per-event walk with the step's expiry width: of the rows that are
+    due, no more than `expire` leave in one step; the rest wait for the
+    next."""
+
+    def __init__(self, width: int, expire: int) -> None:
+        super().__init__(width)
+        self.budget = self.left = expire
+
+    def expire(self) -> int:
+        went = 0
+        while self.left and self.due():
+            self.pop()
+            self.left -= 1
+            went += 1
+        return went
+
+    def step(self, symbols, stamps) -> list:
+        self.left = self.budget
+        return [self.arrive(s, int(t)) for s, t in zip(symbols, stamps)]
+
+
+@pytest.mark.filterwarnings("ignore:.*window_expiry_deferred")
+def test_the_merges_corners_equal_the_per_event_reference(deploy):
+    """What a merge of clocks and deadlines could break where a search did
+    not: an expiry width that is neither the batch's nor a power of two (the
+    deadlines' tail is a sentinel in most steps), frames out of stamp order
+    by one to three, a batch whose every deadline ties a clock (a tie
+    leaves first), and a jump of the clock that leaves rows behind for the
+    next steps (`window_expiry_deferred`)."""
+    expire, half = 48, BATCH // 2
+    d = deploy(app_text(capacity=f"@capacity(window='1024', "
+                                 f"expire='{expire}')"))
+    ref, want = BudgetedWindow(1000, expire), []
+
+    def send(stamps, seed):
+        before = len(d.stamps)
+        d.send(stamps, seed)
+        want.extend(ref.step(d.symbols[before:], d.stamps[before:]))
+
+    for f in [0, 3, 1, 2, 4, 7, 5, 6, 8]:  # a window is some three wide
+        send(f * STRIDE + 8 * np.arange(half), f)
+    w = d.rt.query_runtimes["distinct"].window
+    assert (w.E, w.chunk_width) == (expire, BATCH + expire)
+
+    def expired():
+        return d.rt.statistics_report()["windows"]["distinct"]["expired"]
+    assert expired() == len(d.stamps) - len(ref.fifo) > 0
+    # every deadline at the head of the FIFO is the stamp of an arrival
+    heads = sorted(t for _, t in list(ref.fifo)[:half])
+    assert heads[0] + 1000 > ref.clock
+    before = expired()
+    send(np.asarray(heads) + 1000, 20)
+    assert expired() - before >= half  # each tie let its row go
+    assert expired() == len(d.stamps) - len(ref.fifo)
+    # the clock jumps: every row is due, 48 may go a step
+    live = len(ref.fifo)
+    assert live > 2 * expire
+    for f in (40, 41, 42, 43):
+        send(f * STRIDE + 4 * np.arange(BATCH), f)
+    ts, got = d.rows()
+    assert ts == d.stamps
+    assert sum(g != w_ for g, w_ in zip(got, want)) == 0
+    stats = d.rt.statistics_report()
+    assert stats["windows"]["distinct"]["expiry_deferred"] > expire
+    assert expired() == len(d.stamps) - len(ref.fifo)
+    # and the plain walk, which knows no width, says they did leave late
+    assert got != distinct_counts(d.symbols, d.stamps, 1000)
+
+
 def test_a_head_that_is_not_due_holds_the_rows_behind_it(deploy):
     d = deploy(app_text())
     # FIFO: 3000, then 1000 and 1001 behind it. At clock 2500 the two are

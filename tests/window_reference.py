@@ -21,14 +21,20 @@ class DistinctTimeWindow:
         self.fifo: deque = deque()
         self.counts: Counter = Counter()
 
+    def due(self) -> bool:
+        return bool(self.fifo) and self.fifo[0][1] + self.width <= self.clock
+
+    def pop(self) -> None:
+        symbol, _ = self.fifo.popleft()
+        self.counts[symbol] -= 1
+        if not self.counts[symbol]:
+            del self.counts[symbol]
+
     def expire(self) -> int:
         """Pop what is due at the clock; returns how many rows left."""
         left = 0
-        while self.fifo and self.fifo[0][1] + self.width <= self.clock:
-            symbol, _ = self.fifo.popleft()
-            self.counts[symbol] -= 1
-            if not self.counts[symbol]:
-                del self.counts[symbol]
+        while self.due():
+            self.pop()
             left += 1
         return left
 
