@@ -699,9 +699,19 @@ def compute_cost(app_or_plan, *, batch_size: int = 0,
                              node_index=node.index,
                              notes=[f"not statically derivable: {e}"])
         if node.partition is not None:
-            ec.exact = False
-            ec.notes.append("partitioned query: per-key instance "
-                            "replication not modeled (lower bound)")
+            keyed = _keyed_partition_bytes(node, plan)
+            if keyed is None:
+                ec.exact = False
+                ec.notes.append("partitioned query: per-key instance "
+                                "replication not modeled (lower bound)")
+            elif ec.exact:
+                # the keyed step: the keys are an axis of the query's one
+                # state (core/keyed_partition.py), sized by what the
+                # partition states
+                ec.state_bytes = keyed
+                ec.bucket_ok = True
+                ec.notes.append("partition on the keyed step: a ring row "
+                                "and table entries per stated key")
         report.elements.append(ec)
 
     # --- definitions with their own device state ---
@@ -833,6 +843,29 @@ def price_splice(app, query, *, batch_size: int = 0,
 # --------------------------------------------------------------------------
 # the live oracle (calibration / statistics deltas)
 # --------------------------------------------------------------------------
+
+
+def _keyed_partition_bytes(node: QueryNode, plan: PlanGraph) -> Optional[int]:
+    """The state of a partition's inner query on the keyed step (None: the
+    partition stays on the host loop, or its stream's schema is open),
+    mirroring ops/keyed_window.KeyedLengthWindow.init_state."""
+    from ..core import dtypes
+    from ..core.keyed_partition import stated_keys
+    from ..ops.keyed_window import KeyedLengthWindow
+    from ..ops.slot_table import ROW, buckets_for
+    from ..ops.windows import make_layout
+    from .rules import partition_engine
+
+    c = node.consumed[0]
+    attrs = _closed(getattr(plan.schemas.get(c.stream_id), "attrs", None))
+    if attrs is None or partition_engine(node.partition, plan) is not None:
+        return None
+    keys = stated_keys(node.partition,
+                       dtypes.config.default_partition_capacity)
+    window = KeyedLengthWindow(
+        make_layout(attrs), c.single.handlers.window.parameters[0].value,
+        keys)
+    return 4 * keys * window.R + 4 * buckets_for(keys) * ROW + 4 + 8
 
 
 def measure_runtime_state_bytes(rt) -> dict:

@@ -496,6 +496,9 @@ def shape_bucket_padback(plan: PlanGraph) -> Iterable:
                 continue  # pass-through is shape-polymorphic
             if w.name in _SHAPE_POLYMORPHIC_WINDOWS:
                 continue
+            if node.partition is not None \
+                    and partition_engine(node.partition, plan) is None:
+                continue  # the keyed step reads its width from the batch
             if w.name == "batch" and not w.parameters:
                 continue  # paramless batch lowers to pass-through
             yield _q(node, f"#window.{w.name} is shape-baked while shape "
@@ -580,6 +583,90 @@ def racing_external_time(plan: PlanGraph) -> Iterable:
                                "fills from racing producers: the max-seen "
                                "event-time watermark (and every pane close) "
                                f"becomes nondeterministic — {fix}")
+
+
+# ----------------------------------------------------------- SL117 / SL118
+# which engine a stateful partition takes: the keyed step (one dispatch a
+# batch, the keys an axis of one state) or the host loop (one dispatch a key
+# a batch). The decision is core/keyed_partition.keyed_step_refusal's, the
+# one the runtime builds from.
+
+
+def partition_engine(partition, plan: PlanGraph) -> Optional[str]:
+    """None: the keyed step; else why the partition stays on the host
+    loop."""
+    from ..core.keyed_partition import keyed_step_refusal
+    from ..extension.registry import GLOBAL
+
+    def attribute_types(sid):
+        schema = plan.schemas.get(sid)
+        if schema is None or schema.kind != "stream" or schema.attrs is None:
+            return None
+        return schema.attrs
+
+    return keyed_step_refusal(partition, attribute_types, GLOBAL)
+
+
+def _partition_is_stateless(partition) -> bool:
+    """No window, aggregate, group or rate limit in any inner query: one
+    full-width pass, no per-key state (core/partition.py `route`)."""
+    from ..core.keyed_partition import _walk
+    from ..extension.registry import GLOBAL, ExtensionKind
+    from ..query_api.execution import SingleInputStream
+    from ..query_api.expression import AttributeFunction
+    for q in partition.queries:
+        ins = q.input_stream
+        if not isinstance(ins, SingleInputStream) \
+                or ins.handlers.window is not None \
+                or q.selector.group_by or q.output_rate is not None:
+            return False
+        for a in q.selector.attributes:
+            if any(isinstance(n, AttributeFunction) and GLOBAL.lookup(
+                    ExtensionKind.AGGREGATOR, n.namespace, n.name)
+                    is not None for n in _walk(a.expression)):
+                return False
+    return True
+
+
+def _stateful_partitions(plan: PlanGraph):
+    """(name as the runtime has it, partition, host-loop reason or None)."""
+    for i, p in enumerate(plan.app.partitions):
+        if not _partition_is_stateless(p):
+            yield f"partition{i + 1}", p, partition_engine(p, plan)
+
+
+@rule("SL117", Severity.WARN,
+      "a stateful partition runs on the host loop: one dispatch of the "
+      "inner queries' steps per distinct key per batch")
+def partition_on_host_loop(plan: PlanGraph) -> Iterable:
+    for name, p, reason in _stateful_partitions(plan):
+        if reason is not None:
+            yield (name, f"{name} runs on the host loop (a state per key, "
+                         "one dispatch of every inner step per distinct "
+                         f"key per batch) because {reason}; the keyed step "
+                         "(one dispatch a batch) takes a partition of one "
+                         "stream by one int, long, string or bool attribute "
+                         "around one `from S#window.length(L) select ...` "
+                         "with sum/count/avg/min/max over the window — see "
+                         "docs/PARITY.md", p, p.loc)
+
+
+@rule("SL118", Severity.INFO,
+      "a stateful partition runs on the keyed step: the keys are an axis of "
+      "one state, sized by @capacity(keys=...)")
+def partition_on_keyed_step(plan: PlanGraph) -> Iterable:
+    from ..core import dtypes
+    for name, p, reason in _stateful_partitions(plan):
+        if reason is None:
+            stated = dtypes.stated_capacity(p.annotations).keys
+            held = (f"@capacity(keys='{stated}')" if stated is not None else
+                    "no @capacity(keys=...): the runtime's "
+                    "partition_capacity, "
+                    f"{dtypes.config.default_partition_capacity} by default")
+            yield (name, f"{name} runs on the keyed step: one dispatch a "
+                         f"batch, state for the keys it states ({held}); "
+                         "events of keys beyond that are dropped and counted "
+                         "in statistics_report()['partitions']", p, p.loc)
 
 
 # ------------------------------------------------------------------- SL5xx

@@ -1440,6 +1440,7 @@ class SiddhiAppRuntime:
 
         from ..ops.aggregators import HLLState
         from ..ops.groupby import KeyTable
+        from ..ops.keyed_window import KeyedWindowState
         from ..ops.ratelimit import WindowedSnapshotState
         from ..ops.windows import SlidingState
         from ..ops.windows_extra import KeyedSessionState
@@ -1464,6 +1465,8 @@ class SiddhiAppRuntime:
                 add("session_key_dropped", obj.dropped)
             elif isinstance(obj, PatternState):
                 add("pattern_pending_dropped", obj.dropped)
+            elif isinstance(obj, KeyedWindowState):
+                add("partition_keys_dropped", obj.dropped)
             elif isinstance(obj, WindowedSnapshotState):
                 add("snapshot_ring_overflow", obj.overflow)
             elif isinstance(obj, HLLState):
@@ -1518,14 +1521,19 @@ class SiddhiAppRuntime:
                         if isinstance(qr, PatternQueryRuntime)
                         or (isinstance(qr, QueryRuntime)
                             and qr.cells is not None)}
+            keyed = {n: pr.keyed.device_counters()
+                     for n, pr in self.partitions.items()
+                     if pr.keyed is not None}
         # ONE device->host round trip
-        fetched, counted = jax.device_get((pending, patterns))
+        fetched, counted, keyed = jax.device_get((pending, patterns, keyed))
         for name, arrs in fetched.items():
             stats.record_overflow(name, int(sum(np.sum(a) for a in arrs)))
         for key, qr in joins.items():
             qr.dropped_synced = int(fetched[key][0])
         for n, values in counted.items():
             self.query_runtimes[n].sync_counters(values)
+        for n, values in keyed.items():
+            self.partitions[n].keyed.sync_counters(values)
 
     # ---------------------------------------------------------------- debugger
 

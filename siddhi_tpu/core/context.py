@@ -405,6 +405,13 @@ class Statistics:
                        and qr.cells is not None}
             if windows:
                 out["windows"] = windows
+            # partitions on the keyed step (core/keyed_partition.py): steps,
+            # key slots taken and stated, the lanes of keys turned away
+            partitions = {name: pr.keyed.stats_snapshot()
+                          for name, pr in runtime.partitions.items()
+                          if pr.keyed is not None}
+            if partitions:
+                out["partitions"] = partitions
         if runtime is not None:
             wal = getattr(runtime, "wal", None)
             if wal is not None:

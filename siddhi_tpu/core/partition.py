@@ -9,14 +9,31 @@ thread-local flow id), `@purge` idle-key cleanup (PartitionRuntimeImpl:120-136).
 
 TPU re-design — clone STATE, never code: the reference clones whole
 QueryRuntime object graphs per key; here every inner query is planned and
-jit-compiled exactly ONCE, and a partition key owns only a pytree of state
-(window rings + group tables) swapped into the shared compiled step. Keys
-therefore cost state memory, not compile time. Batches are routed by evaluating
-the compiled key expression on device, then splitting the batch into per-key
-masked views (capacity unchanged — lanes outside the key are invalid). A
-stateless inner graph (pure filter/projection — the BASELINE partitioned-filter
-shape) skips splitting entirely: with no per-key state, one fused pass over the
-whole batch is semantically identical and runs at full batch width.
+jit-compiled exactly ONCE. How a key's state is kept, and how many steps a
+batch costs, follows from the partition's plan, one engine per job:
+
+- **stateless** inner graph (pure filter/projection — the BASELINE
+  partitioned-filter shape): no per-key state, so one fused pass over the
+  whole batch is semantically identical and runs at full batch width.
+- **the keyed step** (core/keyed_partition.py, `self.keyed`): one stream
+  partitioned by one attribute around one `#window.length(L)` query. The
+  key is an AXIS of the inner query's state (one ring of K keys x L rows
+  and an exact key table on the device, ops/keyed_window.py) and the
+  receiver dispatches ONE step a batch through the proxy junction,
+  whatever the number of keys; `@capacity(keys=...)` on the partition
+  states K. For these shapes the host loop below is not reachable.
+- **the mesh step** (parallel/sharded.PartitionedQueryStep), only where the
+  app runs on a device mesh: the step vmapped over a slot axis.
+- **the host loop**, everything else (range partitions, several inner
+  queries or `#inner` streams, joins and patterns inside, time-driven
+  windows, `@purge`, a global stream feeding the partition): a partition
+  key owns a pytree of state (window rings + group tables) swapped into the
+  shared compiled step. Keys cost state memory, not compile time — and one
+  dispatch of every inner step per distinct key per batch: the batch's key
+  values are fetched to the host, and the batch is split into per-key masked
+  views (capacity unchanged — lanes outside the key are invalid).
+  `engine_reason` says why a stateful partition is here; lint rule SL117
+  tells the author at build.
 """
 
 from __future__ import annotations
@@ -200,6 +217,12 @@ class PartitionRuntime:
         # --- mesh-sharded execution (key-slot axis), when eligible ---
         self._mesh_step = None
         self._init_mesh_path()
+        # --- the keyed step: the key as an axis of the inner query's state
+        # (core/keyed_partition.py), for the shapes its plan check takes ---
+        self.keyed = None
+        self.engine_reason = None
+        if self._mesh_step is None and not self.stateless:
+            self._init_keyed_step()
 
         # --- routing subscriptions ---
         for sid, proxy in self.proxies.items():
@@ -251,6 +274,24 @@ class PartitionRuntime:
         self._mesh_sid = sid
         self._mesh_batches = 0
         self._mesh_key_warned = False
+
+    def _init_keyed_step(self) -> None:
+        """One path per job, decided from the plan whatever the number of
+        keys: where `keyed_step_refusal` finds nothing the host loop below
+        is not reachable; where it does, `engine_reason` says what."""
+        from .keyed_partition import KeyedStep, keyed_step_refusal
+
+        def attribute_types(sid):
+            j = self.rt.junctions.get(sid)
+            return None if j is None or sid in self.rt.windows else {
+                a.name: a.type for a in j.definition.attributes}
+
+        self.engine_reason = keyed_step_refusal(
+            self.partition, attribute_types, self.ctx.registry)
+        if self.engine_reason is None:
+            ((_, spec),) = self.key_specs.items()
+            ((_, qr),) = self.runtimes.items()
+            self.keyed = KeyedStep(self, spec, qr)
 
     def _mesh_route(self, batch: EventBatch, now: int) -> None:
         import time as _time
@@ -445,6 +486,9 @@ class PartitionRuntime:
             self._mesh_route(batch, now)
             return
         proxy = self.proxies[sid]
+        if self.keyed is not None:
+            self.keyed.route(proxy, batch, now)
+            return
         spec = self.key_specs[sid]
         if self.stateless and not spec.is_range:
             # value partitions: every valid event has a key, and with no
@@ -514,6 +558,8 @@ class PartitionRuntime:
             empty = EventBatch.empty(proxy.definition, proxy.batch_size)
             self._mesh_route(empty, now)
             return
+        if self.keyed is not None:
+            return  # a length window keeps no time; no key is ever let go
         if self._purge_idle_ms is not None:
             cutoff = now - self._purge_idle_ms
             for key in [k for k, ts in self.last_seen.items() if ts < cutoff]:
@@ -543,6 +589,8 @@ class PartitionRuntime:
         if self._mesh_step is not None:
             return {"__mesh_states__": fetch(prefix + "ms", self._mesh_states),
                     "__mesh_keys__": fetch(prefix + "mk", self._mesh_keys)}
+        if self.keyed is not None:
+            return {"__keyed__": fetch(prefix + "k", self.keyed.qr.state)}
         return {repr(k): {n: fetch(f"{prefix}{k!r}:{n}", s)
                           for n, s in inst.items()}
                 for k, inst in self.instances.items()}
@@ -560,6 +608,14 @@ class PartitionRuntime:
             self._mesh_states = _to_device(
                 snap["__mesh_states__"], self._mesh_states)
             self._mesh_keys = _to_device(snap["__mesh_keys__"], self._mesh_keys)
+            return
+        if self.keyed is not None:
+            if set(snap) != {"__keyed__"}:
+                raise CannotRestoreStateError(
+                    "snapshot was taken on the host loop (a state per key); "
+                    "this partition keeps its keys as an axis of one state")
+            qr = self.keyed.qr
+            qr.state = _to_device(snap["__keyed__"], qr.state)
             return
         self.instances = {}
         now = self.ctx.timestamp_generator.current_time()

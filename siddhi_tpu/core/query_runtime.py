@@ -533,6 +533,18 @@ class QueryRuntime(Receiver):
 
         return step
 
+    def take_step(self, step, state) -> None:
+        """Run `step(state, batch, now, table_states) -> (state, out)` on
+        `state` from here on, in place of the planned window -> selector ->
+        limiter step: a partition's keyed step (core/keyed_partition.py),
+        whose state has a key axis. The step reads its width from the
+        batch, and its window keeps no `SlidingState`, so this query leaves
+        the `windows` section of the statistics."""
+        self._step = jax.jit(step, donate_argnums=(0,))
+        self.state = state
+        self._bucket_ok = True
+        self.cells = None
+
     # -------------------------------------------------------------- runtime
 
     def _selector_state(self):

@@ -87,6 +87,7 @@ RECENT_RING = 64
 STEP_STAGES = (
     "filter",
     "window", "window/append", "window/expire", "window/fetch",
+    "window/route",  # a keyed window's slot lookup and (slot, lane) order
     "selector", "selector/sort", "selector/gather", "selector/scan",
     "selector/scatter",
     "emit",

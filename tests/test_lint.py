@@ -40,6 +40,8 @@ CORPUS_EXPECTATIONS = {
     "sl113": ("SL113", Severity.WARN),
     "sl114": ("SL114", Severity.INFO),
     "sl116": ("SL116", Severity.ERROR),
+    "sl117": ("SL117", Severity.WARN),
+    "sl118": ("SL118", Severity.INFO),
     "sl501": ("SL501", Severity.ERROR),
     "sl502": ("SL502", Severity.ERROR),
     "sl503": ("SL503", Severity.WARN),

@@ -310,7 +310,8 @@ def test_spans_lie_on_the_profilers_clock(tmp_path):
         "siddhi.readback.submit", "siddhi.readback.fetch",
         "siddhi.readback.callback", "siddhi.join.step",
         "siddhi.join.drop_sync", "siddhi.pattern.step",
-        "siddhi.pattern.drop_sync", "siddhi.window.drop_sync"}, sorted(events)
+        "siddhi.pattern.drop_sync", "siddhi.window.drop_sync",
+        "siddhi.partition.step", "siddhi.partition.drop_sync"}, sorted(events)
     dispatches = events["siddhi.feeder.dispatch"]
     chunks = [int(stats["chunk"]) for _, _, stats in dispatches]
     assert chunks == sorted(chunks) and len(set(chunks)) == len(chunks)
