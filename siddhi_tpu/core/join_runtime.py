@@ -30,7 +30,8 @@ from ..ops.join import (JoinPlan, _hash_exprs, collect_vars, compact_pairs,
                         plan_join, probe_cross, probe_equi, probe_equi_mm)
 from ..ops.selector import CompiledSelector
 from ..ops.window_factories import make_window
-from ..ops.windows import SlidingWindow, WindowOp, _unpack_rows
+from ..ops.windows import (SlidingWindow, WindowOp, _pack_rows,
+                           _unpack_rows)
 from ..query_api.definition import Attribute, AttributeType, StreamDefinition
 from ..query_api.execution import (
     EventTrigger,
@@ -168,6 +169,15 @@ class _Side:
                 ins.handlers.window, layout, batch_cap, True, registry,
                 annotations=annotations, playback=bool(ctx.playback))
         self.handlers = ins.handlers
+
+
+def _gather_frame(cols: dict, ts: jax.Array, idx: jax.Array):
+    """A frame's columns and stamps at pair lanes `idx`, through ONE `[W, P]`
+    gather of its rows packed as the ring packs them (an 8-byte column as
+    two words, a float bit-cast): a gather costs by the index, and a pair
+    block is `join_pair_cap_factor` batches wide (PERF.md, PR 37)."""
+    layout = {k: v.dtype for k, v in cols.items()}
+    return _unpack_rows(_pack_rows(cols, ts, layout)[:, idx], layout)
 
 
 def _named(fn, name: str):
@@ -604,14 +614,12 @@ class JoinQueryRuntime:
 
             # --- pair frames ---
             with stage("frames"):
-                p_cols = {k: v[lane] for k, v in batch.cols.items()}
-                p_ts = batch.ts[lane]
+                p_cols, p_ts = _gather_frame(batch.cols, batch.ts, lane)
                 if use_mm:
                     rows = w_build.ring[:, brow]  # [W, P] packed lane gather
                     g_cols, g_ts = _unpack_rows(rows, build_side.window.layout)
                 else:
-                    g_cols = {k: v[brow] for k, v in b_cols.items()}
-                    g_ts = b_ts[brow]
+                    g_cols, g_ts = _gather_frame(b_cols, b_ts, brow)
 
                 pair = Scope()
                 if from_left:

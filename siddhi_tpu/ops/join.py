@@ -12,6 +12,8 @@ a whole micro-batch at once with two strategies chosen at plan time:
   ON condition re-verifies every pair, so hash collisions cannot produce false
   matches. This is a sort-merge join: one sort of the build ring + one
   binary-search per probe lane, all inside the query's fused XLA program.
+  A sliding-window build side skips the sort: `MultimapState` indexes its
+  ring incrementally and `probe_equi_mm` walks bucket chains.
 - **cross join** fallback for ON conditions with no equality conjunct: a
   [B, C] mask with per-row top-K selection. Requires a small build side.
 
@@ -40,6 +42,7 @@ from ..query_api.definition import AttributeType
 from ..query_api.expression import And, Compare, CompareOp, Expression, Variable
 from .expr_compile import CompiledExpr, Scope, TypeResolver, compile_expression
 from .groupby import hash_columns32
+from .windows import _append_packed
 
 BIGKEY = np.uint32(0xFFFFFFFF)  # numpy literal — see ops/windows.py BIG note
 
@@ -214,7 +217,12 @@ def compact_pairs(build_row: jax.Array, pair_valid: jax.Array, k_max: int,
     return lane, jnp.where(pv, rows, 0), pv
 
 
-class MultimapState(NamedTuple):
+class _MultimapFields(NamedTuple):
+    heads: jax.Array  # i32[H] ring position of the newest entry per bucket
+    slots: jax.Array  # u32[C, 3] a ring slot's (arrival tag, key hash, next)
+
+
+class MultimapState(_MultimapFields):
     """Incrementally maintained hash multimap over a FIFO window ring.
 
     Replaces the per-step build-side sort of `probe_equi` for sliding-window
@@ -224,27 +232,30 @@ class MultimapState(NamedTuple):
     them, and chains through an overwritten slot terminate safely because
     every entry past it is older and therefore also overwritten.
 
-    Everything is i32/u32 — int64 lane math is software-emulated on TPU and
-    dominated the first cut of this structure. Entries are addressed by RING
-    POSITION; liveness rides a u32 arrival-index tag per slot compared by
-    wraparound age (`appended - tag`), exact while the window length stays
-    under 2^32 (a slot idle for exactly ~2^32 arrivals could alias — every
-    slot is rewritten each C arrivals, so this needs a 4-billion-event gap).
+    All 32-bit words (int64 lane math is emulated on TPU). Entries are
+    addressed by RING POSITION, and a slot's words — its row's arrival index
+    mod 2^32, its full key hash, the position of the next-older chain entry
+    (an i32 bit-cast) — are ONE row of `slots`: a gather costs by the index,
+    not by the row, and a chain step is one row gather (PERF.md, PR 37: a
+    third of a word gather's time; the TPU keeps the table slots-minor by
+    itself). Liveness rides the arrival tag compared by wraparound age
+    (`appended - tag`), exact while the window stays under 2^32 rows (every
+    slot is rewritten each C arrivals: an alias needs a 2^32-event gap).
     """
 
-    heads: jax.Array  # i32[H] ring position of the newest entry per bucket
-    nexts: jax.Array  # i32[C] ring position of the next-older chain entry
-    slot_hash: jax.Array  # u32[C] full 32-bit key hash of the slot's row
-    slot_seq: jax.Array  # u32[C] arrival index (mod 2^32) of the slot's row
+    __slots__ = ()
+
+    def __new__(cls, heads, slots, *parent):
+        if parent:  # a snapshot from before PR 37: heads, nexts, hash, seq
+            slots = np.stack([np.asarray(w).view(np.uint32)
+                              for w in (parent[1], parent[0], slots)], axis=1)
+        return super().__new__(cls, heads, slots)
 
 
 def multimap_init(ring_capacity: int, n_buckets: int) -> MultimapState:
-    return MultimapState(
-        heads=jnp.full((n_buckets,), -1, jnp.int32),
-        nexts=jnp.full((ring_capacity,), -1, jnp.int32),
-        slot_hash=jnp.zeros((ring_capacity,), jnp.uint32),
-        slot_seq=jnp.full((ring_capacity,), 0xFFFFFFFF, jnp.uint32),
-    )
+    empty = np.array([0xFFFFFFFF, 0, 0xFFFFFFFF], np.uint32)  # next = -1
+    return MultimapState(jnp.full((n_buckets,), -1, jnp.int32),
+                         jnp.broadcast_to(empty, (ring_capacity, 3)))
 
 
 def multimap_buckets(ring_capacity: int) -> int:
@@ -270,52 +281,46 @@ def multimap_append(mm: MultimapState, hashes: jax.Array, live: jax.Array,
     Vectorized intra-batch chaining: one [B] sort by bucket; within a bucket
     run rows link oldest <- newest, the run's oldest links to the bucket's
     previous head, and each run's END (the newest row) becomes the head —
-    one duplicate-free scatter per array, no atomics.
+    no atomics. A row's tag and position follow from its compacted lane, and
+    the slots written are the ring's own: the links go back to arrival order
+    by one word scatter, the entries in by the ring's contiguous append.
     """
-    C = mm.nexts.shape[0]
+    C = mm.slots.shape[0]
     H = mm.heads.shape[0]
-    B = hashes.shape[0]
     # mirror compact_packed: live rows first, stable → arrival order
-    order = stable_partition_order(live)
-    hashes = hashes[order]
-    valid = live[order]
-    j = jnp.arange(B, dtype=jnp.int32)
-    seq = (appended0.astype(jnp.uint32) + j.astype(jnp.uint32))
-    base = (appended0 % C).astype(jnp.int32)
-    pos = base + j
-    pos = jnp.where(pos >= C, pos - C, pos)  # base + j < 2C always
-    bucket = (hashes & jnp.uint32(H - 1)).astype(jnp.int32)
-
-    sortkey = jnp.where(valid, bucket, jnp.int32(H))
+    hashes = hashes[stable_partition_order(live)]
+    n_live = jnp.sum(live, dtype=jnp.int32)
+    j = jnp.arange(hashes.shape[0], dtype=jnp.int32)
+    sortkey = jnp.where(j < n_live, (hashes & jnp.uint32(H - 1)).astype(
+        jnp.int32), jnp.int32(H))
     run = stable_argsort_bounded(sortkey)  # bounded non-negative (<= H)
     b_s = sortkey[run]
-    seq_s = seq[run]
-    hash_s = hashes[run]
-    pos_s = pos[run]
+    pos_s = (appended0 % C).astype(jnp.int32) + run
+    pos_s = jnp.where(pos_s >= C, pos_s - C, pos_s)  # base + lane < 2C always
     same_as_prev = jnp.concatenate(
         [jnp.zeros((1,), bool), b_s[1:] == b_s[:-1]])
     old_head = mm.heads[jnp.clip(b_s, 0, H - 1)]
-    prev_pos = jnp.concatenate(
-        [jnp.full((1,), -1, jnp.int32), pos_s[:-1]])
+    prev_pos = jnp.concatenate([jnp.full((1,), -1, jnp.int32), pos_s[:-1]])
     next_val = jnp.where(same_as_prev, prev_pos, old_head)
 
-    dest = jnp.where(b_s < H, pos_s, jnp.int32(C))
-    nexts = mm.nexts.at[dest].set(next_val, mode="drop")
-    slot_hash = mm.slot_hash.at[dest].set(hash_s, mode="drop")
-    slot_seq = mm.slot_seq.at[dest].set(seq_s, mode="drop")
+    older = jnp.zeros_like(next_val).at[run].set(next_val)  # a permutation
+    entry = jnp.stack([appended0.astype(jnp.uint32) + j.astype(jnp.uint32),
+                       hashes, jax.lax.bitcast_convert_type(older, jnp.uint32)])
+    slots = _append_packed(mm.slots.T, entry, appended0, n_live).T
     is_end = jnp.concatenate(
         [b_s[1:] != b_s[:-1], jnp.ones((1,), bool)]) & (b_s < H)
     hdest = jnp.where(is_end, b_s, jnp.int32(H))
     heads = mm.heads.at[hdest].set(pos_s, mode="drop")
-    return MultimapState(heads, nexts, slot_hash, slot_seq)
+    return MultimapState(heads, slots)
 
 
 def multimap_probe(mm: MultimapState, probe_hash: jax.Array,
                    probe_valid: jax.Array, appended: jax.Array,
                    window_len: jax.Array, k_max: int):
-    """Walk bucket chains for each probe lane; k_max candidates max.
+    """Walk bucket chains for each probe lane; k_max candidates max, one
+    row gather of the slot's entry (tag, hash, next) a step.
 
-    Liveness is the u32 age test `0 < appended - slot_seq <= window_len`,
+    Liveness is the u32 age test `0 < appended - tag <= window_len`,
     and the walk additionally requires ages to STRICTLY INCREASE: a chain
     diverted through an overwritten slot jumps to a newer row, the age
     drops, and the walk stops — no stale or duplicate candidates.
@@ -332,24 +337,21 @@ def multimap_probe(mm: MultimapState, probe_hash: jax.Array,
     alive = probe_valid
     prev_age = jnp.zeros_like(app32, shape=pos.shape)
     cands, oks = [], []
-    for _ in range(k_max):
+    for k in range(k_max + 1):  # the last step only counts a LIVE tail
         ok_pos = alive & (pos >= 0)
         p = jnp.where(ok_pos, pos, 0)
-        age = app32 - mm.slot_seq[p]
+        seq, hsh, nxt = mm.slots[p].T
+        age = app32 - seq
         live = ok_pos & (age > prev_age) & (age <= wlen)
-        match = live & (mm.slot_hash[p] == probe_hash)
+        if k == k_max:  # a dead or diverted tail is not a lost match
+            truncated = jnp.sum(live, dtype=jnp.int32)
+            break
+        match = live & (hsh == probe_hash)
         cands.append(jnp.where(match, p, jnp.int32(0)))
         oks.append(match)
         alive = live
         prev_age = age
-        pos = mm.nexts[p]
-    # truncation = the (k_max+1)-th chain entry is genuinely LIVE (one extra
-    # age probe, no emission) — a dead or diverted tail is not a lost match
-    ok_pos = alive & (pos >= 0)
-    p = jnp.where(ok_pos, pos, 0)
-    age = app32 - mm.slot_seq[p]
-    truncated = jnp.sum(ok_pos & (age > prev_age) & (age <= wlen),
-                        dtype=jnp.int32)
+        pos = jax.lax.bitcast_convert_type(nxt, jnp.int32)
     # chains run newest → oldest; reverse so pair emission (and k_max
     # truncation) is oldest-first like the sorted probe path
     cand_pos = jnp.stack(cands[::-1], axis=1)

@@ -406,3 +406,223 @@ def test_multimap_buckets_leave_a_chain_to_the_probes_own_matches():
     # 6 about once, 7 never (the FNV round alone: 50, 14 and 2)
     assert load.max() <= 6
     assert np.count_nonzero(load >= 5) <= 30
+
+
+# ------------------------------------------------- the packed multimap
+
+
+class PerEventMultimap:
+    """The multimap one event at a time, in plain dicts: a row takes the
+    ring position of its arrival index, remembers the position its bucket's
+    newest row had before it, and becomes that newest row. A probe starts at
+    its bucket's newest position and follows those memories through
+    whatever rows hold the positions NOW, while the ages (arrivals since
+    the row's own) strictly grow and stay inside the window; it examines
+    `k_max` rows whether they match or not, keeps the positions of those
+    with its own hash, oldest first, and is truncated if one more row would
+    have passed."""
+
+    def __init__(self, ring: int, buckets: int, start: int) -> None:
+        self.C, self.H, self.n = ring, buckets, start
+        self.slot: dict = {}  # position -> (arrival index, hash, older)
+        self.newest: dict = {}  # bucket -> position
+
+    def append(self, hashes, live) -> None:
+        for h, ok in zip(hashes.tolist(), live.tolist()):
+            if ok:
+                pos, b = self.n % self.C, h % self.H
+                self.slot[pos] = (self.n, h, self.newest.get(b, -1))
+                self.newest[b] = pos
+                self.n += 1
+
+    def probe(self, h: int, window_len: int, k_max: int):
+        pos, age_before, found = self.newest.get(h % self.H, -1), 0, []
+        for examined in range(k_max + 1):
+            if pos < 0:
+                break
+            arrived, its_hash, older = self.slot[pos]
+            if not age_before < self.n - arrived <= window_len:
+                break
+            if examined == k_max:
+                return found[::-1], True
+            found += [pos] * (its_hash == h)
+            pos, age_before = older, self.n - arrived
+        return found[::-1], False
+
+
+@pytest.mark.parametrize("ring,length,buckets,k_max,start", [
+    pytest.param(40, 40, 8, 4, 0, id="ring_wraps_three_times"),
+    pytest.param(40, 25, 8, 4, 7, id="window_shorter_than_the_ring"),
+    pytest.param(40, 40, 8, 4, 2**32 - 70, id="arrival_tags_cross_2^32"),
+    pytest.param(40, 40, 1, 4, 0, id="one_bucket_deeper_than_k_max"),
+    pytest.param(16, 16, 2, 16, 3, id="chains_diverted_every_batch"),
+])
+def test_packed_multimap_equals_a_per_event_dict_of_lists(
+        ring, length, buckets, k_max, start):
+    """`multimap_append` + `multimap_probe` over a ring that wraps: keys
+    repeat inside a batch, a quarter of the lanes are invalid, buckets are
+    so few that every chain runs into slots another bucket has overwritten,
+    and (one bucket) deeper than `k_max`: candidates, their order and
+    `truncated` as the per-event model gives them."""
+    import jax.numpy as jnp
+
+    from siddhi_tpu.ops.join import (multimap_append, multimap_init,
+                                     multimap_probe)
+    B = 16
+    rng = np.random.default_rng([37, ring, buckets, start % 97])
+    keys = rng.integers(0, 2**32, 12, dtype=np.uint64).astype(np.uint32)
+    mm = multimap_init(ring, buckets)
+    model = PerEventMultimap(ring, buckets, start)
+    appended = start
+    truncated_seen = matched = 0
+    for step in range(11):
+        hashes = keys[rng.integers(0, keys.size, B)]
+        live = rng.random(B) > 0.25
+        mm = multimap_append(mm, jnp.asarray(hashes), jnp.asarray(live),
+                             jnp.int64(appended))
+        model.append(hashes, live)
+        appended += int(live.sum())
+        probes = keys[rng.integers(0, keys.size, B)]
+        valid = rng.random(B) > 0.2
+        window_len = min(appended - start, length)
+        pos, ok, truncated = multimap_probe(
+            mm, jnp.asarray(probes), jnp.asarray(valid), jnp.int64(appended),
+            jnp.int64(window_len), k_max)
+        pos, ok = np.asarray(pos), np.asarray(ok)
+        want = [model.probe(int(h), window_len, k_max) if v else ([], False)
+                for h, v in zip(probes, valid)]
+        assert [pos[i][ok[i]].tolist() for i in range(B)] \
+            == [w[0] for w in want], step
+        assert int(truncated) == sum(w[1] for w in want), step
+        truncated_seen += int(truncated)
+        matched += int(ok.sum())
+    assert appended - start > 3 * ring  # the ring wrapped three times
+    assert matched > B and (truncated_seen > 0) == (k_max < ring // buckets)
+
+
+def _gathers_by_stage(lowered) -> dict:
+    """{stage: [operand type of each `stablehlo.gather` under
+    `siddhi.<stage>`]} of a lowered step program."""
+    import re
+    text = lowered.as_text(debug_info=True)
+    locs = dict(re.findall(r"^(#loc\d+) = loc\((.*)\)$", text, flags=re.M))
+
+    def stage_of(ref: str, depth: int = 0):
+        body = locs.get(ref, "")
+        named = re.search(r"siddhi\.(\w+)", body)
+        if named or depth == 4:
+            return named and named.group(1)
+        return next(filter(None, (stage_of(r, depth + 1) for r in
+                                  re.findall(r"#loc\d+", body))), None)
+
+    found: dict = {}
+    ops = re.findall(r'"stablehlo\.gather"\(.*? : \((tensor<[^>]*>), '
+                     r'.*? loc\((#loc\d+)\)', text)
+    assert len(ops) == text.count('"stablehlo.gather"(')
+    for operand, ref in ops:
+        found.setdefault(stage_of(ref), []).append(operand)
+    return found
+
+
+@pytest.mark.parametrize("side", ["left", "right"])
+def test_the_join_step_gathers_rows_not_words(small, side):
+    """In place of an engagement counter (the packing is chosen at trace
+    time): the chain walk reads one packed entry a step (`heads`, sixteen
+    steps, the truncation's: 18 gathers where the three-array multimap took
+    50), and both sides' pair frames cross as packed 32-bit rows — no
+    `f32` and no 8-byte operand under `siddhi.frames` (`price` gathered as
+    `f32[524288]` was 16 of join_100k's 104 ms step: PERF.md, PR 34)."""
+    import re
+
+    import jax.numpy as jnp
+
+    from siddhi_tpu.core.event import EventBatch
+    join = small.rt.query_runtimes["join"]
+    fn = join._step_left if side == "left" else join._step_right
+    stream = STREAMS[0 if side == "left" else 1]
+    empty = EventBatch.empty(small.rt.junctions[stream].definition, 64)
+    found = _gathers_by_stage(fn.lower(join.state, empty, jnp.int64(0), None))
+    k_max = dtypes.config.join_max_matches
+    assert 0 < len(found["probe"]) <= k_max + 2
+    entry = "tensor<%dx3xui32>" % join.state[2].slots.shape[0]
+    assert found["probe"].count(entry) == k_max + 1
+    assert len(found["frames"]) == 2
+    assert not [t for t in found["frames"]
+                if re.search(r"x(f32|[iu]i?64|f64)>", t)]
+    # the append gathers the hash by the bucket order, nothing else by it
+    assert len(found["window"]) <= 4
+
+
+def test_restore_converts_a_snapshot_the_three_array_multimap_wrote(
+        monkeypatch):
+    """A join query's snapshot holds its multimaps. One written before
+    PR 37 pickles `MultimapState(heads, nexts, slot_hash, slot_seq)`; the
+    class takes those four on the way in and packs the three per-slot
+    arrays into its table, so `restore` leaves the state the snapshot's
+    writer had and the next step finds the pairs it would have found."""
+    import pickle
+    from collections import namedtuple
+
+    import jax
+
+    from siddhi_tpu.ops import join as J
+    app = ("@app:name('Upgrade')\n"
+           "define stream L (k int, v int);\n"
+           "define stream R (k int, v int);\n"
+           "@info(name = 'join')\n"
+           "from L#window.length(6) join R#window.length(6) on L.k == R.k "
+           "select L.k as k, L.v as lv, R.v as rv insert into OutStream;")
+
+    def deployment():
+        rt = SiddhiManager().create_siddhi_app_runtime(app, batch_size=8)
+        got: list = []
+        rt.add_callback("OutStream",
+                        lambda evs: got.extend(e.data for e in evs))
+        rt.start()
+        return rt, got
+
+    def feed(rt, frames):
+        for stream, rows in frames:
+            rt.get_input_handler(stream).send_batch(rows)
+            rt.flush()
+
+    before = [("L", [(k % 4, k) for k in range(8)]),  # the rings wrap
+              ("R", [(k % 3, 100 + k) for k in range(7)])]
+    after = [("R", [(1, 200), (3, 201)]), ("L", [(0, 50), (2, 51)])]
+    rt, got = deployment()
+    feed(rt, before)
+    snap = pickle.loads(rt.snapshot())
+    written = snap["queries"]["join"]
+    assert [type(s) for s in written[2:4]] == [J.MultimapState] * 2
+
+    parent = namedtuple("MultimapState", "heads nexts slot_hash slot_seq")
+    parent.__module__ = J.__name__
+
+    def as_the_parent_wrote(mm):
+        seq, key_hash, older = mm.slots.T
+        return parent(mm.heads, older.view(np.int32), key_hash, seq)
+
+    snap["queries"]["join"] = written[:2] + tuple(
+        as_the_parent_wrote(mm) for mm in written[2:4]) + written[4:]
+    with monkeypatch.context() as m:  # pickled by reference, as it was
+        m.setattr(J, "MultimapState", parent)
+        blob = pickle.dumps(snap, protocol=pickle.HIGHEST_PROTOCOL)
+    assert blob != rt.snapshot()
+
+    rt2, got2 = deployment()
+    rt2.restore(blob)
+    restored = rt2.query_runtimes["join"].state
+    assert [type(s) for s in restored[2:4]] == [J.MultimapState] * 2
+    for a, b in zip(jax.tree_util.tree_leaves(restored),
+                    jax.tree_util.tree_leaves(rt.query_runtimes["join"].state)):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    del got[:]
+    feed(rt, after)
+    feed(rt2, after)
+    # R(1), R(3) against L's last six, then L(0), L(2) against R's
+    assert got2 == got == [
+        (1, 5, 200), (3, 3, 201), (3, 7, 201),
+        (0, 50, 103), (0, 50, 106), (2, 51, 105)]
+    rt.shutdown()
+    rt2.shutdown()
