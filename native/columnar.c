@@ -12,6 +12,14 @@
  * the Python StringTable, so native and Python encode paths share one code
  * space and snapshot/restore stays unchanged.
  *
+ * intern_column() interns a whole column (the send_columns and wire paths)
+ * through the same pair, with two caches in front of the dict: a
+ * pointer-identity memo for producers that pool their string objects, and
+ * a table keyed on each str's UTF-8 bytes, probed with the interpreter
+ * released. Both hold permanent codes only and are read-through: a miss
+ * falls through to the dict, in value order, so every code is the one the
+ * plain loop gives.
+ *
  * Type codes (one byte per attribute):
  *   'b' bool -> int8 buffer      'i' int -> int32
  *   'l' long -> int64            'f' float -> float32
@@ -309,26 +317,273 @@ idmemo_slot(PyObject *p)
                     >> (64 - IDMEMO_BITS));
 }
 
+/* memoize a permanent code (transient ring codes recycle): prefer an empty
+ * probe slot, else evict the second */
+static void
+idmemo_put(id_memo *m, PyObject *v, int32_t code)
+{
+    size_t slot = idmemo_slot(v);
+    size_t slot2 = (slot + 1) & (IDMEMO_SIZE - 1); /* one probe step */
+    if (m->keys[slot] == v || m->keys[slot2] == v)
+        return;
+    size_t s = (m->keys[slot] == NULL) ? slot : slot2;
+    Py_XDECREF(m->keys[s]);
+    m->keys[s] = Py_NewRef(v);
+    m->codes[s] = code;
+}
+
+/* --- byte-keyed intern table ------------------------------------------
+ *
+ * A read-through cache of StringTable._to_code keyed on a str's UTF-8
+ * bytes, so that a lookup needs neither the interpreter nor the `str`'s
+ * Python hash: a wire frame's dictionary entries are new objects every
+ * frame, so their hashes are never cached and the pointer memo above never
+ * hits them. Never the master: every entry is a permanent code that
+ * intern_string returned for those bytes (codes are append-only, so an
+ * entry never goes stale), a miss falls through to the dict, and the
+ * table is dropped wholesale on StringTable.restore. Open addressing,
+ * linear probing on a 64-bit hash, doubled at a load of one half; a key
+ * of up to ST_INLINE bytes lives in its slot, a longer one in `keys`.
+ *
+ * `lock` orders the probe, which runs with the interpreter released,
+ * against inserts and growth. It is never held across Python code, and no
+ * thread waits for it while holding the interpreter (st_lock), so it
+ * cannot deadlock with the interpreter's lock. */
+
+#define ST_INLINE 16
+#define ST_BLOCK 32        /* values hashed and prefetched together */
+#define ST_NOGIL_MIN 1024  /* fewer probes than this keep the interpreter:
+                            * releasing it would cost more than it frees */
+#define TRANSIENT_BASE (1 << 30)
+
+typedef struct {
+    uint64_t h;
+    int32_t code;          /* 0: empty (code 0 is null, never cached) */
+    uint32_t len;
+    union {
+        char b[ST_INLINE];
+        size_t off;        /* into `keys`, for a longer key */
+    } key;
+} st_slot;
+
+typedef struct {
+    st_slot *slots;
+    size_t mask;           /* slot count - 1 */
+    size_t count;
+    char *keys;
+    size_t keys_len, keys_cap;
+    PyThread_type_lock lock;
+} intern_table;
+
+static void
+intern_table_destruct(PyObject *capsule)
+{
+    intern_table *t = (intern_table *)PyCapsule_GetPointer(
+        capsule, "siddhi.interntable");
+    if (t == NULL)
+        return;
+    PyMem_Free(t->slots);
+    PyMem_Free(t->keys);
+    PyThread_free_lock(t->lock);
+    PyMem_Free(t);
+}
+
+/* intern_table_new() -> capsule */
+static PyObject *
+intern_table_new(PyObject *self, PyObject *args)
+{
+    intern_table *t = PyMem_Calloc(1, sizeof(intern_table));
+    if (t == NULL)
+        return PyErr_NoMemory();
+    t->mask = 1023;
+    t->slots = PyMem_Calloc(t->mask + 1, sizeof(st_slot));
+    t->lock = PyThread_allocate_lock();
+    if (t->slots == NULL || t->lock == NULL) {
+        PyMem_Free(t->slots);
+        if (t->lock != NULL)
+            PyThread_free_lock(t->lock);
+        PyMem_Free(t);
+        return PyErr_NoMemory();
+    }
+    return PyCapsule_New(t, "siddhi.interntable", intern_table_destruct);
+}
+
+static uint64_t
+st_hash(const char *p, size_t len)
+{
+    const uint64_t k0 = 0x9E3779B97F4A7C15ULL, k1 = 0xC2B2AE3D27D4EB4FULL;
+    uint64_t h = (uint64_t)len * k1, v;
+    for (; len > 8; p += 8, len -= 8) {
+        memcpy(&v, p, 8);
+        h = (h ^ v) * k0;
+        h ^= h >> 29;
+    }
+    v = 0;
+    memcpy(&v, p, len);
+    h = (h ^ v) * k0;
+    h ^= h >> 32;
+    h *= k1;
+    return h ^ (h >> 29);
+}
+
+static inline const char *
+st_key(const intern_table *t, const st_slot *s)
+{
+    return s->len <= ST_INLINE ? s->key.b : t->keys + s->key.off;
+}
+
+/* the code cached for these bytes, or 0 */
+static inline int32_t
+st_find(const intern_table *t, uint64_t h, const char *p, size_t len)
+{
+    for (size_t i = (size_t)h & t->mask;; i = (i + 1) & t->mask) {
+        const st_slot *s = &t->slots[i];
+        if (s->code == 0)
+            return 0;
+        if (s->h == h && s->len == len && memcmp(st_key(t, s), p, len) == 0)
+            return s->code;
+    }
+}
+
+static inline st_slot *
+st_empty_slot(st_slot *slots, size_t mask, uint64_t h)
+{
+    size_t i = (size_t)h & mask;
+    while (slots[i].code != 0)
+        i = (i + 1) & mask;
+    return &slots[i];
+}
+
+/* cache `code` for these bytes; an allocation that fails leaves the key
+ * out (the table is a cache: the dict still has it). Caller holds `lock`. */
+static void
+st_insert(intern_table *t, uint64_t h, const char *p, size_t len,
+          int32_t code)
+{
+    if (len > UINT32_MAX || st_find(t, h, p, len) != 0)
+        return;
+    if (2 * (t->count + 1) > t->mask + 1) {
+        size_t mask = 2 * t->mask + 1;
+        st_slot *slots = PyMem_Calloc(mask + 1, sizeof(st_slot));
+        if (slots == NULL)
+            return;
+        for (size_t i = 0; i <= t->mask; i++)
+            if (t->slots[i].code != 0)
+                *st_empty_slot(slots, mask, t->slots[i].h) = t->slots[i];
+        PyMem_Free(t->slots);
+        t->slots = slots;
+        t->mask = mask;
+    }
+    size_t off = 0;
+    if (len > ST_INLINE) {
+        if (t->keys_len + len > t->keys_cap) {
+            size_t cap = 2 * t->keys_cap + len + 4096;
+            char *keys = PyMem_Realloc(t->keys, cap);
+            if (keys == NULL)
+                return;
+            t->keys = keys;
+            t->keys_cap = cap;
+        }
+        off = t->keys_len;
+        memcpy(t->keys + off, p, len);
+        t->keys_len += len;
+    }
+    st_slot *s = st_empty_slot(t->slots, t->mask, h);
+    s->h = h;
+    s->len = (uint32_t)len;
+    if (len > ST_INLINE)
+        s->key.off = off;
+    else
+        memcpy(s->key.b, p, len);
+    s->code = code;
+    t->count++;
+}
+
+/* take `lock` while holding the interpreter: wait for it with the
+ * interpreter released, as its holder may be a probe that needs it next */
+static void
+st_lock(intern_table *t)
+{
+    if (!PyThread_acquire_lock(t->lock, NOWAIT_LOCK)) {
+        Py_BEGIN_ALLOW_THREADS
+        PyThread_acquire_lock(t->lock, WAIT_LOCK);
+        Py_END_ALLOW_THREADS
+    }
+}
+
+/* one value still to resolve after phase 1 */
+typedef struct {
+    uint64_t h;
+    size_t off;            /* its bytes in the call's own copy */
+    Py_ssize_t len;        /* -1: not a key (not an exact str, or not
+                            * encodable as UTF-8) */
+    Py_ssize_t idx;        /* its position in the column */
+} st_probe;
+
+/* Phase 2: look every keyed value up, ST_BLOCK at a time — hash the block
+ * and prefetch its slots, then compare. Hits go to `out`; the rest are
+ * compacted, in value order, to the front of `pr`. Returns how many are
+ * left; `*hits` is how many the table resolved. Reads only the table and
+ * the call's own memory: safe without the interpreter. */
+static Py_ssize_t
+st_probe_all(const intern_table *t, st_probe *pr, Py_ssize_t n,
+             const char *bytes, int32_t *out, Py_ssize_t *hits)
+{
+    Py_ssize_t left = 0, found = 0;
+    for (Py_ssize_t b = 0; b < n; b += ST_BLOCK) {
+        Py_ssize_t e = (n - b > ST_BLOCK) ? b + ST_BLOCK : n;
+        for (Py_ssize_t k = b; k < e; k++)
+            if (pr[k].len >= 0) {
+                pr[k].h = st_hash(bytes + pr[k].off, (size_t)pr[k].len);
+                __builtin_prefetch(&t->slots[pr[k].h & t->mask]);
+            }
+        for (Py_ssize_t k = b; k < e; k++) {
+            int32_t code = pr[k].len < 0 ? 0
+                : st_find(t, pr[k].h, bytes + pr[k].off, (size_t)pr[k].len);
+            if (code != 0) {
+                out[pr[k].idx] = code;
+                found++;
+            } else {
+                pr[left++] = pr[k];
+            }
+        }
+    }
+    *hits = found;
+    return left;
+}
+
 /* intern_column(values, out: int32 buffer, to_code: dict, to_str: list,
- *               transient: dict[, memo_capsule]) — vectorized string
- * interning for one column (send_columns path); `transient` keeps live
- * uuid codes stable. */
+ *               transient: dict, memo_capsule, table_capsule) -> int
+ *
+ * Vectorized string interning for one column (the send_columns and wire
+ * paths); `transient` keeps live uuid codes stable. Returns how many
+ * values the byte-keyed table resolved. Three phases:
+ *   1. with the interpreter: None -> 0, the pointer memo, and each other
+ *      exact str's UTF-8 bytes copied into the call's own buffer;
+ *   2. the table probe (above), with the interpreter released when there
+ *      are at least ST_NOGIL_MIN values to probe;
+ *   3. with the interpreter: the rest through intern_string in value order
+ *      — so every code is the one the plain loop would give — then their
+ *      permanent codes into the memo and the table.
+ * A str that is not UTF-8 (a lone surrogate) is simply not a key. */
 static PyObject *
 intern_column(PyObject *self, PyObject *args)
 {
     PyObject *values, *out, *to_code, *to_str, *transient;
-    PyObject *memo_capsule = NULL;
-    if (!PyArg_ParseTuple(args, "OOO!O!O!|O", &values, &out,
+    PyObject *memo_capsule, *table_capsule;
+    if (!PyArg_ParseTuple(args, "OOO!O!O!OO", &values, &out,
                           &PyDict_Type, &to_code, &PyList_Type, &to_str,
-                          &PyDict_Type, &transient, &memo_capsule))
+                          &PyDict_Type, &transient, &memo_capsule,
+                          &table_capsule))
         return NULL;
-    id_memo *memo = NULL;
-    if (memo_capsule != NULL && memo_capsule != Py_None) {
-        memo = (id_memo *)PyCapsule_GetPointer(memo_capsule,
-                                               "siddhi.idmemo");
-        if (memo == NULL)
-            return NULL;
-    }
+    id_memo *memo = (id_memo *)PyCapsule_GetPointer(memo_capsule,
+                                                    "siddhi.idmemo");
+    if (memo == NULL)
+        return NULL;
+    intern_table *table = (intern_table *)PyCapsule_GetPointer(
+        table_capsule, "siddhi.interntable");
+    if (table == NULL)
+        return NULL;
     PyObject *fast = PySequence_Fast(values, "values must be a sequence");
     if (fast == NULL)
         return NULL;
@@ -338,48 +593,135 @@ intern_column(PyObject *self, PyObject *args)
         Py_DECREF(fast);
         return NULL;
     }
+    PyObject *result = NULL;
+    int32_t *data = (int32_t *)buf.buf;
+    st_probe *pr = NULL;
+    char *bytes = NULL;
+    size_t bytes_len = 0, bytes_cap = 0;
+    Py_ssize_t left = 0, hits = 0, held = 0;
+    /* phase 3's values whose codes go into the table, held until then */
+    PyObject **held_v = NULL;
+    int32_t *held_code = NULL;
     if (buf.len < n * (Py_ssize_t)sizeof(int32_t)) {
         PyErr_SetString(PyExc_ValueError, "intern_column: out buffer too small");
-        PyBuffer_Release(&buf);
-        Py_DECREF(fast);
-        return NULL;
+        goto done;
     }
-    int32_t *data = (int32_t *)buf.buf;
+    pr = PyMem_Malloc((n > 0 ? (size_t)n : 1) * sizeof(st_probe));
+    if (pr == NULL) {
+        PyErr_NoMemory();
+        goto done;
+    }
+
+    /* phase 1 */
     for (Py_ssize_t i = 0; i < n; i++) {
         PyObject *v = PySequence_Fast_GET_ITEM(fast, i);
-        size_t slot = 0, slot2 = 0;
-        if (memo != NULL && v != Py_None) {
-            slot = idmemo_slot(v);
-            if (memo->keys[slot] == v) {
-                data[i] = memo->codes[slot];
-                continue;
-            }
-            slot2 = (slot + 1) & (IDMEMO_SIZE - 1); /* one probe step */
-            if (memo->keys[slot2] == v) {
-                data[i] = memo->codes[slot2];
-                continue;
-            }
+        if (v == Py_None) {
+            data[i] = 0;
+            continue;
         }
-        int32_t code = intern_string(v, to_code, to_str, transient);
-        if (code < 0 && PyErr_Occurred()) {
-            PyBuffer_Release(&buf);
-            Py_DECREF(fast);
-            return NULL;
+        size_t slot = idmemo_slot(v);
+        size_t slot2 = (slot + 1) & (IDMEMO_SIZE - 1);
+        if (memo->keys[slot] == v || memo->keys[slot2] == v) {
+            data[i] = memo->codes[memo->keys[slot] == v ? slot : slot2];
+            continue;
         }
-        data[i] = code;
-        /* memoize permanent codes only (transient ring codes recycle);
-         * prefer an empty slot, else evict the probe slot */
-        if (memo != NULL && v != Py_None && code < (1 << 30)) {
-            size_t s = (memo->keys[slot] == NULL) ? slot : slot2;
-            Py_XDECREF(memo->keys[s]);
-            Py_INCREF(v);
-            memo->keys[s] = v;
-            memo->codes[s] = code;
+        st_probe *e = &pr[left++];
+        e->idx = i;
+        e->off = 0;
+        e->len = -1;
+        if (!PyUnicode_CheckExact(v))
+            continue;
+        Py_ssize_t len;
+        const char *p = PyUnicode_AsUTF8AndSize(v, &len);
+        if (p == NULL) {
+            PyErr_Clear();
+            continue;
+        }
+        if (bytes_len + (size_t)len > bytes_cap) {
+            size_t cap = 2 * bytes_cap + (size_t)len + 4096;
+            char *grown = PyMem_Realloc(bytes, cap);
+            if (grown == NULL) {
+                PyErr_NoMemory();
+                goto done;
+            }
+            bytes = grown;
+            bytes_cap = cap;
+        }
+        memcpy(bytes + bytes_len, p, (size_t)len);
+        e->off = bytes_len;
+        e->len = len;
+        bytes_len += (size_t)len;
+    }
+
+    /* phase 2 */
+    if (left >= ST_NOGIL_MIN) {
+        Py_BEGIN_ALLOW_THREADS
+        PyThread_acquire_lock(table->lock, WAIT_LOCK);
+        left = st_probe_all(table, pr, left, bytes, data, &hits);
+        PyThread_release_lock(table->lock);
+        Py_END_ALLOW_THREADS
+    } else if (left > 0) {
+        st_lock(table);
+        left = st_probe_all(table, pr, left, bytes, data, &hits);
+        PyThread_release_lock(table->lock);
+    }
+
+    /* phase 3: the rest in value order, then their permanent codes into
+     * the memo and the table. No Python code runs under `lock`: the
+     * resolution (which may compare arbitrary objects) comes first. */
+    if (left > 0) {
+        held_v = PyMem_Malloc((size_t)left * sizeof(PyObject *));
+        held_code = PyMem_Malloc((size_t)left * sizeof(int32_t));
+        if (held_v == NULL || held_code == NULL) {
+            PyErr_NoMemory();
+            goto done;
         }
     }
+    for (Py_ssize_t k = 0; k < left; k++) {
+        Py_ssize_t i = pr[k].idx;
+        if (i >= PySequence_Fast_GET_SIZE(fast)) {
+            PyErr_SetString(PyExc_RuntimeError,
+                            "intern_column: values changed size");
+            goto done;
+        }
+        PyObject *v = PySequence_Fast_GET_ITEM(fast, i);
+        int32_t code = intern_string(v, to_code, to_str, transient);
+        if (code < 0 && PyErr_Occurred())
+            goto done;
+        data[i] = code;
+        if (v == Py_None || code <= 0 || code >= TRANSIENT_BASE)
+            continue;
+        idmemo_put(memo, v, code);
+        if (PyUnicode_CheckExact(v)) {
+            held_v[held] = Py_NewRef(v);
+            held_code[held++] = code;
+        }
+    }
+    if (held > 0) {
+        st_lock(table);
+        for (Py_ssize_t k = 0; k < held; k++) {
+            Py_ssize_t len;
+            const char *p = PyUnicode_AsUTF8AndSize(held_v[k], &len);
+            if (p == NULL)
+                PyErr_Clear();
+            else
+                st_insert(table, st_hash(p, (size_t)len), p, (size_t)len,
+                          held_code[k]);
+        }
+        PyThread_release_lock(table->lock);
+    }
+    result = PyLong_FromSsize_t(hits);
+
+done:
+    for (Py_ssize_t k = 0; k < held; k++)
+        Py_DECREF(held_v[k]);
+    PyMem_Free(held_v);
+    PyMem_Free(held_code);
+    PyMem_Free(pr);
+    PyMem_Free(bytes);
     PyBuffer_Release(&buf);
     Py_DECREF(fast);
-    return Py_NewRef(Py_None);
+    return result;
 }
 
 /* map_codes(codes: int32 buffer, to_str: list) -> list[str|None]
@@ -1086,8 +1428,10 @@ static PyMethodDef methods[] = {
      "Fill an int64 timestamp buffer with monotone padding."},
     {"idmemo_new", idmemo_new, METH_VARARGS,
      "idmemo_new() -> capsule: pointer-identity intern memo"},
+    {"intern_table_new", intern_table_new, METH_VARARGS,
+     "intern_table_new() -> capsule: byte-keyed cache of permanent codes"},
     {"intern_column", intern_column, METH_VARARGS,
-     "Intern a string column into an int32 code buffer."},
+     "Intern a string column into an int32 code buffer; returns table hits."},
     {"map_codes", map_codes, METH_VARARGS,
      "Decode an int32 code buffer through a string table list."},
     {"decode_dict", decode_dict, METH_VARARGS,

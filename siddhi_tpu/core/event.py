@@ -87,9 +87,12 @@ class StringTable:
         #: (~1024 at the default 1M capacity) — documented bound.
         self._transient_gens: list[int] = []
         self._transient_cap: Optional[int] = None
-        #: native pointer-identity intern memo (capsule); lazily created by
-        #: encode_array, dropped whenever permanent codes are reassigned
+        #: the extension's caches of permanent codes (capsules): a
+        #: pointer-identity memo and a table keyed on a string's UTF-8
+        #: bytes, both read-through to _to_code; lazily created by
+        #: intern_array, dropped whenever permanent codes are reassigned
         self._id_memo = None
+        self._intern_table = None
 
     def encode(self, s: Optional[str]) -> int:
         if s is None:
@@ -155,27 +158,34 @@ class StringTable:
     def encode_many(self, values: Sequence[Optional[str]]) -> np.ndarray:
         return np.fromiter((self.encode(v) for v in values), dtype=np.int32, count=len(values))
 
-    def encode_array(self, values: np.ndarray) -> np.ndarray:
-        """Vectorized interning for a whole column (send_columns path):
-        native C loop when built, else a local-ref dict loop — both ~5x the
-        per-row encode() dispatch. (np.unique was measured and rejected:
-        sorting object arrays does Python-level compares.)"""
-        values = np.asarray(values, dtype=object)
+    def encode_array(self, values) -> np.ndarray:
+        """Vectorized interning for a whole column (send_columns path)."""
+        return self.intern_array(values)[0]
+
+    def intern_array(self, values) -> tuple[np.ndarray, int, int]:
+        """encode_array's codes, with how many values the extension took
+        and how many of them its byte-keyed table resolved: (codes, values,
+        hits); both counts 0 on the Python loop. The native call resolves
+        what its caches miss in value order through the same dict, so the
+        codes are the loop's. A list is passed as it is (the wire's
+        dictionaries); anything else becomes an object array. (np.unique was
+        measured and rejected: sorting object arrays does Python-level
+        compares.)"""
+        if not isinstance(values, list):
+            values = np.asarray(values, dtype=object)
         n = len(values)
         out = np.empty(n, dtype=np.int32)
         from .. import native as native_mod
-        if native_mod.native is not None:
-            if self._id_memo is None and \
-                    hasattr(native_mod.native, "idmemo_new"):
-                # pointer-identity fast path for producers that pool their
-                # string objects (see columnar.c); dropped on restore()
-                # because restore reassigns permanent codes
-                self._id_memo = native_mod.native.idmemo_new()
-            native_mod.native.intern_column(values, out, self._to_code,
-                                            self._to_str,
-                                            self._transient_code,
-                                            self._id_memo)
-            return out
+        nat = native_mod.native
+        if nat is not None:
+            if self._intern_table is None:
+                # dropped on restore(): restore reassigns permanent codes
+                self._id_memo = nat.idmemo_new()
+                self._intern_table = nat.intern_table_new()
+            hits = nat.intern_column(values, out, self._to_code,
+                                     self._to_str, self._transient_code,
+                                     self._id_memo, self._intern_table)
+            return out, n, hits
         to_code, to_str = self._to_code, self._to_str
         transient = self._transient_code
         for i, s in enumerate(values):
@@ -190,7 +200,7 @@ class StringTable:
                 to_code[s] = c
                 to_str.append(s)
             out[i] = c
-        return out
+        return out, 0, 0
 
     def decode_array(self, codes) -> list:
         """Vectorized decode: one list-index per row through a local ref,
@@ -217,7 +227,8 @@ class StringTable:
             snap = {"strings": snap, "transient": [], "transient_next": 0}
         strings = snap["strings"]
         # mutate in place: native encode plans hold references to these
-        self._id_memo = None  # permanent codes reassigned below
+        # permanent codes reassigned below
+        self._id_memo = self._intern_table = None
         self._to_str[:] = list(strings)
         self._to_code.clear()
         self._to_code.update(

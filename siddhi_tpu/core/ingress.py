@@ -253,6 +253,10 @@ class IngressPipeline:
         self._t0 = time.monotonic()
         self._worker_busy_ns = [0] * self.workers
         self._worker_runs = [0] * self.workers
+        # values the workers passed to the extension's interning, and of
+        # them those its byte-keyed table resolved (StringTable.intern_array)
+        self._intern_values = [0] * self.workers
+        self._intern_table_hits = [0] * self.workers
         self._batches = 0       # feeder only
         self._overlapped = 0    # feeder only
         self._on_starve = 0     # feeder only
@@ -494,7 +498,7 @@ class IngressPipeline:
             t0 = time.perf_counter_ns()
             try:
                 kind, start, m, ts, payload = item
-                intern_ns = intern_cpu = 0
+                intern_ns = intern_cpu = n_values = n_hits = 0
                 if kind == "rows":
                     if ordered:
                         # rows_to_columns interns inline (native
@@ -529,7 +533,9 @@ class IngressPipeline:
                                     took = True
                                 tbl = codec.string_tables[name]
                                 with interning() as span, intern_locked():
-                                    codes = tbl.encode_array(a)
+                                    codes, nv, nh = tbl.intern_array(a)
+                                n_values += nv
+                                n_hits += nh
                                 intern_ns += span.wall_ns
                                 intern_cpu += span.cpu_ns
                                 out.append(np.ascontiguousarray(
@@ -541,8 +547,9 @@ class IngressPipeline:
                                 tbl = codec.string_tables[name]
                                 with interning() as span:
                                     with intern_locked():
-                                        codes = tbl.encode_array(
-                                            np.asarray(a, dtype=object))
+                                        codes, nv, nh = tbl.intern_array(a)
+                                    n_values += nv
+                                    n_hits += nh
                                     # idx -1 = null -> code 0 via a shifted
                                     # LUT
                                     lut = np.empty(len(codes) + 1,
@@ -565,6 +572,8 @@ class IngressPipeline:
                 spent = time.perf_counter_ns() - t0
                 cells.book("intern", intern_ns, intern_cpu)
                 cells.book("decode", spent - intern_ns)
+                self._intern_values[wid] += n_values
+                self._intern_table_hits[wid] += n_hits
                 self._worker_busy_ns[wid] += spent
                 self._worker_runs[wid] += 1
                 self._feeder_idle.clear()
@@ -878,6 +887,8 @@ class IngressPipeline:
             "runs_in": self._runs_in,
             "frames_in": self._frames_in,
             "wire_native_frames": self._wire_native_frames,
+            "intern_values": sum(self._intern_values),
+            "intern_table_hits": sum(self._intern_table_hits),
             "batches_delivered": delivered,
             # a held batch leaves behind the next one's upload (overlapped),
             # when the ring runs empty (on starve) or at a flush (the rest)

@@ -401,3 +401,106 @@ class TestStatisticsSection:
                 assert key in sec
         finally:
             rt.shutdown()
+
+
+# ---------------------------------------- overlapping dictionaries, 4 producers
+#
+# Four producers post SXF1 frames whose dictionaries overlap (a 10,000-key
+# universe, 2,048 rows a frame) through four workers: the extension's
+# byte-keyed intern table answers for most values, off the interpreter lock.
+# A frame is one run and one batch, so the delivered blocks name the order
+# the runs interned in (their tickets); the synchronous path fed the frames
+# in that order must give the same codes and the same string table.
+
+WIRE_FRAME = 2048
+WIRE_KEYS = 10_000
+
+
+def _wire_app(asynchronous: bool) -> str:
+    return ((f"@app:name('Wire4')\n@Async(buffer.size='{WIRE_FRAME}', "
+             "workers='4')\n" if asynchronous else "@app:name('Wire1')\n")
+            + "define stream TradeStream "
+            "(symbol string, price double, volume long);\n"
+            "@info(name='q') from TradeStream "
+            "select symbol, price, volume insert into OutStream;")
+
+
+def _wire_frames(producers: int = 4, per_producer: int = 6):
+    """{frame's first timestamp: (producer, cols, ts)}: each frame's rows
+    drawn from the shared universe, with nulls, its stamps its own."""
+    frames = {}
+    for p in range(producers):
+        for f in range(per_producer):
+            rng = np.random.default_rng([53, p, f])
+            keys = rng.integers(0, WIRE_KEYS, WIRE_FRAME).tolist()
+            sym = np.array([f"K{k:05d}" for k in keys], dtype=object)
+            sym[rng.random(WIRE_FRAME) < 0.01] = None
+            ts = np.arange(WIRE_FRAME, dtype=np.int64) + \
+                (p * per_producer + f) * 100_000
+            frames[int(ts[0])] = (p, {
+                "symbol": sym,
+                "price": rng.uniform(1.0, 1000.0, WIRE_FRAME),
+                "volume": rng.integers(1, 1000, WIRE_FRAME)}, ts)
+    return frames
+
+
+def test_four_producers_overlapping_dictionaries_intern_as_the_serial_path():
+    from siddhi_tpu.io import wire
+    frames = _wire_frames()
+    seen: dict = {}
+
+    def body(h, cols, ts):
+        return wire.encode_frames(wire.schema_plan(h.junction.definition),
+                                  cols, WIRE_FRAME, ts=ts)
+
+    def feed_producers(h, rt):
+        p_stats = _pipeline_of(rt)
+        assert p_stats is not None, "pipeline did not engage"
+        mine = [[body(h, c, ts) for q, c, ts in frames.values() if q == p]
+                for p in range(4)]
+        start = threading.Barrier(4)
+
+        def produce(bodies):
+            start.wait()
+            for b in bodies:
+                assert wire.deliver_frames(h, b) == WIRE_FRAME
+
+        threads = [threading.Thread(target=produce, args=(b,))
+                   for b in mine]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        rt.flush()
+        seen["stats"] = p_stats.stats_snapshot()
+        seen["strings"] = h.junction.codec.string_tables["symbol"].snapshot()
+
+    pipe = _capture(_wire_app(True), feed_producers)
+    assert [len(b[0]) for b in pipe] == [WIRE_FRAME] * len(frames)
+    order = [int(b[0][0]) for b in pipe]  # the frames in ticket order
+    assert sorted(order) == sorted(frames)
+    serial_seen: dict = {}
+
+    def feed_serial(h, rt):
+        for first in order:
+            _, cols, ts = frames[first]
+            assert wire.deliver_frames(h, body(h, cols, ts)) == WIRE_FRAME
+            rt.flush()
+        serial_seen["strings"] = \
+            h.junction.codec.string_tables["symbol"].snapshot()
+
+    serial = _capture(_wire_app(False), feed_serial, batch_size=WIRE_FRAME)
+    _assert_blocks_identical(pipe, serial)
+    assert seen["strings"] == serial_seen["strings"]
+    # each distinct string misses the table at most once; every other value
+    # of every frame's dictionary is found in it (none with the Python path)
+    stats = seen["stats"]
+    distinct = len(seen["strings"]["strings"]) - 1  # code 0 is null
+    if native_mod.available():
+        assert stats["intern_values"] == sum(
+            len({s for s in c["symbol"] if s is not None})
+            for _, c, _ in frames.values())
+        assert stats["intern_table_hits"] >= \
+            stats["intern_values"] - distinct > 0
+    else:
+        assert stats["intern_values"] == stats["intern_table_hits"] == 0
