@@ -82,6 +82,25 @@ def aot_warm(jit_fn, *args) -> None:
     jit_fn.lower(*abstract).compile()
 
 
+def selector_round_width(window, selector, extras: dict,
+                         lanes: int) -> Optional[int]:
+    """The lanes a round of the selector takes over the window's chunk of
+    `lanes`, or None where it takes the whole chunk in one call. A sliding
+    window whose expiry width exceeds its batch (`@capacity(expire=...)`)
+    packs what left and what arrived at the front of a chunk mostly empty:
+    a lane-sequential selector then runs in rounds of one batch over that
+    prefix alone. A per-lane array among the scope's extras keeps the one
+    call."""
+    from ..ops.windows import SlidingWindow
+    if not (isinstance(window, SlidingWindow) and window.E > window.B
+            and selector.lane_sequential):
+        return None
+    if any(getattr(x, "shape", ())[:1] == (lanes,)
+           for x in jax.tree_util.tree_leaves(extras)):
+        return None
+    return window.B
+
+
 def _selects_aggregates(selector, registry) -> bool:
     """True if any select item contains an aggregator call — the same
     detection CompiledSelector performs, needed BEFORE the window is built
@@ -378,9 +397,14 @@ class QueryRuntime(Receiver):
         self.cells = (StageCells(("drop_sync",))
                       if isinstance(self.window, _Sliding) else None)
         self._out_lanes = 0
+        #: the lanes the selector ran over, summed on the device (the step
+        #: takes and returns it): a statistic, in no snapshot
+        self._selector_lanes = (jnp.int64(0) if self.cells is not None
+                                else None)
         self._loss_warned = False
         self.synced = {"live": 0, "live_hwm": 0, "appended": 0, "expired": 0,
-                       "ring_overflow": 0, "expiry_deferred": 0}
+                       "ring_overflow": 0, "expiry_deferred": 0,
+                       "selector_lanes": 0}
         self._capacity_warned = False
         self._capacity_pressure = False
         self._snapshot_warned = False
@@ -435,7 +459,8 @@ class QueryRuntime(Receiver):
                                 default=True)
             return batch
 
-        def step(state, batch: EventBatch, now, table_states=None):
+        def step(state, batch: EventBatch, now, table_states=None,
+                 selector_lanes=None):
             # trace-time side effect: fires once per compiled executable —
             # the per-query compile counter (recompile-storm observability).
             # Fused members suppress it: the SharedStepGroup counts ONE
@@ -499,7 +524,14 @@ class QueryRuntime(Receiver):
                                 sliding_extrema_lanes(
                                     eop, args[0](rscope), wstate_pre.expired,
                                     wstate_pre.appended, chunk, args[0](cscope))
-                sstate, out = selector.step(sstate, chunk, cscope)
+                width = selector_round_width(window, selector,
+                                             cscope.extras, chunk.capacity)
+                if width is None:
+                    sstate, out = selector.step(sstate, chunk, cscope)
+                    lanes = chunk.capacity
+                else:
+                    sstate, out, lanes = selector.step_in_rounds(
+                        sstate, chunk, cscope, width)
             with stage("emit"):
                 if getattr(limiter, "needs_window_contents", False):
                     # non-FIFO snapshot: per-arrival output is suppressed; ticks
@@ -529,7 +561,11 @@ class QueryRuntime(Receiver):
                 else:
                     rstate, out = limiter.step(rstate, out, now)
 
-            return (wstate, sstate, rstate), out
+            if selector_lanes is None:
+                return (wstate, sstate, rstate), out
+            # the lanes the selector ran over, summed: a statistic, carried
+            # beside the state and never in a snapshot
+            return (wstate, sstate, rstate), out, selector_lanes + lanes
 
         return step
 
@@ -544,6 +580,7 @@ class QueryRuntime(Receiver):
         self.state = state
         self._bucket_ok = True
         self.cells = None
+        self._selector_lanes = None
 
     # -------------------------------------------------------------- runtime
 
@@ -604,8 +641,10 @@ class QueryRuntime(Receiver):
         now = jnp.int64(self.ctx.timestamp_generator.current_time())
         for cap in buckets:
             batch = EventBatch.empty(self.input_junction.definition, cap)
+            lanes = (() if self._selector_lanes is None
+                     else (self._selector_lanes,))
             aot_warm(self._step, self.state, batch, now,
-                     self._table_states())
+                     self._table_states(), *lanes)
         return self.ctx.statistics.compiles.get(self.name, 0) - n0
 
     def on_batch(self, batch: EventBatch, now: int) -> None:
@@ -624,8 +663,13 @@ class QueryRuntime(Receiver):
         if self._in_fallbacks:
             self._maybe_in_fallback(batch, now)
         traced = self.ctx.statistics.compiles.get(self.name, 0)
-        self.state, out = self._step(self.state, batch, jnp.int64(now),
-                                     self._table_states())
+        if self._selector_lanes is None:
+            self.state, out = self._step(self.state, batch, jnp.int64(now),
+                                         self._table_states())
+        else:
+            self.state, out, self._selector_lanes = self._step(
+                self.state, batch, jnp.int64(now), self._table_states(),
+                self._selector_lanes)
         traced = self.ctx.statistics.compiles.get(self.name, 0) != traced
         self._out_lanes += out.capacity
         self._distribute(out, now)
@@ -673,9 +717,10 @@ class QueryRuntime(Receiver):
         step dispatched so far, so every 64th step and not each."""
         wstate = self.state[0]
         with self.cells.span("drop_sync", "siddhi.window.drop_sync"):
-            lost = jax.device_get((wstate.overflow, wstate.deferred))
-        self.synced["ring_overflow"], self.synced["expiry_deferred"] = \
-            int(lost[0]), int(lost[1])
+            lost = jax.device_get((wstate.overflow, wstate.deferred,
+                                   self._selector_lanes))
+        (self.synced["ring_overflow"], self.synced["expiry_deferred"],
+         self.synced["selector_lanes"]) = (int(v) for v in lost)
         if sum(self.synced[k] for k in ("ring_overflow", "expiry_deferred")):
             import warnings
             warnings.warn(
@@ -696,7 +741,8 @@ class QueryRuntime(Receiver):
         live = ws.appended - ws.expired
         held = {"live": live, "live_hwm": ws.live_hwm,
                 "appended": ws.appended, "expired": ws.expired,
-                "ring_overflow": ws.overflow, "expiry_deferred": ws.deferred}
+                "ring_overflow": ws.overflow, "expiry_deferred": ws.deferred,
+                "selector_lanes": self._selector_lanes}
         held = {k: jnp.copy(v) for k, v in held.items()}
         if report:
             self.state = (ws._replace(live_hwm=live), *self.state[1:])
@@ -708,7 +754,9 @@ class QueryRuntime(Receiver):
     def stats_snapshot(self) -> dict:
         """statistics_report()["windows"][name], for a query over a sliding
         window. `steps`, `out_lanes` (the out block's lanes, valid or not:
-        what the read-back fetches), `appended` and `expired` are
+        what the read-back fetches), `selector_lanes` (the lanes the
+        selector ran over: the chunk's width a step, or its rounds times
+        the batch where it runs in rounds), `appended` and `expired` are
         cumulative; `live` is the rows in the window after the last step
         synced, `live_hwm` the most since the statistics_report() before;
         the two loss counters are the device's as last synced."""

@@ -63,6 +63,10 @@ class AggregatorSpec:
     #: planners can swap in the removal-capable range-query path (the
     #: monotone component scan cannot undo removals)
     extrema_op: Optional[str] = None
+    #: custom_scan is a fold over the lanes in order, exact in integers:
+    #: a chunk cut into consecutive calls gives the same per-lane values
+    #: and the same state, bit for bit (ops/selector.py `lane_sequential`)
+    lane_sequential: bool = False
 
 
 class AggregatorFactory:
@@ -248,7 +252,8 @@ def _make_distinct_count(arg_types):
         return (kt2, pair_counts2, distinct2), out
 
     return AggregatorSpec((), lambda cs: cs[0], _T.LONG,
-                          init_custom=init_custom, custom_scan=custom_scan)
+                          init_custom=init_custom, custom_scan=custom_scan,
+                          lane_sequential=True)
 
 
 class HLLState(NamedTuple):

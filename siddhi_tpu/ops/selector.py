@@ -17,6 +17,7 @@ Aggregator calls may be nested inside arbitrary expressions
 from __future__ import annotations
 
 import dataclasses
+import functools
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -361,6 +362,92 @@ class CompiledSelector:
         return {string_table.decode(int(c)) for c in live}
 
     # ------------------------------------------------------------------- step
+
+    @functools.cached_property
+    def lane_sequential(self) -> bool:
+        """True where `step` is a fold over the chunk's lanes in order whose
+        rows and state come out bit for bit the same however the chunk is
+        cut into consecutive calls: no feature reads the chunk as a whole
+        (order by, offset, limit, one row per group, the window extrema's
+        range queries), no key table (it numbers a call's new keys in slot
+        order), and every scan exact under regrouping (integer sums, min and
+        max: a float sum rounds by the shape of its scan)."""
+        if (self.order_by or self.offset is not None
+                or self.limit is not None or self.emit_final_per_group
+                or self.extrema_plan or self.needs_key_table):
+            return False
+        for _, spec, _ in self.agg_specs:
+            if spec.custom_scan is not None:
+                state = jax.eval_shape(lambda: spec.init_custom(
+                    self.group_capacity, grouped=bool(self.group_vars)))
+                keyed = any(isinstance(x, KeyTable) for x in jax.tree.leaves(
+                    state, is_leaf=lambda x: isinstance(x, KeyTable)))
+                if keyed or not spec.lane_sequential:
+                    return False
+            elif not all(c.op in ("min", "max")
+                         or jnp.issubdtype(c.dtype, jnp.integer)
+                         or c.dtype == jnp.bool_ for c in spec.components):
+                return False
+        return True
+
+    def step_in_rounds(self, state: SelectorState, chunk: EventBatch,
+                       scope: Scope, width: int):
+        """`step` over the chunk's lanes up to its last valid one, in rounds
+        of `width` lanes carried through one loop; only where
+        `lane_sequential`, so that the rows and the state are `step`'s own.
+        At least one round runs. Returns (state, out, lanes run): `out` is
+        as wide as the chunk, and its lanes past the last round are invalid
+        rows with the chunk's stamps and types."""
+        L = chunk.capacity
+        padded = -(-L // width) * width
+
+        def pad(a):
+            return a if padded == L else jnp.pad(a, (0, padded - L))
+
+        ts, valid, types = pad(chunk.ts), pad(chunk.valid), pad(chunk.types)
+        cols = {k: pad(v) for k, v in chunk.cols.items()}
+        frames = {ref: ({k: pad(v) for k, v in fc.items()},
+                        pad(scope.ts[ref]), pad(scope.valids[ref]))
+                  for ref, fc in scope.frames.items()}
+        n_live = jnp.max(jnp.where(
+            valid, jnp.arange(1, padded + 1, dtype=jnp.int32), 0))
+        rounds = jnp.maximum(1, (n_live + width - 1) // width)
+
+        def one_round(r, sstate):
+            def cut(a):
+                return jax.lax.dynamic_slice_in_dim(a, r * width, width)
+
+            sub = Scope()
+            for ref, (fc, fts, fvalid) in frames.items():
+                sub.add_frame(ref, {k: cut(v) for k, v in fc.items()},
+                              cut(fts), cut(fvalid))
+            sub.default_frame = scope.default_frame
+            sub.extras = dict(scope.extras)
+            part = EventBatch(ts=cut(ts), cols={k: cut(v) for k, v in
+                                                cols.items()},
+                              valid=cut(valid), types=cut(types))
+            return self.step(sstate, part, sub)
+
+        shapes = jax.eval_shape(one_round, 0, state)[1].cols
+
+        def body(r, carry):
+            sstate, out_cols, out_valid = carry
+            sstate, out = one_round(r, sstate)
+            put = functools.partial(jax.lax.dynamic_update_slice_in_dim,
+                                    start_index=r * width, axis=0)
+            return (sstate, {k: put(v, out.cols[k])
+                             for k, v in out_cols.items()},
+                    put(out_valid, out.valid))
+
+        state, out_cols, out_valid = jax.lax.fori_loop(
+            0, rounds, body,
+            (state, {k: jnp.zeros((padded,), s.dtype)
+                     for k, s in shapes.items()},
+             jnp.zeros((padded,), bool)))
+        out = EventBatch(ts=chunk.ts,
+                         cols={k: v[:L] for k, v in out_cols.items()},
+                         valid=out_valid[:L], types=chunk.types)
+        return state, out, rounds * width
 
     def step(self, state: SelectorState, chunk: EventBatch,
              scope: Scope) -> tuple[SelectorState, EventBatch]:

@@ -355,9 +355,14 @@ def test_the_windows_account_and_its_high_water(deploy):
     first = d.rt.statistics_report()["windows"]["distinct"]
     assert set(first) == {"capacity", "expire_width", "steps", "out_lanes",
                           "live", "live_hwm", "appended", "expired",
-                          "ring_overflow", "expiry_deferred", "stage_ms"}
+                          "ring_overflow", "expiry_deferred",
+                          "selector_lanes", "stage_ms"}
     assert first["steps"] == 6
     assert first["out_lanes"] == 6 * (BATCH + 256)
+    # expire 256 > the batch: the selector ran in rounds of one batch over
+    # what arrived and left, at least one a step
+    assert first["selector_lanes"] % BATCH == 0
+    assert 6 * BATCH <= first["selector_lanes"] < first["out_lanes"]
     assert first["appended"] - first["expired"] == first["live"]
     assert first["live_hwm"] >= first["live"] > 0
     assert "drop_sync" in first["stage_ms"]
