@@ -9,11 +9,12 @@ every emitted row against a plain per-event reference kept in this file:
     pipeline --> junction --> jitted step on the device --> async read-back
     --> columnar callback
 
-The deployment is bench.py's `e2e_ingress` app (filter `price < 700.0` →
-`lengthBatch(10000)` sum/avg/count group by symbol) at the headline's key
-count: 1,000,000 distinct symbols, 131,072-lane batches, group capacity
-2**20. Prices are multiples of 0.25, so float32 partial sums are exact and
-the comparison can be too.
+The deployment is a two-query ingress app (filter `price < 700.0` →
+`lengthBatch(10000)` sum/avg/count group by symbol, fed by one
+`@Async(workers=4)` stream) at the flagship's key count: 1,000,000
+distinct symbols, 131,072-lane batches, group capacity 2**20. Prices are
+multiples of 0.25, so float32 partial sums are exact and the comparison can
+be too.
 
   phase A  one producer, 16 frames (2.1M events): every emitted row equals
            the reference's, in order.
@@ -270,8 +271,8 @@ class _Traffic:
 
 
 class _Deployment:
-    """One runtime built the way bench.py's e2e_ingress builds it, served
-    over a real socket, with a columnar callback collecting the output."""
+    """One runtime of `APP` with async callbacks, served over a real
+    socket, with a columnar callback collecting the output."""
 
     def __init__(self, name: str, sizes: Sizes, superstep: bool) -> None:
         from siddhi_tpu import SiddhiManager

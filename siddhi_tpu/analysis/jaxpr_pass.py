@@ -20,7 +20,7 @@ Hazards:
          or ordered jaxpr effects — that knocks pjit off its C++
          no-Python dispatch fastpath, re-paying interpreter overhead on
          every batch. `fastpath_certify(app)` returns the per-step
-         verdicts; tools/fastpath_gate.py keeps the in-tree bench apps
+         verdicts; tools/fastpath_gate.py keeps the apps of its inventory
          from regressing.
 
 Never raises over a query: one whose step cannot be traced here is skipped

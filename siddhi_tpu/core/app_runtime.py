@@ -945,7 +945,7 @@ class SiddhiAppRuntime:
         {query_name: fresh_compile_count}; a step that does not compile is
         logged at ERROR and handed back in the result's `failures`
         ({query_name: exception}) — a server keeps starting, a caller that
-        needs every step compiled (chip_smoke.py, bench.py) treats any
+        needs every step compiled (chip_smoke.py, the benchmark) treats any
         entry as fatal instead of meeting the same error later on a feeder
         thread."""
         import logging

@@ -1,9 +1,10 @@
 """Zero-copy parallel ingress pipeline (the host half of the perf story).
 
-BENCH_r04 measured the device sustaining 61–105M ev/s while e2e throughput
-topped out at 0.7–3M ev/s: the product is host-bound, not TPU-bound. The
-pipeline here closes that gap by overlapping the three host stages that the
-synchronous path runs strictly in sequence:
+Where a step program is cheap (a stateless filter's takes a fraction of a
+millisecond a batch), the host's decode, interning and upload set the pace,
+not the TPU (PERF.md §5 has the split per cell). The pipeline here narrows
+that gap by overlapping the three host stages that the synchronous path runs
+strictly in sequence:
 
     producers ──claim──▶ [decode/intern worker pool] ──publish──▶
         lock-free columnar ring ──pop──▶ [feeder] ──device_put──▶

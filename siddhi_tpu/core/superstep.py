@@ -4,8 +4,7 @@ The async ingress feeder (core/ingress.py) normally delivers one full ring
 chunk per controller-lock acquisition: one pjit dispatch per query (or
 fused group) per micro-batch, plus the host fan-out. At CPU/TPU dispatch
 cost ~0.1-6 ms that per-batch hop dominates the stateful laggards long
-before the kernels do (BENCH_r08: groupby 555k ev/s device vs 52.7M for
-the stateless filter kernel).
+before the kernels do.
 
 A superstep amortizes the hop: the feeder stages K consecutive full chunks
 into one `[K, B]` host block, uploads it with a single device_put, and the

@@ -32,9 +32,8 @@ The package threads one measurement substrate through the whole pipeline:
                  analyzed offline by `python -m siddhi_tpu.doctor`.
 
 Gating: SIDDHI_TELEMETRY=0 turns span/histogram recording off (the <5%
-overhead budget is measured by bench.py's e2e_ingress config and guarded by
-tests/test_telemetry.py); default is ON — the whole point is that production
-always has the data.
+overhead budget is guarded by tests/test_telemetry.py); default is ON — the
+whole point is that production always has the data.
 """
 
 from __future__ import annotations

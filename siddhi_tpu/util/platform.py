@@ -7,7 +7,7 @@ only accepts before the backend starts. Used by ``tests/conftest.py``, the
 test worker scripts and ``__graft_entry__``.
 
 ``configure_compile_cache`` is called by every entry point that compiles for
-the chip (``chip_smoke.py``, ``bench.py`` children, ``python -m
+the chip (``chip_smoke.py``, ``benchmarks/run.py``, ``python -m
 siddhi_tpu.service``) so that a second process does not compile what the
 first one already did.
 """

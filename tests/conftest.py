@@ -1,6 +1,6 @@
 """Test configuration: run everything on a virtual 8-device CPU mesh so sharding
 tests work without TPU hardware (the chip is reached only through
-`chip_smoke.py` and `bench.py`, never from pytest).
+`chip_smoke.py` and `benchmarks/run.py`, never from pytest).
 
 Also hosts the multi-process test harness: `worker_fleet` launches real OS
 worker processes (fresh interpreters — jax.distributed and service workers

@@ -1,31 +1,22 @@
-"""Shape-bucketed compile cache, AOT warmup, and the watchdogged bench.
+"""Shape-bucketed compile cache and AOT warmup.
 
 Covers the round-6 perf tentpole:
 - junctions pad partial micro-batches to power-of-two lane buckets, so a
   shape-polymorphic query step compiles at most log2(max_batch)+1 variants
   (visible through the new per-query compile counter in Statistics);
 - padded (bucketed) execution is bit-identical to full-capacity execution;
-- AOT warmup precompiles the whole ladder at start();
-- bench.py can never go dark again: a deliberately-hung config is bounded
-  by the parent-side watchdog and still yields a JSON line from partials.
+- AOT warmup precompiles the whole ladder at start().
 """
 
 from __future__ import annotations
 
-import json
 import math
-import os
-import subprocess
-import sys
-import time
 
 import pytest
 
 from siddhi_tpu import SiddhiManager
 from siddhi_tpu.core import dtypes
 from siddhi_tpu.errors import SiddhiAppCreationError
-
-BENCH = os.path.join(os.path.dirname(__file__), os.pardir, "bench.py")
 
 FILTER_APP = """
 define stream S (symbol string, price double, volume long);
@@ -233,48 +224,3 @@ class TestSetProjectionProvenance:
             rt.flush()
         assert got == [1, 2, 2, 3]
         rt.shutdown()
-
-
-class TestBenchWatchdog:
-    """Acceptance: per-config watchdogs provably bound a deliberately-hung
-    config — the `_hang` hidden config swallows the in-process alarm, so
-    only the parent-side deadline can stop it, and the emitted JSON line
-    must still carry the partial numbers."""
-
-    def test_hung_config_is_bounded_and_yields_partial_json(self):
-        budget = 6
-        t0 = time.monotonic()
-        r = subprocess.run(
-            [sys.executable, BENCH, "_hang",
-             f"--config-seconds={budget}", "--max-seconds=30"],
-            capture_output=True, text=True, timeout=90,
-            env=dict(os.environ, JAX_PLATFORMS="cpu"))
-        elapsed = time.monotonic() - t0
-        assert elapsed < 60, f"watchdog failed to bound the hang: {elapsed}s"
-        lines = [ln for ln in r.stdout.splitlines()
-                 if ln.startswith("{")]
-        assert lines, r.stdout + r.stderr
-        res = json.loads(lines[-1])
-        assert res["partial"] is True
-        assert "timeout" in res["error"]
-        assert res["stage_one"] == 1.0  # checkpointed number survived
-
-
-@pytest.mark.smoke
-@pytest.mark.slow
-def test_bench_filter_bounded_smoke():
-    """Smoke tier: a bounded `bench.py filter --max-seconds=60` run emits a
-    JSON line with the device-path number within the budget (possibly
-    tagged partial if the e2e leg did not fit — the device measure itself
-    compiles and runs in seconds on CPU)."""
-    r = subprocess.run(
-        [sys.executable, BENCH, "filter",
-         "--config-seconds=55", "--max-seconds=60"],
-        capture_output=True, text=True, timeout=120,
-        env=dict(os.environ, JAX_PLATFORMS="cpu",
-                 SIDDHI_E2E_BATCH="16384"))
-    lines = [ln for ln in r.stdout.splitlines() if ln.startswith("{")]
-    assert lines, r.stdout + r.stderr
-    res = json.loads(lines[-1])
-    assert res.get("metric", "").startswith("filter")
-    assert isinstance(res.get("value"), (int, float)), res

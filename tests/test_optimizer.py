@@ -12,6 +12,7 @@ from siddhi_tpu.analysis.optimizer import (
     DECLINE_OBJECT,
     DECLINE_PARTITION,
 )
+from siddhi_tpu.core.shared import group_cap
 
 pytestmark = pytest.mark.smoke
 
@@ -233,21 +234,33 @@ class TestReporting:
 
 
 class TestCompileCounts:
-    def test_fused_compiles_once_per_group(self):
+    @pytest.mark.parametrize("n_queries", [8, 64])
+    def test_fused_compiles_once_per_group(self, n_queries):
+        """Compiles scale with fused groups, not queries: one per group
+        with the optimizer, one per query without it."""
         app = STREAM + "".join(
             f"@info(name='q{i}') from S[price > {i}.0] select symbol "
-            f"insert into Out{i};\n" for i in range(8))
-        m, rt = _runtime(app, optimize=True)
-        rt.start()
-        _feed(rt, n=8)   # one full batch, one shape
-        comp = rt.statistics_report()["compiles"]
-        group_compiles = sum(v for k, v in comp.items()
-                             if k.startswith("shared:"))
-        member_compiles = sum(v for k, v in comp.items()
-                              if k.startswith("q"))
-        assert group_compiles == 1
+            f"insert into Out{i};\n" for i in range(n_queries))
+
+        def compiles(optimize):
+            m, rt = _runtime(app, optimize=optimize)
+            rt.start()
+            _feed(rt, n=8)   # one full batch, one shape
+            rep = rt.statistics_report()
+            m.shutdown()
+            comp = rep["compiles"]
+            return (sum(v for k, v in comp.items() if k.startswith("shared:")),
+                    sum(v for k, v in comp.items() if k.startswith("q")),
+                    rep["optimizer"])
+
+        group_compiles, member_compiles, opt = compiles(True)
+        assert opt["queries_fused"] == n_queries
+        assert opt["groups"] == -(-n_queries // group_cap())
+        assert group_compiles == opt["groups"]
         assert member_compiles == 0
-        m.shutdown()
+        group_compiles, member_compiles, _ = compiles(False)
+        assert group_compiles == 0
+        assert member_compiles == n_queries
 
 
 # ------------------------------------------------------------- dark sinks

@@ -146,10 +146,15 @@ class TestShardRouter:
 class TestParity:
     pytestmark = pytest.mark.smoke
 
-    def test_sharded_vs_serial_bit_identical(self):
+    @pytest.mark.parametrize("n", [1, 4, 8])
+    def test_sharded_vs_serial_bit_identical(self, n):
         rows = _rows(2000)
         mgr = SiddhiManager()
-        plane, got = _run(mgr, SHARDED_APP, rows)
+        app = SHARDED_APP.replace("n='4'", f"n='{n}'")
+        plane, got = _run(mgr, app, rows, shutdown=False)
+        assert plane.n_shards == n
+        assert plane.conservation_report()["conserved"] is True
+        plane.shutdown()
         _, want = _run(SiddhiManager(), SERIAL_APP, rows)
         assert len(got) == len(want) == len(rows)
         assert sorted(got) == sorted(want)  # multiset, exact floats

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Cost-model calibration gate (analysis/cost.py vs live telemetry).
 
-For every bench app in tools/fastpath_gate.py's inventory: predict state
+For every app in tools/fastpath_gate.py's inventory: predict state
 bytes and compile-ladder size statically, then build the real runtime,
 measure allocated device state (`measure_runtime_state_bytes`) and count
 actual warmup compiles, and fail if prediction drifts outside the band
@@ -29,7 +29,7 @@ from pathlib import Path
 REPO = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO))
 
-from fastpath_gate import APPS  # noqa: E402 — same-dir bench inventory
+from fastpath_gate import APPS  # noqa: E402 — same-dir app inventory
 
 
 def _ratio(live: float, predicted: float) -> float:
@@ -107,7 +107,7 @@ TRIPLE = re.compile(r"(\"\"\"|''')(.*?)\1", re.DOTALL)
 
 def _in_tree_app_strings():
     """Every triple-quoted SiddhiQL-looking string under tests/ + samples/
-    (same extraction as tests/test_lint.py's zero-FP sweep), plus the bench
+    (same extraction as tests/test_lint.py's zero-FP sweep), plus the gate's
     inventory itself."""
     for name, text in APPS.items():
         yield f"fastpath_gate:{name}", text

@@ -1,14 +1,14 @@
 #!/usr/bin/env python
-"""Fastpath zero-regression gate (SL204) over the in-tree bench apps.
+"""Fastpath zero-regression gate (SL204) over an inventory of in-tree apps.
 
-Every compiled step of every bench-suite app is certified against pjit's
+Every compiled step of every app in `APPS` is certified against pjit's
 C++ dispatch fastpath via `analysis.jaxpr_pass.fastpath_certify`: no host
-callback, no ordered effect. KNOWN_VETOED is EMPTY — every sort of the
-bench apps' steps runs on the device (ops/search.py) — and the gate is
-hard: ANY vetoed step in ANY bench app fails CI outright. A host callback
+callback, no ordered effect. KNOWN_VETOED is EMPTY — every sort of these
+apps' steps runs on the device (ops/search.py) — and the gate is hard: ANY
+vetoed step in ANY app of the inventory fails CI outright. A host callback
 in a step would also make the plan superstep-ineligible
 (core/superstep.py), so this gate doubles as the superstep-eligibility
-floor for the bench suite.
+floor for the inventory.
 
     python tools/fastpath_gate.py [--json]
 
@@ -24,8 +24,9 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
-# one entry per bench config in tools' bench suite (same SiddhiQL texts;
-# the bench functions build them inline so they are restated here)
+# the gate's own inventory (tools/cost_calibrate.py prices the same apps):
+# a filter, a group-by, a distinct count, a pattern, a join, an event-time
+# gate, the served ingress app and a sharded plane, one SiddhiQL text each
 APPS = {
     "filter": """
     define stream TradeStream (symbol string, price double, volume long);
@@ -89,9 +90,9 @@ APPS = {
     group by symbol
     insert into SummaryStream;
     """,
-    # the sharded execution plane's bench app (bench.py sharded_e2e): a
-    # key-local pipeline — windowless running aggregate grouped by the
-    # partition key — replicated per shard behind the partition-key router
+    # the sharded execution plane's app: a key-local pipeline — windowless
+    # running aggregate grouped by the partition key — replicated per shard
+    # behind the partition-key router
     "sharded_e2e": """
     @app:name('ShardedBench')
     @app:shards(n='4', key='symbol')
