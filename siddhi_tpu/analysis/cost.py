@@ -603,8 +603,10 @@ def _named_window_cost(name: str, defn, registry,
     return ec
 
 
-def _table_cost(name: str, defn, group_cap: int) -> ElementCost:
+def _table_cost(name: str, defn, group_cap: int, app=None) -> ElementCost:
     from ..core import dtypes
+    from ..core.table_index import COUNTERS, index_refusal
+    from ..ops.slot_table import ROW, buckets_for
 
     ec = ElementCost(name, "table")
     if defn.annotations and defn.annotation("store") is not None:
@@ -612,10 +614,8 @@ def _table_cost(name: str, defn, group_cap: int) -> ElementCost:
         ec.notes.append("@store record table: rows live host-side (only "
                         "the device cache would count; not modeled)")
         return ec
-    cap_ann = defn.annotation("capacity") if defn.annotations else None
-    cap = (int(cap_ann.element(None))
-           if cap_ann is not None and cap_ann.element(None)
-           else dtypes.config.default_table_capacity)
+    cap = dtypes.stated_capacity(defn.annotations).rows \
+        or dtypes.config.default_table_capacity
     attrs = {a.name: a.type for a in defn.attributes
              if a.type != AttributeType.OBJECT}
     if any(t is None for t in attrs.values()):
@@ -624,6 +624,12 @@ def _table_cost(name: str, defn, group_cap: int) -> ElementCost:
         return ec
     # TableState: cols + ts int64[C] + valid bool[C]  (core/table.py)
     ec.state_bytes = cap * (sum(_itemsize(t) for t in attrs.values()) + 8 + 1)
+    if app is not None and index_refusal(defn, app) is None:
+        # the device index of its key (core/table_index.py): the slot
+        # table's buckets, its count and the counters
+        ec.state_bytes += 4 * buckets_for(cap) * ROW + 4 + 8 * len(COUNTERS)
+        ec.notes.append("@PrimaryKey on the device index: a 128-word "
+                        "bucket per 8 stated rows")
     return ec
 
 
@@ -760,7 +766,7 @@ def compute_cost(app_or_plan, *, batch_size: int = 0,
                     _named_window_cost(sid, schema.defn, registry, batch_cap))
             elif schema.kind == "table" and schema.defn is not None:
                 report.elements.append(
-                    _table_cost(sid, schema.defn, group_cap))
+                    _table_cost(sid, schema.defn, group_cap, plan.app))
             elif schema.kind == "aggregation" and schema.defn is not None:
                 report.elements.append(
                     _aggregation_cost(sid, schema.defn, plan, registry,
@@ -920,7 +926,8 @@ def measure_runtime_state_bytes(rt) -> dict:
         state = getattr(t, "state", None)
         if state is None:
             state = getattr(t, "_state", None)
-        out[tname] = _tree_bytes(state)
+        out[tname] = _tree_bytes(state) + _tree_bytes(
+            getattr(t, "_index", None))
     for aname, a in getattr(rt, "aggregations", {}).items():
         out[aname] = _tree_bytes(a.state)
     return out

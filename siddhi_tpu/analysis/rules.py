@@ -229,11 +229,19 @@ def unbounded_join(plan: PlanGraph) -> Iterable:
         ins = node.query.input_stream
         if not isinstance(ins, JoinInputStream):
             continue
-        for side, label in ((ins.left, "left"), (ins.right, "right")):
+        kinds = []
+        for side in (ins.left, ins.right):
+            schema = plan.schemas.get(side.stream_id)
+            kinds.append(schema.kind if schema else "stream")
+        for (side, label), other in zip(((ins.left, "left"),
+                                         (ins.right, "right")),
+                                        reversed(kinds)):
             schema = plan.schemas.get(side.stream_id)
             kind = schema.kind if schema else "stream"
             if kind in ("table", "window", "aggregation"):
                 continue  # bounded by the store's own retention
+            if other in ("table", "aggregation"):
+                continue  # a table or an aggregation never probes this side
             if side.handlers.window is None:
                 yield _q(node, f"{label} join side {side.stream_id!r} has no "
                                "window: its join buffer grows without "
@@ -619,6 +627,42 @@ def pattern_on_threshold_plan(plan: PlanGraph) -> Iterable:
                        f"{stated.keys!r}), pending={stated.pending!r}); a "
                        "partial match whose row is full is dropped and "
                        "counted — see docs/PARITY.md")
+
+
+# ------------------------------------------------------------------- SL120
+# which engine a table with a primary key takes: the device index
+# (core/table_index.py: one program a write batch, a probe by the key one
+# lookup a lane) or the columnar [B, C] path. The decision is
+# core/table_index.index_refusal's, the one the runtime builds from.
+
+
+@rule("SL120", Severity.INFO,
+      "a table with a @PrimaryKey is written and probed through a device "
+      "index of its key, or says why it stays on the [B, C] path")
+def table_engine(plan: PlanGraph) -> Iterable:
+    from ..core import dtypes
+    from ..core.table_index import index_refusal
+    for tid, td in plan.app.table_definitions.items():
+        if td.annotation("PrimaryKey") is None:
+            continue
+        reason = index_refusal(td, plan.app)
+        if reason is None:
+            rows = dtypes.stated_capacity(td.annotations).rows \
+                or dtypes.config.default_table_capacity
+            yield _d(tid, td,
+                     f"table {tid!r} is written and probed through a device "
+                     f"index of its key: {rows} rows (@capacity(rows=...)); "
+                     "within a batch `update or insert` keeps the last "
+                     "event of a key, `insert into` the first; a key with "
+                     "no free row is dropped and counted in "
+                     "statistics_report()['tables'] — see docs/PARITY.md")
+        else:
+            yield _d(tid, td,
+                     f"table {tid!r} stays on the [B, C] path (a mask of "
+                     f"batch x rows a write) because {reason}; the device "
+                     "index takes a single int, long or string @PrimaryKey "
+                     "written by `insert into` and `update or insert ... on "
+                     "T.key == key` whose `set` reads no table attribute")
 
 
 # ----------------------------------------------------------- SL117 / SL118

@@ -317,7 +317,17 @@ class SiddhiAppRuntime:
                 self.tables[td.id] = RecordTableRuntime(
                     td, ctx, self.ctx.registry)
             else:
-                self.tables[td.id] = InMemoryTable(td, ctx)
+                from .table import refuse_masks
+                from .table_index import index_refusal, inserted_by
+                table = self.tables[td.id] = InMemoryTable(
+                    td, ctx, indexed=index_refusal(td, app) is None)
+                if table.primary_keys and table.index_key is None \
+                        and inserted_by(app, td.id):
+                    # the columnar insert's [B, C] and [B, B] key masks
+                    B = ctx.effective_batch_size
+                    refuse_masks(f"insert into table {td.id!r} (a primary "
+                                 "key on the [B, C] path)",
+                                 B * (table.capacity + B))
 
         from .window import NamedWindow
         for wd in app.window_definitions.values():
@@ -480,12 +490,23 @@ class SiddhiAppRuntime:
                 raise DefinitionNotExistError(f"table {out.target_id!r} is not defined")
             aliases = [getattr(query.input_stream, "stream_id", None),
                        getattr(query.input_stream, "reference_id", None)]
-            executor_cls = (RecordTableOutputExecutor
-                            if isinstance(table, RecordTableRuntime)
-                            else TableOutputExecutor)
+            from .table import IndexedTableWriter, refuse_masks
+            if isinstance(table, RecordTableRuntime):
+                executor_cls = RecordTableOutputExecutor
+            elif getattr(table, "index_key", None) is not None:
+                # index_refusal admitted every writer of the table
+                executor_cls = IndexedTableWriter
+            else:
+                executor_cls = TableOutputExecutor
             qr.table_executor = executor_cls(
                 table, out, qr.selector.out_types, qr.output_codec,
                 self.ctx.registry, out_frame_aliases=aliases)
+            if executor_cls is TableOutputExecutor:
+                refuse_masks(
+                    f"{out.action.name.lower().replace('_', ' ')} into table "
+                    f"{out.target_id!r} (query {qr.name!r})",
+                    qr.table_executor.mask_bytes(
+                        self.ctx.effective_batch_size))
 
     # ------------------------------------------------------ churn (splice)
     #
@@ -1524,8 +1545,11 @@ class SiddhiAppRuntime:
             keyed = {n: pr.keyed.device_counters()
                      for n, pr in self.partitions.items()
                      if pr.keyed is not None}
+            tables = {n: t.device_counters() for n, t in self.tables.items()
+                      if getattr(t, "index_key", None) is not None}
         # ONE device->host round trip
-        fetched, counted, keyed = jax.device_get((pending, patterns, keyed))
+        fetched, counted, keyed, tables = jax.device_get(
+            (pending, patterns, keyed, tables))
         for name, arrs in fetched.items():
             stats.record_overflow(name, int(sum(np.sum(a) for a in arrs)))
         for key, qr in joins.items():
@@ -1534,6 +1558,10 @@ class SiddhiAppRuntime:
             self.query_runtimes[n].sync_counters(values)
         for n, values in keyed.items():
             self.partitions[n].keyed.sync_counters(values)
+        for n, values in tables.items():
+            self.tables[n].sync_counters(values)
+            stats.record_overflow(f"table:{n}.table_rows_dropped",
+                                  self.tables[n].synced["dropped"])
 
     # ---------------------------------------------------------------- debugger
 

@@ -412,6 +412,13 @@ class Statistics:
                           if pr.keyed is not None}
             if partitions:
                 out["partitions"] = partitions
+            # tables on the device index (core/table_index.py): rows taken
+            # and stated, inserts, updates, drops, duplicates, probes, hits
+            tables = {name: t.stats_snapshot()
+                      for name, t in runtime.tables.items()
+                      if getattr(t, "index_key", None) is not None}
+            if tables:
+                out["tables"] = tables
         if runtime is not None:
             wal = getattr(runtime, "wal", None)
             if wal is not None:

@@ -181,18 +181,20 @@ class StatedCapacity(NamedTuple):
     pattern's partial matches per position (`pending`), a sliding window's
     ring rows (`window`) and the rows that may leave it in one step
     (`expire`); on a partition, the keys it holds state for (`keys`:
-    core/keyed_partition.py). None: the app says nothing, the defaults
-    hold."""
+    core/keyed_partition.py); on a table, its rows (`rows`: core/table.py).
+    None: the app says nothing, the defaults hold."""
 
     pending: Optional[int] = None
     window: Optional[int] = None
     expire: Optional[int] = None
     keys: Optional[int] = None
+    rows: Optional[int] = None
 
 
 def stated_capacity(annotations) -> StatedCapacity:
-    """The one parser of `@capacity(pending=, window=, expire=, keys=)`; whoever
-    builds the structure validates the number against what it sizes."""
+    """The one parser of `@capacity(pending=, window=, expire=, keys=,
+    rows=)`; whoever builds the structure validates the number against what
+    it sizes."""
     ann = next((a for a in (annotations or ())
                 if a.name.lower() == "capacity"), None)
     if ann is None:

@@ -42,7 +42,7 @@ from ..query_api.execution import (
     SingleInputStream,
 )
 from ..telemetry.tracing import StageCells, stage
-from . import dtypes
+from . import dtypes, table_index
 from .context import SiddhiAppContext
 from .event import EventBatch, EventType, StreamCodec
 from .query_runtime import QueryCallback
@@ -261,6 +261,10 @@ class JoinQueryRuntime:
                                         self.resolver, registry)
         self.plan_from_right = plan_join(jis.on, self.right.ref, self.left.ref,
                                          self.resolver, registry)
+        self.index_from_left = self._index_probe(jis.on, self.left,
+                                                 self.right, registry)
+        self.index_from_right = self._index_probe(jis.on, self.right,
+                                                  self.left, registry)
 
         # --- store-fallback key extraction for cached @store sides ---
         # (reference: AbstractQueryableRecordTable.java:109,207-238 — the
@@ -365,6 +369,28 @@ class JoinQueryRuntime:
             for s in (self.left, self.right))
 
     # ------------------------------------------------------------------- plan
+
+    def _index_probe(self, on, probe_side, build_side, registry):
+        """(key expression of the probe side, the key's dtype) where the
+        build side is a table on the device index (core/table_index.py) and
+        `on` equates its key to a probe-side expression at the top level;
+        else None: the sorted probe."""
+        table = build_side.table
+        key = getattr(table, "index_key", None)
+        if key is None or build_side.filters:
+            return None
+        from .table_index import probe_key
+        expr = probe_key(on, table.definition.id, build_side.ref, key,
+                         self.resolver, probe_side.ref)
+        if expr is None:
+            return None
+        compiled = compile_expression(expr, self.resolver, registry)
+        key_type = table.attr_types[key]
+        if compiled.type != key_type and not (
+                compiled.type == AttributeType.INT
+                and key_type == AttributeType.LONG):
+            return None  # a cast could equate unequal values
+        return compiled, table.state.cols[key].dtype
 
     def _simple_equi_pairs(self, on, probe_side, table_side):
         """(probe_attr, table_attr) pairs from `a.x == T.y` conjuncts —
@@ -496,6 +522,8 @@ class JoinQueryRuntime:
         probe_side = self.left if from_left else self.right
         build_side = self.right if from_left else self.left
         plan = self.plan_from_left if from_left else self.plan_from_right
+        index_probe = (self.index_from_left if from_left
+                       else self.index_from_right)
         selector = self.selector
         k_max = self.k_max
         within = self.within_ms
@@ -542,59 +570,72 @@ class JoinQueryRuntime:
                         mm_probe = multimap_append(mm_probe, hashes, live,
                                                    appended0)
 
-            with stage("probe"):
-                # --- build-side contents (multimap path never materializes
-                #     the full ring — candidates gather packed rows below) ---
-                if use_mm:
-                    b_cols = b_ts = b_valid = None
-                elif build_side.is_table:
-                    b_cols = build_tstate.cols
-                    b_ts = build_tstate.ts
-                    b_valid = build_tstate.valid
-                elif build_side.is_named_window:
-                    b_cols, b_ts, b_valid = build_side.named_window.contents(
-                        build_tstate, now)
-                elif build_side.is_aggregation:
-                    b_cols, b_ts, b_valid = build_side.agg_view.contents(
-                        build_tstate, now)
-                else:
-                    b_cols, b_ts, b_valid = build_side.window.contents(w_build, now)
-                if (not use_mm) and build_side.filters and (
-                        build_side.is_table or build_side.is_named_window
-                        or build_side.is_aggregation):
-                    # stream sides are filtered before their ring append; probed
-                    # contents (tables / named windows) are filtered here
-                    bscope = Scope()
-                    bscope.add_frame(build_side.ref, b_cols, b_ts, b_valid,
-                                     default=True)
-                    bscope.extras["now"] = now
-                    for f in build_side.filters:
-                        b_valid = b_valid & f(bscope)
-
-                # --- candidate pairs ---
+            if index_probe is not None:
+                # one bucket lookup and one row gather a lane: at most one
+                # match, the rest of `on` judged on the gathered row
+                index, tstate = build_tstate
+                key_expr, key_dtype = index_probe
+                C_t = tstate.ts.shape[0]
+                slot, pv, counts = table_index.probe(
+                    index, C_t, key_expr(pscope).astype(key_dtype), mask)
+                lane = jax.lax.iota(jnp.int32, batch.ts.shape[0])
+                brow = jnp.minimum(slot, C_t - 1)
+                b_cols, b_ts = tstate.cols, tstate.ts
                 truncated = jnp.int32(0)
-                if use_mm:
-                    bw = build_side.window
-                    window_len = w_build.appended - jnp.maximum(
-                        w_build.expired, w_build.appended - bw.C)
-                    lane, brow, pv, truncated = probe_equi_mm(
-                        plan, pscope, mask, mm_build, w_build.appended,
-                        window_len, k_max)
-                    if bw.time_ms is not None:
-                        # probe-time expiry BEFORE pair compaction, mirroring
-                        # SlidingWindow.contents(): a time window whose own side
-                        # went idle holds rows past their deadline that would
-                        # otherwise consume pair_cap slots and evict live matches
-                        tsw = w_build.ring[-2:, brow]
-                        cand_ts = jax.lax.bitcast_convert_type(
-                            jnp.stack([tsw[0], tsw[1]], axis=-1), jnp.int64)
-                        pv = pv & (cand_ts + jnp.int64(bw.time_ms) > now)
-                elif plan.probe_keys:
-                    lane, brow, pv = probe_equi(
-                        plan, pscope, mask, b_cols, b_ts, b_valid,
-                        build_side.ref, k_max)
-                else:
-                    lane, brow, pv = probe_cross(mask, b_valid, k_max)
+            else:
+                with stage("probe"):
+                    # --- build-side contents (multimap path never materializes
+                    #     the full ring — candidates gather packed rows below) ---
+                    if use_mm:
+                        b_cols = b_ts = b_valid = None
+                    elif build_side.is_table:
+                        b_cols = build_tstate.cols
+                        b_ts = build_tstate.ts
+                        b_valid = build_tstate.valid
+                    elif build_side.is_named_window:
+                        b_cols, b_ts, b_valid = build_side.named_window.contents(
+                            build_tstate, now)
+                    elif build_side.is_aggregation:
+                        b_cols, b_ts, b_valid = build_side.agg_view.contents(
+                            build_tstate, now)
+                    else:
+                        b_cols, b_ts, b_valid = build_side.window.contents(w_build, now)
+                    if (not use_mm) and build_side.filters and (
+                            build_side.is_table or build_side.is_named_window
+                            or build_side.is_aggregation):
+                        # stream sides are filtered before their ring append; probed
+                        # contents (tables / named windows) are filtered here
+                        bscope = Scope()
+                        bscope.add_frame(build_side.ref, b_cols, b_ts, b_valid,
+                                         default=True)
+                        bscope.extras["now"] = now
+                        for f in build_side.filters:
+                            b_valid = b_valid & f(bscope)
+
+                    # --- candidate pairs ---
+                    truncated = jnp.int32(0)
+                    if use_mm:
+                        bw = build_side.window
+                        window_len = w_build.appended - jnp.maximum(
+                            w_build.expired, w_build.appended - bw.C)
+                        lane, brow, pv, truncated = probe_equi_mm(
+                            plan, pscope, mask, mm_build, w_build.appended,
+                            window_len, k_max)
+                        if bw.time_ms is not None:
+                            # probe-time expiry BEFORE pair compaction, mirroring
+                            # SlidingWindow.contents(): a time window whose own side
+                            # went idle holds rows past their deadline that would
+                            # otherwise consume pair_cap slots and evict live matches
+                            tsw = w_build.ring[-2:, brow]
+                            cand_ts = jax.lax.bitcast_convert_type(
+                                jnp.stack([tsw[0], tsw[1]], axis=-1), jnp.int64)
+                            pv = pv & (cand_ts + jnp.int64(bw.time_ms) > now)
+                    elif plan.probe_keys:
+                        lane, brow, pv = probe_equi(
+                            plan, pscope, mask, b_cols, b_ts, b_valid,
+                            build_side.ref, k_max)
+                    else:
+                        lane, brow, pv = probe_cross(mask, b_valid, k_max)
             with stage("compact"):
                 # compact the sparse [B*k_max] block before any per-pair gather —
                 # frame materialization, verification, and the selector then run
@@ -614,7 +655,10 @@ class JoinQueryRuntime:
 
             # --- pair frames ---
             with stage("frames"):
-                p_cols, p_ts = _gather_frame(batch.cols, batch.ts, lane)
+                if index_probe is not None:
+                    p_cols, p_ts = batch.cols, batch.ts  # lane i is lane i
+                else:
+                    p_cols, p_ts = _gather_frame(batch.cols, batch.ts, lane)
                 if use_mm:
                     rows = w_build.ring[:, brow]  # [W, P] packed lane gather
                     g_cols, g_ts = _unpack_rows(rows, build_side.window.layout)
@@ -690,6 +734,9 @@ class JoinQueryRuntime:
             new_wl, new_wr = (w_probe, w_build) if from_left else (w_build, w_probe)
             new_mml, new_mmr = ((mm_probe, mm_build) if from_left
                                 else (mm_build, mm_probe))
+            if index_probe is not None:
+                return ((new_wl, new_wr, new_mml, new_mmr, sel), out, dropped,
+                        counts)
             return (new_wl, new_wr, new_mml, new_mmr, sel), out, dropped
 
         return step
@@ -715,14 +762,7 @@ class JoinQueryRuntime:
                             and not from_left))
             if not triggers:
                 continue
-            if build.is_table:
-                tstate = build.table.state
-            elif build.is_named_window:
-                tstate = build.named_window.state
-            elif build.is_aggregation:
-                tstate = build.agg_view.state
-            else:
-                tstate = None
+            tstate = self._build_state(from_left)
             step = self._step_left if from_left else self._step_right
             batch = EventBatch.empty(side.junction.definition,
                                      side.junction.batch_size)
@@ -741,17 +781,11 @@ class JoinQueryRuntime:
                     or (self.trigger == EventTrigger.LEFT and from_left)
                     or (self.trigger == EventTrigger.RIGHT and not from_left))
         step = self._step_left if from_left else self._step_right
-        if build.is_table:
-            if getattr(build, "_fallback_pairs", None) is not None or \
-                    getattr(build, "_fallback_cond", None) is not None:
-                self._maybe_store_fallback(build, side, batch)
-            tstate = build.table.state
-        elif build.is_named_window:
-            tstate = build.named_window.state
-        elif build.is_aggregation:
-            tstate = build.agg_view.state
-        else:
-            tstate = None
+        if build.is_table and (
+                getattr(build, "_fallback_pairs", None) is not None
+                or getattr(build, "_fallback_cond", None) is not None):
+            self._maybe_store_fallback(build, side, batch)
+        tstate = self._build_state(from_left)
         if not triggers:
             # non-triggering side still feeds its window (+ multimap)
             if side.is_table or side.is_named_window or side.is_aggregation:
@@ -768,8 +802,10 @@ class JoinQueryRuntime:
         side_name = "left" if from_left else "right"
         with self.cells.span("step_" + side_name, "siddhi.join.step",
                              side=side_name):
-            self.state, out, dropped = step(self.state, batch,
-                                            jnp.int64(now), tstate)
+            self.state, out, dropped, *counts = step(
+                self.state, batch, jnp.int64(now), tstate)
+        if counts:  # the table index's probes and hits, on the device
+            build.table.set_counts(counts[0])
         self._out_lanes += out.capacity
         self._candidate_lanes += batch.capacity * self.k_max
         # accumulate on device; sync only at checkpoints (an int() every
@@ -792,6 +828,22 @@ class JoinQueryRuntime:
                     stacklevel=2)
                 self._drop_warned = True
         self._distribute(out, now)
+
+    def _build_state(self, from_left: bool):
+        """What the step reads of its build side when that side is no
+        stream: a table's state (with its index first, where the step
+        probes it), a named window's, an aggregation's."""
+        build = self.right if from_left else self.left
+        if build.is_table:
+            if (self.index_from_left if from_left
+                    else self.index_from_right) is not None:
+                return build.table.index_state(), build.table.state
+            return build.table.state
+        if build.is_named_window:
+            return build.named_window.state
+        if build.is_aggregation:
+            return build.agg_view.state
+        return None
 
     def stats_snapshot(self) -> dict:
         """statistics_report()["joins"][name]. `steps`, `out_lanes` (the

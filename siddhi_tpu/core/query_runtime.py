@@ -645,6 +645,10 @@ class QueryRuntime(Receiver):
                      else (self._selector_lanes,))
             aot_warm(self._step, self.state, batch, now,
                      self._table_states(), *lanes)
+            warm = getattr(self.table_executor, "warm", None)
+            if warm is not None:
+                warm(EventBatch.empty(self.output_definition, cap),
+                     self.query.output_stream.event_type)
         return self.ctx.statistics.compiles.get(self.name, 0) - n0
 
     def on_batch(self, batch: EventBatch, now: int) -> None:
@@ -920,6 +924,10 @@ class QueryRuntime(Receiver):
             self.output_junction.publish_batch(fwd, now)
         elif action in (OutputAction.DELETE, OutputAction.UPDATE,
                         OutputAction.UPDATE_OR_INSERT) and self.table_executor is not None:
+            if getattr(self.table_executor, "takes_output", False):
+                # one table-write program selects and writes
+                self.table_executor.apply_output(out, etype)
+                return
             fwd = self._select_event_type(out, etype)
             self.table_executor.apply(fwd)
 

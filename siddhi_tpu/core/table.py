@@ -19,11 +19,16 @@ vectorized:
   sequentially; per-row multi-event read-modify-write chains are the one
   divergence, documented in tests);
 - insert/update-or-insert scatter into free slots computed by stable argsort
-  of the validity mask.
+  of the validity mask; an update-or-insert's events that no row matched
+  insert in arrival order (a later one that matches an earlier one's new row
+  updates it).
 
-The reference's primary-key/index holders become: primary key = compiled
-key-equality condition used by update-or-insert/contains fast paths; duplicate
-primary-key inserts are dropped (reference throws; we surface a counter).
+The reference's primary-key/index holders become: a single-attribute primary
+key whose writers the device index can take is that index
+(core/table_index.py: one program a write batch, one lookup a join probe, no
+[B,C] mask); any other primary key is a compiled key-equality condition, its
+inserts deduplicated by [B,C] and [B,B] masks. Duplicate primary-key inserts
+are dropped (reference throws; we surface a counter).
 """
 
 from __future__ import annotations
@@ -44,6 +49,19 @@ from ..query_api.expression import Compare, CompareOp, Expression, Variable
 from . import dtypes
 from .context import SiddhiAppContext
 from .event import EventBatch, EventType, StreamCodec
+
+
+def refuse_masks(what: str, nbytes: int) -> None:
+    """Refuse at build a columnar-path program whose cross masks exceed the
+    device's memory (it would fail at its first batch)."""
+    stats = jax.devices()[0].memory_stats() or {}
+    limit = int(stats.get("bytes_limit", 16 << 30))
+    if nbytes > limit:
+        raise SiddhiAppCreationError(
+            f"{what}: its [B, C] masks take {nbytes:,} bytes a batch, more "
+            f"than the device's {limit:,}; a single-attribute @PrimaryKey "
+            "written by `insert into` or `update or insert ... on "
+            "T.key == key` takes the device index instead (SL120)")
 
 
 class TableState(NamedTuple):
@@ -77,14 +95,14 @@ class InMemoryTable:
     """Host handle owning the device TableState + compiled per-query ops."""
 
     def __init__(self, definition: TableDefinition, ctx: SiddhiAppContext,
-                 capacity: Optional[int] = None) -> None:
+                 capacity: Optional[int] = None,
+                 indexed: bool = False) -> None:
         self.definition = definition
         self.ctx = ctx
         self.codec = StreamCodec(definition, ctx.global_strings)
-        cap_ann = definition.annotation("capacity") if definition.annotations else None
-        self.capacity = capacity or (
-            int(cap_ann.element(None)) if cap_ann is not None and cap_ann.element(None)
-            else dtypes.config.default_table_capacity)
+        self.capacity = capacity or dtypes.stated_capacity(
+            definition.annotations).rows \
+            or dtypes.config.default_table_capacity
         self.attr_types = {a.name: a.type for a in definition.attributes
                           if a.type != AttributeType.OBJECT}
         self._state = TableState(
@@ -115,7 +133,25 @@ class InMemoryTable:
                     f"@Index({a!r}): bool attributes are not indexable")
         self._indexes = None  # dict[attr, (sorted_vals[C], n_live)] | None
         self._index_fn = None
-        self.dropped_duplicates = 0
+        self._host_duplicates = 0
+        # the device index of a single-attribute primary key
+        # (core/table_index.py), where the app's text lets it take every
+        # write: the key's slot is the row
+        self.index_key: Optional[str] = None
+        self._index = None
+        if indexed:
+            from . import table_index
+            from ..telemetry.tracing import StageCells
+            (self.index_key,) = self.primary_keys
+            self._index = table_index.init_index(self.capacity)
+            self._index_stale = False
+            self._insert_writer = None
+            self._rebuild_fn = None
+            self.steps = 0
+            self.synced = {n: 0 for n in ("rows",) + table_index.COUNTERS}
+            self._drop_warned = False
+            # statistics_report()["tables"][id]["stage_ms"]
+            self.cells = StageCells(("write", "drop_sync"))
         self._insert_fn = jax.jit(self._make_insert())
 
     # ------------------------------------------------------------------ state
@@ -128,6 +164,10 @@ class InMemoryTable:
     def state(self, new_state: TableState) -> None:
         self._state = new_state
         self._indexes = None  # any mutation invalidates the sorted copies
+        if self.index_key is not None:
+            # written past the index (a restore, an on-demand query): made
+            # anew before its next use
+            self._index_stale = True
 
     def clear(self) -> None:
         """Reset to empty, keeping compiled kernels and capacity."""
@@ -136,6 +176,100 @@ class InMemoryTable:
             ts=jnp.zeros_like(self._state.ts),
             valid=jnp.zeros_like(self._state.valid),
         )
+
+    # ------------------------------------------------------------ the index
+
+    @property
+    def dropped_duplicates(self) -> int:
+        """Inserts of a key the table held, dropped (upstream throws)."""
+        if self.index_key is not None:
+            self.sync_counters(jax.device_get(self.device_counters()))
+            return self.synced["duplicates"]
+        return self._host_duplicates
+
+    def index_state(self):
+        """The device index, made anew first where a write went past it."""
+        if self._index_stale:
+            from . import table_index
+            if self._rebuild_fn is None:
+                self._rebuild_fn = jax.jit(table_index.make_rebuild(
+                    self._dtypes(), self.index_key, self.capacity))
+            s = self._state
+            cols, ts, valid, self._index = self._rebuild_fn(
+                s.cols, s.ts, s.valid, self._index.counts)
+            self._state = TableState(cols, ts, valid)
+            self._index_stale = False
+        return self._index
+
+    def _dtypes(self) -> dict:
+        return {a: v.dtype for a, v in self._state.cols.items()}
+
+    def insert_writer(self):
+        """The table-write program (`jit_table_write_<table>`) of a plain
+        `insert into`: `(cols, ts, valid, index, out_cols, out_ts, live,
+        set_vals) -> (cols, ts, valid, index)`, the table's state donated."""
+        if self._insert_writer is None:
+            from . import table_index
+            from .join_runtime import _named
+            body = table_index.make_write(self._dtypes(), self.index_key,
+                                          self.capacity, False)
+            self._insert_writer = jax.jit(
+                _named(body, f"table_write_{self.definition.id}"),
+                donate_argnums=(0, 1, 2, 3))
+        return self._insert_writer
+
+    def write_indexed(self, fn, *args) -> None:
+        """One table write through the index: a device program, no sync
+        (the counters are fetched every SYNC_EVERY-th write)."""
+        from .table_index import SYNC_EVERY
+        index = self.index_state()
+        s = self._state
+        with self.cells.span("write", "siddhi.table.write",
+                             table=self.definition.id):
+            cols, ts, valid, self._index = fn(s.cols, s.ts, s.valid, index,
+                                              *args)
+        self._state = TableState(cols, ts, valid)
+        self._indexes = None
+        self.steps += 1
+        if not self._drop_warned and self.steps % SYNC_EVERY == 0:
+            # a device sync under the controller lock, as the join's: it
+            # waits for every program dispatched so far
+            with self.cells.span("drop_sync", "siddhi.table.drop_sync",
+                                 table=self.definition.id):
+                self.sync_counters(jax.device_get(self.device_counters()))
+
+    def set_counts(self, counts) -> None:
+        """The counters a join's probe program returned."""
+        self._index = self._index._replace(counts=counts)
+
+    def device_counters(self) -> dict:
+        """Copies of the index's counters for one fetch (under the
+        controller lock: the next write donates them)."""
+        index = self.index_state()
+        return {"rows": jnp.copy(index.slots.count),
+                "counts": jnp.copy(index.counts)}
+
+    def sync_counters(self, fetched: dict) -> None:
+        from .table_index import COUNTERS
+        self.synced["rows"] = int(fetched["rows"])
+        self.synced.update(
+            {n: int(v) for n, v in zip(COUNTERS, fetched["counts"])})
+        if self.synced["dropped"] and not self._drop_warned:
+            import warnings
+            warnings.warn(
+                f"table {self.definition.id!r}: {self.synced['dropped']} "
+                f"writes of keys beyond its {self.capacity} rows were "
+                "dropped — raise @capacity(rows=...) on the table",
+                stacklevel=2)
+            self._drop_warned = True
+
+    def stats_snapshot(self) -> dict:
+        """statistics_report()["tables"][id]. `rows` (rows taken) and the
+        device counters are as last synced: at a report and every 64th
+        write; `steps` (writes) is the host's count."""
+        return {"capacity": self.capacity, "key": self.index_key,
+                "steps": self.steps, **self.synced,
+                "stage_ms": self.cells.snapshot()}
 
     def probe_indexes(self) -> dict:
         """Sorted-copy indexes for in-kernel equality probes; rebuilt lazily
@@ -206,6 +340,13 @@ class InMemoryTable:
         return insert
 
     def insert_batch(self, batch: EventBatch) -> None:
+        if self.index_key is not None:
+            # through the index: the first event of a key wins, the others
+            # are counted as duplicates; a key with no free row is dropped
+            # and counted
+            self.write_indexed(self.insert_writer(), batch.cols,
+                               batch.ts, batch.valid, {})
+            return
         new_state, overflow, dropped = self._insert_fn(self.state, batch)
         ov = int(overflow)
         if ov:
@@ -214,7 +355,7 @@ class InMemoryTable:
                 f"table {self.definition.id} capacity {self.capacity} exceeded "
                 f"({ov} rows would be dropped)")
         self.state = new_state
-        self.dropped_duplicates += int(dropped)
+        self._host_duplicates += int(dropped)
 
     def insert_rows(self, rows, timestamp: int = 0) -> None:
         cols = self.codec.rows_to_columns(rows, n_pad=len(rows))
@@ -268,6 +409,8 @@ class InMemoryTable:
         return out
 
     def all_rows(self) -> list[tuple]:
+        if self.index_key is not None:
+            self.index_state()
         batch = EventBatch(ts=self.state.ts, cols=self.state.cols,
                            valid=self.state.valid,
                            types=jnp.zeros((self.capacity,), jnp.int8))
@@ -336,6 +479,16 @@ class TableOutputExecutor:
 
         self._fn = jax.jit(self._make())
 
+    def mask_bytes(self, batch: int) -> int:
+        """Bytes of the `[B, C]` masks one batch of `batch` lanes builds:
+        the condition's, and one per `set` value."""
+        per = 1 + sum(jnp.dtype(dtypes.device_dtype(ce.type)).itemsize
+                      for _, ce in self.sets)
+        rows = self.table.capacity
+        if self.action == OutputAction.UPDATE_OR_INSERT:
+            rows += batch  # the batch's own new rows, in arrival order
+        return batch * rows * per
+
     def _make(self):
         from ..ops.expr_compile import Scope
 
@@ -377,10 +530,27 @@ class TableOutputExecutor:
             if action == OutputAction.UPDATE:
                 return updated, jnp.int32(0)
 
-            # update-or-insert: events matching no row are inserted
-            ev_matched = mask.any(axis=1)
-            to_insert = dataclasses.replace(out, valid=out.valid & ~ev_matched)
-            return updated, to_insert
+            # update-or-insert: events matching no row are inserted, in
+            # arrival order as a per-event evaluation inserts them: a later
+            # such event whose condition holds against an earlier one's new
+            # row updates that row instead (the last one's `set` wins)
+            ins = out.valid & ~mask.any(axis=1)
+            lanes = jnp.arange(B)
+            rows = TableState(
+                {k: out.cols[k] if k in out.cols else jnp.zeros((B,), v.dtype)
+                 for k, v in tstate.cols.items()}, out.ts, ins)
+            s3 = _broadcast_scope(scope, tid, rows)
+            found = jnp.broadcast_to(cond(s3), (B, B)) & ins[:, None] \
+                & ins[None, :] & (lanes[None, :] < lanes[:, None])
+            lead = ins & ~found.any(axis=1)
+            has = found.any(axis=0)
+            b_star = (B - 1) - jnp.argmax(found[::-1, :], axis=0)
+            cols = dict(out.cols)
+            for name, ce in sets:
+                vals = jnp.broadcast_to(ce(s3), (B, B))
+                picked = vals[b_star, lanes].astype(tstate.cols[name].dtype)
+                cols[name] = jnp.where(has, picked, rows.cols[name])
+            return updated, dataclasses.replace(out, cols=cols, valid=lead)
 
         return run
 
@@ -391,3 +561,92 @@ class TableOutputExecutor:
             self.table.insert_batch(to_insert)
         else:
             self.table.state, _ = self._fn(self.table.state, out)
+
+
+class IndexedTableWriter:
+    """`update or insert into T ... on T.key == key` on a table with the
+    device index (core/table_index.py): one program a batch
+    (`jit_table_write_<table>`), the output's event-type selection inside
+    it, no `[B, C]` mask and no sync. Within a batch the events of a key
+    apply in arrival order: the last wins what `set` writes."""
+
+    #: QueryRuntime._distribute hands the raw output and its event type
+    takes_output = True
+
+    def __init__(self, table: InMemoryTable, output_stream: OutputStream,
+                 out_types: dict[str, AttributeType],
+                 out_codec: StreamCodec, registry,
+                 out_frame_aliases: Sequence[str] = ()) -> None:
+        from ..ops.expr_compile import TypeResolver, compile_expression
+
+        self.table = table
+        tid = table.definition.id
+        missing = [a for a in table.attr_types if a not in out_types]
+        if missing:
+            raise SiddhiAppCreationError(
+                f"update or insert into {tid}: the output has no "
+                f"{missing} for the row it inserts")
+        frames = {"__out__": dict(out_types)}
+        codecs = {"__out__": out_codec}
+        self.aliases = tuple(a for a in out_frame_aliases
+                             if a and a not in (tid, "__out__"))
+        for alias in self.aliases:
+            frames[alias] = dict(out_types)
+            codecs[alias] = out_codec
+        resolver = TypeResolver(frames, "__out__", codecs)
+        key = table.index_key
+        if output_stream.set_attributes:
+            self.sets = [(sa.table_variable.attribute,
+                          compile_expression(sa.expression, resolver,
+                                             registry))
+                         for sa in output_stream.set_attributes]
+        else:
+            # upstream's default: every table attribute from the output's
+            # of its name; the key equals the row's by the condition
+            self.sets = [(a, compile_expression(
+                Variable(a, stream_id="__out__"), resolver, registry))
+                for a in table.attr_types if a != key]
+        self._fns: dict = {}
+
+    def _program(self, etype):
+        fn = self._fns.get(etype)
+        if fn is None:
+            from ..ops.expr_compile import Scope
+            from . import table_index
+            from .query_runtime import QueryRuntime
+            t = self.table
+            write = table_index.make_write(t._dtypes(), t.index_key,
+                                           t.capacity, True)
+            sets, aliases = self.sets, self.aliases
+
+            def program(cols, ts, valid, index, out):
+                out = QueryRuntime._select_event_type(out, etype)
+                scope = Scope()
+                scope.add_frame("__out__", out.cols, out.ts, out.valid,
+                                default=True)
+                for alias in aliases:
+                    scope.add_frame(alias, out.cols, out.ts, out.valid)
+                set_vals = {a: jnp.broadcast_to(ce(scope), out.ts.shape)
+                            for a, ce in sets}
+                return write(cols, ts, valid, index, out.cols, out.ts,
+                             out.valid, set_vals)
+
+            program.__name__ = program.__qualname__ = \
+                f"table_write_{self.table.definition.id}"
+            fn = self._fns[etype] = jax.jit(program,
+                                            donate_argnums=(0, 1, 2, 3))
+        return fn
+
+    def apply_output(self, out: EventBatch, etype) -> None:
+        self.table.write_indexed(self._program(etype), out)
+
+    def apply(self, out: EventBatch) -> None:
+        from ..query_api.execution import OutputEventType
+        self.apply_output(out, OutputEventType.CURRENT)
+
+    def warm(self, out: EventBatch, etype) -> None:
+        """Compile the program for `out`'s shapes without running it."""
+        from .query_runtime import aot_warm
+        s = self.table.state
+        aot_warm(self._program(etype), s.cols, s.ts, s.valid,
+                 self.table.index_state(), out)

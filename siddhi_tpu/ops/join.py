@@ -39,7 +39,8 @@ from .search import (
 from ..core import dtypes
 from ..errors import SiddhiAppCreationError
 from ..query_api.definition import AttributeType
-from ..query_api.expression import And, Compare, CompareOp, Expression, Variable
+from ..query_api.expression import (And, Compare, CompareOp, Constant,
+                                    Expression, Variable)
 from .expr_compile import CompiledExpr, Scope, TypeResolver, compile_expression
 from .groupby import hash_columns32
 from .windows import _append_packed
@@ -112,7 +113,8 @@ def plan_join(on: Optional[Expression], probe_frame: str, build_frame: str,
     probe_keys: list = []
     build_keys: list = []
     for conj in split_conjuncts(on):
-        if isinstance(conj, Compare) and conj.op == CompareOp.EQUAL:
+        if isinstance(conj, Compare) and conj.op == CompareOp.EQUAL \
+                and not _string_constant(conj):
             lf = frames_of(conj.left, resolver)
             rf = frames_of(conj.right, resolver)
             if lf <= {probe_frame} and rf <= {build_frame}:
@@ -127,6 +129,13 @@ def plan_join(on: Optional[Expression], probe_frame: str, build_frame: str,
     if residual is not None and residual.type != AttributeType.BOOL:
         raise SiddhiAppCreationError("join ON condition must be boolean")
     return JoinPlan(probe_keys, build_keys, residual)
+
+
+def _string_constant(conj: Compare) -> bool:
+    """`attr == 'text'`: a filter of one side, never a hash key (the
+    constant compiles to no device value)."""
+    return any(isinstance(e, Constant) and e.type_name == "string"
+               for e in (conj.left, conj.right))
 
 
 def _hash_exprs(keys: Sequence[CompiledExpr], scope: Scope) -> jax.Array:

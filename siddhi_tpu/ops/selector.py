@@ -19,6 +19,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 from dataclasses import dataclass, field
+from types import SimpleNamespace
 from typing import Optional
 
 import jax
@@ -301,7 +302,20 @@ class CompiledSelector:
         # --- having / order by compiled against the output frame ---
         out_frames = dict(frames)
         out_frames["__out__"] = dict(self.out_types)
-        out_resolver = TypeResolver(out_frames, "__out__", resolver.codecs,
+        # a string the select passes through keeps its source's codes: a
+        # constant compared with it is interned in that source's table
+        strings = {}
+        for name, e in rewritten:
+            if isinstance(e, Variable) \
+                    and self.out_types.get(name) == AttributeType.STRING:
+                ref, attr, _ = self.resolver.resolve(e)
+                codec = resolver.codecs.get(ref or resolver.default_frame)
+                if codec is not None and attr in codec.string_tables:
+                    strings[name] = codec.string_tables[attr]
+        out_codecs = dict(resolver.codecs)
+        if strings:
+            out_codecs["__out__"] = SimpleNamespace(string_tables=strings)
+        out_resolver = TypeResolver(out_frames, "__out__", out_codecs,
                                     resolver.set_projections)
         self.having = (compile_expression(selector.having, out_resolver, registry)
                        if selector.having is not None else None)

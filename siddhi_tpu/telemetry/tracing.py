@@ -96,13 +96,17 @@ STEP_STAGES = (
     # the pattern's step programs
     "append", "match", "match/expire",
     "match/range",  # the key-and-threshold plan's pass over its table
+    # a table's device index (core/table_index.py): the key's slot, the
+    # events of a key in arrival order, the rows written
+    "table", "table/lookup", "table/order", "table/write",
 )
 #: the top-level stages of each family of step program, in program order
 STEP_FAMILIES = {
     "query": ("filter", "window", "selector", "emit"),
-    "join": ("filter", "window", "probe", "compact", "frames", "selector",
-             "emit"),
+    "join": ("filter", "window", "table", "probe", "compact", "frames",
+             "selector", "emit"),
     "pattern": ("filter", "append", "match", "frames", "selector", "emit"),
+    "table": ("table",),
 }
 STAGE_PREFIX = "siddhi."
 

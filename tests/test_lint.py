@@ -43,6 +43,7 @@ CORPUS_EXPECTATIONS = {
     "sl117": ("SL117", Severity.WARN),
     "sl118": ("SL118", Severity.INFO),
     "sl119": ("SL119", Severity.INFO),
+    "sl120": ("SL120", Severity.INFO),
     "sl501": ("SL501", Severity.ERROR),
     "sl502": ("SL502", Severity.ERROR),
     "sl503": ("SL503", Severity.WARN),

@@ -98,6 +98,28 @@ SHAPES = {
                 "filter", "match", "match/expire", "frames", "selector",
                 "emit"}),
             "jit_pattern_heartbeat": ("pattern", {"append", "selector"})}),
+    # the SiddhiQL guide's update-or-insert and table join on the device
+    # index of a primary key (core/table_index.py)
+    "indexed_table": ("""
+        define stream DeviceStream (deviceID long, roomNo int, type string);
+        define stream TempStream (deviceID long, temp double);
+        @PrimaryKey('deviceID') @capacity(rows = '1024')
+        define table DeviceTable (deviceID long, roomNo int, type string);
+        @info(name = 'register')
+        from DeviceStream select deviceID, roomNo, type
+        update or insert into DeviceTable on DeviceTable.deviceID == deviceID;
+        @info(name = 'enrich')
+        from TempStream join DeviceTable
+        on TempStream.deviceID == DeviceTable.deviceID
+        select TempStream.deviceID as deviceID, DeviceTable.type as roomType,
+               TempStream.temp as temp
+        having roomType == 'server-room'
+        insert into ServerRoomTempStream;
+        """, 256, {
+            "jit_table_write_DeviceTable": ("table", {
+                "table", "table/lookup", "table/order", "table/write"}),
+            "jit_join_probe_left": ("join", {
+                "table", "table/lookup", "frames", "selector"})}),
 }
 
 
