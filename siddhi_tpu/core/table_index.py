@@ -39,7 +39,7 @@ from jax import lax
 from ..ops.keyed_window import key_words
 from ..ops.lanes import from_words, words
 from ..ops.slot_table import (SlotTable, init_slot_table, lookup,
-                              lookup_or_insert, run_first)
+                              lookup_or_insert)
 from ..query_api.definition import AttributeType
 from ..query_api.execution import OutputAction, Query
 from ..query_api.expression import Compare, CompareOp, Variable
@@ -189,15 +189,33 @@ def probe(index: TableIndex, capacity: int, key: jax.Array, live):
     return slot, hit, counts
 
 
-def _scatter(col, at, vals, **hints):
-    """`col.at[at].set(vals, mode="drop")`, an 8-byte column as its two
-    32-bit words: the TPU emulates a 64-bit scatter over the whole column
-    (≈ 7.5 ms a scatter at 2^20 rows; PERF.md, PR 44), not over the lanes."""
+def _scatter(col, at, vals):
+    """`col.at[at].set(vals)` for a non-decreasing `at`, an index outside
+    the column (negative too) dropped; an 8-byte column as its two 32-bit
+    words: the TPU emulates a 64-bit scatter over the whole column (≈ 7.5 ms
+    a scatter at 2^20 rows on a TPU v5e, PERF.md), not over the lanes."""
+    hints = dict(mode="drop", indices_are_sorted=True,
+                 wrap_negative_indices=False)
     if col.dtype.itemsize != 8:
-        return col.at[at].set(vals.astype(col.dtype), mode="drop", **hints)
-    return from_words([w.at[at].set(v, mode="drop", **hints) for w, v in
+        return col.at[at].set(vals.astype(col.dtype), **hints)
+    return from_words([w.at[at].set(v, **hints) for w, v in
                        zip(words(col), words(vals.astype(col.dtype)))],
                       col.dtype)
+
+
+def _gather_words(tree, idx):
+    """Every `[N]` leaf of `tree` at lanes `idx`, through ONE gather of the
+    leaves' 32-bit words stacked words-major, `[W, N]` (an 8-byte column as
+    its two words): the lanes stay the minor axis, where a `[N, W]` block
+    pads each row to 128 words on the TPU."""
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    split = [words(a) for a in leaves]
+    mat = jnp.stack([w for ws in split for w in ws])[:, idx]
+    out, at = [], 0
+    for a, ws in zip(leaves, split):
+        out.append(from_words(list(mat[at:at + len(ws)]), a.dtype))
+        at += len(ws)
+    return jax.tree_util.tree_unflatten(treedef, out)
 
 
 def make_write(attr_dtypes: dict, key: str, capacity: int, upsert: bool):
@@ -205,7 +223,17 @@ def make_write(attr_dtypes: dict, key: str, capacity: int, upsert: bool):
     `(cols, ts, valid, index, out_cols, out_ts, live, set_vals) -> (cols,
     ts, valid, index)`. `set_vals` holds, per attribute an update writes,
     its value on every lane (upsert only); an inserted row takes the
-    output's attribute of its name."""
+    output's attribute of its name.
+
+    The batch's rows move packed (`_gather_words`: an 8-byte column as its
+    two words, every column of one index in one gather): once into slot
+    order, then each run's first row (what an insert takes) and its last
+    (what an update sets): three gathers a batch in place of a gather a
+    column. Words-major, as `[W, B]`: a `[B, W]` block (`ops/lanes.py`
+    `gather_lanes`) took 4.3 ms at 131,072 lanes on a TPU v5e, `[W, B]`
+    1.07 (PERF.md).
+    Every scatter into the table's columns runs in slot order and says so:
+    the TPU sorts a scatter's indices first where it is not told."""
     C = capacity
 
     def write(cols, ts, valid, index, out_cols, out_ts, live, set_vals):
@@ -222,33 +250,40 @@ def make_write(attr_dtypes: dict, key: str, capacity: int, upsert: bool):
                     (slot, lax.iota(jnp.int32, B)), num_keys=2)
                 edge = s_slot[1:] != s_slot[:-1]
                 start = jnp.concatenate([jnp.ones((1,), bool), edge])
-                first = run_first(start)
                 end = jnp.concatenate([edge, jnp.ones((1,), bool)])
-                # each position's run end: a scan from the right
-                last = (B - 1) - run_first(end[::-1])[::-1]
+                # each position's run start and end
+                pos = lax.iota(jnp.int32, B)
+                first = lax.cummax(jnp.where(start, pos, 0))
+                last = lax.cummin(jnp.where(end, pos, B - 1), reverse=True)
                 placed = s_slot < C
                 new = placed & (s_slot >= n0)
-                lane_first = s_lane[first]
-                lane_last = s_lane[last]
+                sets = set_vals if upsert else {}
+                s_out, s_ts, s_set = _gather_words(
+                    ({a: out_cols[a] for a in attr_dtypes}, out_ts, sets),
+                    s_lane)
             with stage("table/write"):
                 # every lane of a run writes its run's value to the run's
                 # row: duplicate indices with one value, in slot order
-                at_all = s_slot
-                at_new = jnp.where(new, s_slot, C)
+                ins, ins_ts = _gather_words(
+                    ({a: s_out[a] for a in attr_dtypes if a not in sets},
+                     s_ts), first)
+                upd = _gather_words(s_set, last) if sets else {}
+                # rows taken before this batch fall off the front: the
+                # indices stay non-decreasing
+                at_new = jnp.where(s_slot < n0, -1, s_slot)
+                # a new key's lone event is its insert
+                alone = new & (first == last)
                 new_cols = dict(cols)
                 for a, dt in attr_dtypes.items():
-                    ins = out_cols[a][lane_first].astype(dt)
-                    if upsert and a in set_vals:
-                        upd = set_vals[a][lane_last].astype(dt)
-                        # a new key's lone event is its insert
-                        alone = new & (first == last)
+                    if a in sets:
                         new_cols[a] = _scatter(
-                            cols[a], at_all, jnp.where(alone, ins, upd),
-                            indices_are_sorted=True)
+                            cols[a], s_slot,
+                            jnp.where(alone, s_out[a].astype(dt),
+                                      upd[a].astype(dt)))
                     else:
-                        new_cols[a] = _scatter(cols[a], at_new, ins)
-                new_ts = _scatter(ts, at_new, out_ts[lane_first])
-                new_valid = valid.at[at_new].set(True, mode="drop")
+                        new_cols[a] = _scatter(cols[a], at_new, ins[a])
+                new_ts = _scatter(ts, at_new, ins_ts)
+                new_valid = _scatter(valid, at_new, jnp.ones((B,), bool))
                 n_placed = jnp.sum(placed, dtype=jnp.int32)
                 n_new = jnp.sum(new & start, dtype=jnp.int32)
                 counts = bump(

@@ -5,7 +5,10 @@ registry, held bit for bit and in order to the plain per-event reference
 choice and its lint text (SL120), and every form that stays on the [B, C]
 path; and the parent's faults on this app, as regression tests."""
 
+import re
+
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -52,20 +55,32 @@ def app(cap=1024, write=UPSERT, having=HAVING, on="", async_="",
             + ENRICH.format(on=on, having=having))
 
 
+#: 64-bit values at the edges of their two 32-bit words
+WORD_EDGES = (-2**63, -2**32 - 1, -2**32, -1, 0, 2**31, 2**32 - 1, 2**32,
+              2**63 - 1)
+
+
 class Fleet:
     """Seeded devices (63-bit ids), their rooms, registrations and
-    readings; every event stamped with a global index."""
+    readings; every event stamped with a global index. With `edges` the
+    first devices' ids, and the stamps, walk WORD_EDGES."""
 
-    def __init__(self, seed, keys):
+    def __init__(self, seed, keys, edges=False):
         self.rng = np.random.default_rng(seed)
         self.ids = self.rng.choice(np.arange(-2**62, 2**62, 2**40 + 7),
                                    keys, replace=False).astype(np.int64)
+        if edges:
+            self.ids[:len(WORD_EDGES)] = WORD_EDGES[:keys]
+            assert len(set(self.ids.tolist())) == keys
         self.rooms = self.rng.integers(0, 100, keys).astype(np.int32)
         self.stamp = 0
+        self.edges = edges
 
     def _stamps(self, n):
         out = list(range(self.stamp, self.stamp + n))
         self.stamp += n
+        if self.edges:
+            return [WORD_EDGES[s % len(WORD_EDGES)] for s in out]
         return out
 
     def registrations(self, n, devices=None):
@@ -105,6 +120,13 @@ class Engine:
     def table_rows(self):
         return sorted(self.table.all_rows())
 
+    def table_stamps(self):
+        """(deviceID, the row's timestamp) of every row, sorted."""
+        s = self.table.state
+        held = np.asarray(s.valid)
+        return sorted(zip(np.asarray(s.cols["deviceID"])[held].tolist(),
+                          np.asarray(s.ts)[held].tolist()))
+
     def counters(self):
         return self.rt.statistics_report()["tables"]["DeviceTable"]
 
@@ -113,17 +135,32 @@ def reference_rows(reg: Registry):
     return sorted((k, *v) for k, v in reg.rows.items())
 
 
-def run_both(text, batch, plan, insert_mode=False):
+def run_both(text, batch, plan, insert_mode=False, cap=None):
     """Feed the engine and the reference the same batches, in order; a
-    batch is ("reg" | "read", rows)."""
+    batch is ("reg" | "read", rows). A key finds a row where it has one or
+    fewer than `cap` are taken when its first event comes (the rest are
+    dropped, `reg.dropped`). With a Fleet's `edges` the registrations are
+    sent stamped with their `timestamp`, and `reg.stamps` holds each row's:
+    its inserting event's."""
     eng = Engine(text, batch)
     reg = Registry()
+    reg.dropped, reg.stamps = 0, {}
     want = []
     eng.hits = 0
-    for kind, rows in plan:
+    for kind, rows, *stamped in plan:
         if kind == "reg":
-            eng.send("DeviceStream", rows)
+            if stamped and stamped[0]:
+                eng.rt.get_input_handler("DeviceStream").send_batch(
+                    rows, timestamps=[r[3] for r in rows])
+                eng.rt.flush()
+            else:
+                eng.send("DeviceStream", rows)
             for r in rows:
+                if r[0] not in reg.rows:
+                    if cap is not None and len(reg.rows) >= cap:
+                        reg.dropped += 1
+                        continue
+                    reg.stamps[r[0]] = r[3]
                 (reg.insert if insert_mode else reg.upsert)(*r)
         else:
             eng.send("TempStream", rows)
@@ -135,59 +172,97 @@ def run_both(text, batch, plan, insert_mode=False):
     return eng, reg, want
 
 
-def assert_same(eng, reg, want):
+def assert_same(eng, reg, want, stamped=False):
     got = [(d, r, t, float(np.float32(temp)), ts, u)
            for d, r, t, temp, ts, u in eng.rows]
     assert got == want  # bit for bit and in reading order
     assert eng.table_rows() == reference_rows(reg)
+    if stamped:
+        assert eng.table_stamps() == sorted(reg.stamps.items())
 
 
 # --------------------------------------------------- engine vs reference
 
 
-@pytest.mark.parametrize("seed,keys,batch,cap", [
-    (11, 50, 8, 64), (12, 120, 32, 256), (13, 500, 256, 1024),
-    (14, 300, 64, 512)])
+@pytest.mark.parametrize("seed,keys,batch,cap,edges", [
+    pytest.param(11, 50, 8, 64, False, id="11-50-8-64"),
+    pytest.param(12, 120, 32, 256, False, id="12-120-32-256"),
+    pytest.param(13, 500, 256, 1024, False, id="13-500-256-1024"),
+    pytest.param(14, 300, 64, 512, False, id="14-300-64-512"),
+    # a full table: keys past the 64th to come are dropped and counted
+    pytest.param(15, 120, 64, 64, False, id="full_table"),
+    # ids, `updated` and the rows' stamps at the words' edges
+    pytest.param(16, 40, 32, 64, True, id="word_edges"),
+    pytest.param(17, 100, 64, 64, True, id="full_table_at_word_edges")])
 def test_upsert_and_join_match_the_per_event_reference(seed, keys, batch,
-                                                      cap):
-    fleet = Fleet(seed, keys)
+                                                      cap, edges):
+    fleet = Fleet(seed, keys, edges)
     plan = []
     for _ in range(6):
         # repeated keys within a batch: few keys, many events
-        plan.append(("reg", fleet.registrations(batch)))
+        plan.append(("reg", fleet.registrations(batch), edges))
         plan.append(("read", fleet.readings(batch)))
-    eng, reg, want = run_both(app(cap=cap), batch, plan)
-    assert_same(eng, reg, want)
+    eng, reg, want = run_both(app(cap=cap), batch, plan, cap=cap)
+    assert_same(eng, reg, want, stamped=edges)
     c = eng.counters()
     n_reg = 6 * batch
     assert c["inserted"] == len(reg.rows) == c["rows"]
-    assert c["inserted"] + c["updated"] == n_reg
-    assert c["dropped"] == 0 and c["duplicates"] == 0
+    assert c["inserted"] + c["updated"] + c["dropped"] == n_reg
+    assert c["dropped"] == reg.dropped and c["duplicates"] == 0
+    assert (reg.dropped > 0) == (keys > cap)
     assert c["probes"] == 6 * batch
     assert c["hits"] == eng.hits
     assert c["capacity"] == cap and c["steps"] == 6
 
 
-@pytest.mark.parametrize("seed", [21, 22, 23])
-def test_repeated_keys_in_one_batch_last_event_wins(seed):
-    fleet = Fleet(seed, 4)
-    rows = fleet.registrations(64)  # 4 devices, 16 events each
-    eng, reg, want = run_both(app(cap=64), 64, [("reg", rows)])
-    assert_same(eng, reg, want)
+RUNS = (2, 3, 4, 5)
+
+
+@pytest.mark.parametrize("seed,runs,edges", [
+    pytest.param(21, None, False, id="21"),
+    pytest.param(22, None, False, id="22"),
+    pytest.param(23, None, False, id="23"),
+    pytest.param(24, RUNS, False, id="runs_2_to_5"),
+    pytest.param(25, RUNS, True, id="runs_2_to_5_at_word_edges")])
+def test_repeated_keys_in_one_batch_last_event_wins(seed, runs, edges):
+    if runs is None:
+        fleet = Fleet(seed, 4)
+        plan = [("reg", fleet.registrations(64))]  # 4 devices, 16 each
+    else:
+        # held keys (0..4) and new ones (5..9) in one batch, each in a run
+        # of 2 to 5 events, and one of each alone; arrival order shuffled
+        n = len(runs)
+        fleet = Fleet(seed, 2 * n + 2, edges)
+        devices = [d for i, r in enumerate(runs)
+                   for d in [i] * r + [n + 1 + i] * r] + [n, 2 * n + 1]
+        plan = [("reg", fleet.registrations(n + 1, devices=range(n + 1)),
+                 edges),
+                ("reg", fleet.registrations(
+                    len(devices), devices=fleet.rng.permutation(devices)),
+                 edges)]
+    eng, reg, want = run_both(app(cap=64), 64, plan)
+    assert_same(eng, reg, want, stamped=edges)
     last = {}
-    for r in rows:
+    for r in plan[-1][1]:
         last[r[0]] = r
-    assert eng.table_rows() == sorted(last.values())
+    assert [r for r in eng.table_rows() if r[0] in last] \
+        == sorted(last.values())
+    c = eng.counters()
+    assert c["inserted"] == len(reg.rows)
+    assert c["inserted"] + c["updated"] == sum(len(p[1]) for p in plan)
 
 
-@pytest.mark.parametrize("seed", [31, 32])
-def test_plain_insert_first_event_wins_and_the_rest_are_counted(seed):
-    fleet = Fleet(seed, 10)
-    plan = [("reg", fleet.registrations(40)), ("reg", fleet.registrations(40)),
+@pytest.mark.parametrize("seed,edges", [
+    pytest.param(31, False, id="31"), pytest.param(32, False, id="32"),
+    pytest.param(33, True, id="word_edges")])
+def test_plain_insert_first_event_wins_and_the_rest_are_counted(seed, edges):
+    fleet = Fleet(seed, 10, edges)
+    plan = [("reg", fleet.registrations(40), edges),
+            ("reg", fleet.registrations(40), edges),
             ("read", fleet.readings(40))]
     eng, reg, want = run_both(app(cap=64, write="insert into DeviceTable"),
                               40, plan, insert_mode=True)
-    assert_same(eng, reg, want)
+    assert_same(eng, reg, want, stamped=edges)
     assert eng.table.dropped_duplicates == reg.duplicates == 80 - 10
     assert eng.counters()["duplicates"] == reg.duplicates
 
@@ -315,6 +390,103 @@ def test_the_write_program_is_warmed_and_named():
     names = {fn.__name__ for fn in eng.rt.query_runtimes[
         "register"].table_executor._fns.values()}
     assert names == {"table_write_DeviceTable"}
+
+
+# ------------------------------------------ the write's data movement
+
+
+def _write_program_hlo(write, rows=1024, lanes=1024):
+    """The table-write program of a `rows`-row registry written by `write`
+    at `lanes` lanes, lowered: its HLO text before any optimisation."""
+    from siddhi_tpu.core.event import EventBatch
+    eng = Engine(app(cap=rows, write=write), lanes)
+    qr = eng.rt.query_runtimes["register"]
+    t = eng.table
+    out = EventBatch.empty(qr.output_definition, lanes)
+    state = (t.state.cols, t.state.ts, t.state.valid, t.index_state())
+    if write == UPSERT:
+        prog = qr.table_executor._program(qr.query.output_stream.event_type)
+        args = state + (out,)
+    else:
+        prog = t.insert_writer()
+        args = state + (out.cols, out.ts, out.valid, {})
+    shapes = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(jnp.shape(x), jnp.result_type(x)),
+        args)
+    return prog.lower(*shapes).compiler_ir(dialect="hlo").as_hlo_text()
+
+
+def _gathers_and_scatters(hlo: str):
+    """(everywhere, steady): each gather and scatter of the module as
+    (opcode, result type, operand types, line); `steady` those outside
+    every `conditional`'s branches (the index's insert path), reached from
+    the entry by calls alone."""
+    comps, entry, name, types = {}, None, None, {}
+    for line in hlo.splitlines():
+        head = re.match(r"(ENTRY )?(\S+) \{$", line)
+        if head:
+            name = head.group(2)
+            entry = name if head.group(1) else entry
+            comps[name] = []
+        elif line == "}":
+            name = None
+        elif name is not None:
+            comps[name].append(line.strip())
+            ins = re.match(r"(?:ROOT )?(\S+) = (\S+) ", line.strip())
+            if ins:
+                types[ins.group(1)] = ins.group(2)
+    steady, todo = set(), [entry]
+    while todo:
+        c = todo.pop()
+        if c not in steady:
+            steady.add(c)
+            for line in comps[c]:
+                todo += re.findall(r"to_apply=([\w.\-]+)", line)
+    found = {"everywhere": [], "steady": []}
+    for c, lines in comps.items():
+        for line in lines:
+            op = re.match(r"(?:ROOT )?\S+ = (\S+) (gather|scatter)\((.*?)\)",
+                          line)
+            if op:
+                item = (op.group(2), op.group(1),
+                        [types[o.strip()] for o in op.group(3).split(",")],
+                        line)
+                found["everywhere"].append(item)
+                if c in steady:
+                    found["steady"].append(item)
+    return found["everywhere"], found["steady"]
+
+
+@pytest.mark.parametrize("write,packed", [
+    pytest.param(UPSERT, 3, id="update_or_insert"),
+    pytest.param("insert into DeviceTable", 2, id="insert")])
+def test_the_write_moves_rows_packed_and_scatters_in_slot_order(write,
+                                                                packed):
+    """The write of a 1,024-row table of a `long` key and a `long`
+    attribute at 1,024 lanes. No gather or scatter of the program moves
+    64-bit elements. Outside the index's insert branch the batch's rows are
+    taken by `packed` row gathers of 32-bit words, narrower than the
+    bucket's 128-word row the lookup gathers: one into slot order, one at
+    each run's first event (what an insert takes) and, where `update or
+    insert` sets attributes, one at each run's last (what `set` writes);
+    no gather takes one column. Each of the nine scatters into the table's
+    columns (deviceID, updated and the stamp as two words each, roomNo,
+    type, valid) states that its indices are sorted."""
+    everywhere, steady = _gathers_and_scatters(_write_program_hlo(write))
+    wide = [i for i in everywhere
+            if any(t.startswith(("s64", "u64", "f64")) for t in
+                   [i[1]] + i[2])]
+    assert not wide, wide
+    gathers = [i for i in steady if i[0] == "gather"]
+    blocks = [g for g in gathers if re.match(r"s32\[\d+,\d+\]", g[1])]
+    buckets = [g for g in blocks if g[1].startswith("s32[1024,128]")]
+    assert len(buckets) == 1  # the lookup's row of the key's bucket
+    assert len(blocks) - len(buckets) == packed
+    assert len(gathers) == len(blocks)  # no one-column gather
+    scatters = [i for i in steady if i[0] == "scatter"]
+    assert len(scatters) == 9
+    assert all(re.match(r"(s32|pred)\[1024\]", sc[1])
+               and "indices_are_sorted=true" in sc[3] for sc in scatters)
 
 
 # ---------------------------------------------------- the plan and SL120
